@@ -271,7 +271,7 @@ def launch_fleet(n, extra, tag, *, transport, raw, ring_nonce, env, nice=10):
     """Spawn ``n`` ``stream_producer.py`` processes; returns Producers.
 
     Producers run at ``nice`` +10 by default: on a 1-core host they are
-    pure contention for the consumer/tunnel-pump whenever the ring has
+    pure contention for the consumer/transfer-pump whenever the ring has
     space, and backpressure (the blocking ring writer) keeps them fed
     regardless of priority — deprioritizing them shortens transfer tails
     without starving the stream.  The priority drop rides a ``nice -n``

@@ -44,22 +44,50 @@ def popen_group_kwargs():
     return {}
 
 
+def _checkout_root():
+    """Directory holding the ``blendjax`` package (the checkout root)."""
+    return os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+
+
+def place_compile_cache(env):
+    """THE compile-cache policy, applied to ``env`` in place: a caller who
+    set ``JAX_COMPILATION_CACHE_DIR`` decides where compiled programs
+    persist and nothing else is written anywhere; unset, they go to
+    ``<checkout>/.jax_cache`` — a FIXED path (it is part of the cache
+    key's surroundings: a directory named by pid, time or tempfile never
+    hits).  jax reads the variable at import, so no code calls
+    ``jax.config.update('jax_compilation_cache_dir', ...)``.  Children
+    get it through :func:`child_env`; the few top-level programs that
+    import jax themselves call this on ``os.environ`` first."""
+    env.setdefault(
+        "JAX_COMPILATION_CACHE_DIR",
+        os.path.join(_checkout_root(), ".jax_cache"),
+    )
+    return env
+
+
 def child_env():
-    """Environment for producer subprocesses.
+    """Environment for every subprocess the repo spawns (producers, serve
+    servers, learners, stages, shards) — one child-environment policy.
 
     ``--python-use-system-env`` tells Blender to honor PYTHONPATH; prepend the
     package root that provides ``blendjax`` (the btb producer side) so
     producer scripts can import it even when the launching process found it
     via cwd alone.  Shared with the watchdog's respawn path.
+
+    The platform is NOT decided here: ``JAX_PLATFORMS`` passes through
+    from the caller untouched (tests export ``cpu``; on a TPU machine
+    nothing is set and a jax child takes the chip).  The compile cache
+    follows :func:`place_compile_cache`, so a respawned child finds what
+    its predecessor compiled.
     """
     env = os.environ.copy()
-    pkg_root = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
     env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (pkg_root, env.get("PYTHONPATH", "")) if p
+        p for p in (_checkout_root(), env.get("PYTHONPATH", "")) if p
     )
-    return env
+    return place_compile_cache(env)
 
 
 class BlenderLauncher:

@@ -35,7 +35,7 @@ _SENTINEL = object()
 class TransferGate:
     """Pauses feed workers while a host->device transfer is in flight.
 
-    On core-starved hosts (TPU-VM sidecars, CI containers) the tunnel/PCIe
+    On core-starved hosts (TPU-VM sidecars, CI containers) the PCIe
     client that pumps ``device_put`` shares its core with the collate and
     recv threads; any concurrently running Python thread then stretches the
     transfer by GIL-handoff latency (measured on a 1-core host: 9.8 MB
@@ -59,10 +59,10 @@ class TransferGate:
         Liveness backstop for :meth:`wait` — a crashed transfer thread
         must not freeze the feed forever.  When it fires, a warning is
         logged once per stall episode (re-armed each time the gate next
-        opens, so a later unrelated stall — e.g. after a relay recovery —
-        is visible too; ADVICE r4) and the ``transfer_gate_backstops``
-        fleet counter increments (every fire: the counter is the
-        quantitative record, the log is the narrative one).
+        opens, so a later unrelated stall is visible too; ADVICE r4) and
+        the ``transfer_gate_backstops`` fleet counter increments (every
+        fire: the counter is the quantitative record, the log is the
+        narrative one).
     counters: EventCounters | None
         Backstop-fire sink; defaults to the process-wide
         ``blendjax.utils.timing.fleet_counters`` so

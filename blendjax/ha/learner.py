@@ -165,7 +165,12 @@ def main(argv=None):
         checkpointer=ckptr,
     )
 
+    from blendjax.utils.device import device_info
+
     ckptr.stats_extra["pid"] = os.getpid()
+    # where this learner computes, for a supervisor across the process
+    # boundary (LearnerProcess.stats): platform, device_kind, count
+    ckptr.stats_extra.update(device_info())
     resumed_from = None
     if manifest is not None:
         ckptr.restore(learner, manifest)  # republish included

@@ -22,8 +22,11 @@ pass (Q innermost) — recomputing probabilities from the saved logsumexp
 softmax-jacobian correction).  No (T, T) matrix exists in either
 direction; gradient parity vs the einsum reference is tested to ~5e-5.
 
-Interpret mode (``interpret=True``) runs the same kernel on CPU for CI;
-parity against ``full_attention`` is tested both causal and not.
+Interpret mode runs the same kernel on CPU for CI (parity against
+``full_attention`` is tested both causal and not).  ONE rule picks it,
+:func:`resolve_interpret`: ``interpret=None`` (every default) compiles
+through Mosaic on a TPU backend and interprets elsewhere; an explicit
+bool wins.
 """
 
 from __future__ import annotations
@@ -33,16 +36,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent in CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
+
+
+def resolve_interpret(interpret=None):
+    """THE rule for Pallas interpret mode, shared by every kernel the
+    package ships and every parallel scheme that wraps one: ``None``
+    compiles on a TPU backend and interprets elsewhere (the CPU mesh CI
+    runs on); an explicit bool wins (``tests/test_tpu_lowering.py``
+    forces the compiled lowering when exporting for TPU from a CPU
+    host)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
 def _default_scale(scale, d):
@@ -344,10 +352,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
 
 def _scratch(shapes):
-    if _VMEM is not None:
-        return [_VMEM(s, jnp.float32) for s in shapes]
-    # pragma: no cover - jaxlib without the TPU pallas extension
-    return [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    return [pltpu.VMEM(s, jnp.float32) for s in shapes]
 
 
 def _flat(x):
@@ -364,13 +369,7 @@ def _sds(shape, dtype, like):
     """ShapeDtypeStruct that inherits ``like``'s varying-manual-axes type,
     so the kernel composes inside shard_map (e.g. as Ulysses' inner
     attention) under vma typing."""
-    try:
-        vma = jax.typeof(like).vma
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _check_blocks(t, block, name):
@@ -443,7 +442,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_kv, interpret,
         scratch_shapes=_scratch([
             (block_q, d), (block_q, 128), (block_q, 128)
         ]),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return _unflat(of, b, h), (qf, kf, vf, of, lse)
 
@@ -477,12 +476,12 @@ def _check_window_overshoot(window, q_offset, tq, tk):
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8)
 )
 def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_kv=128, interpret=False, window=None):
+                    block_kv=128, interpret=None, window=None):
     """Fused block-wise attention; same contract as ``full_attention``:
     q/k/v (B, T, H, D) -> (B, T, H, D).
 
     ``T`` must divide by both block sizes (pick blocks accordingly or pad
-    upstream).  ``interpret=True`` runs on CPU (CI parity tests).
+    upstream).  ``interpret`` follows :func:`resolve_interpret`.
 
     GQA/MQA: k/v may carry FEWER heads than q (``H % H_kv == 0``) —
     each group of ``H // H_kv`` q heads reads the same KV head, purely
@@ -546,7 +545,7 @@ def _dq_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
         out_specs=q_spec_i,
         out_shape=_sds((bh, tq, d), out_dtype or qf.dtype, qf),
         scratch_shapes=_scratch([(block_q, d)]),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf, dof, lse, delta)
 
 
@@ -596,7 +595,7 @@ def _dkv_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
             _sds((bh, tk, d), out_dtype or vf.dtype, qf),
         ],
         scratch_shapes=_scratch([(block_kv, d), (block_kv, d)]),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf, dof, lse, delta)
 
 
@@ -640,7 +639,7 @@ flash_attention.defvjp(_fwd, _bwd)
 
 
 def make_flash_attention(causal=True, block_q=128, block_kv=128,
-                         interpret=False, window=None):
+                         interpret=None, window=None):
     """``attn_fn`` closure for :func:`blendjax.models.seqformer.apply` —
     drop-in for the default ``full_attention``.
 

@@ -13,7 +13,8 @@ Two implementations of the decode:
 - :func:`decode_frames_pallas` — a Pallas TPU kernel doing
   uint8→float→(sRGB linearize)→normalize in one VMEM pass; useful when the
   decode feeds multiple consumers and you want it materialized exactly
-  once.  Runs in interpret mode on CPU for tests.
+  once.  Interpret mode follows the one rule in
+  :func:`blendjax.ops.flash_attention.resolve_interpret`.
 """
 
 from __future__ import annotations
@@ -23,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from blendjax.ops.flash_attention import resolve_interpret
 
 # sRGB <-> linear (IEC 61966-2-1)
 
@@ -80,13 +83,13 @@ def _decode_kernel(x_ref, o_ref, *, linearize):
     jax.jit, static_argnames=("dtype", "linearize", "block_rows", "interpret")
 )
 def decode_frames_pallas(
-    frames_u8, dtype=jnp.float32, linearize=False, block_rows=256, interpret=False
+    frames_u8, dtype=jnp.float32, linearize=False, block_rows=256, interpret=None
 ):
     """Pallas TPU kernel version of :func:`decode_frames` (no mean/std).
 
     The frame batch is viewed as a 2-D (rows, 128) array padded to the TPU
     tile grid; each grid step converts ``block_rows`` rows HBM->VMEM->HBM.
-    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU CI).
+    ``interpret=None`` compiles on TPU and interprets elsewhere.
     """
     orig_shape = frames_u8.shape
     total = frames_u8.size
@@ -108,7 +111,7 @@ def decode_frames_pallas(
         grid=(n_rows // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, _LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, _LANE), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2d)
     return out.reshape(-1)[:total].reshape(orig_shape)
 

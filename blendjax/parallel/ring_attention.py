@@ -48,31 +48,23 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
+
+from blendjax.ops.flash_attention import resolve_interpret
 
 _NEG = -1e30  # finite mask value: keeps the online-softmax nan-free
 
 
 def _pvary(x, axes):
-    """Mark ``x`` device-varying over ``axes`` under shard_map's vma typing
-    (no-op on JAX versions without the typing).  Idempotent: axes the
-    value already varies over are skipped — zeros_like of a sharded input
-    is already varying, and re-casting raises."""
-    try:
-        vma = jax.typeof(x).vma
-        axes = tuple(a for a in axes if a not in vma)
-    except (AttributeError, TypeError):
-        pass
+    """Mark ``x`` device-varying over ``axes`` under shard_map's vma
+    typing.  Idempotent: axes the value already varies over are skipped
+    — zeros_like of a sharded input is already varying, and re-casting
+    raises."""
+    vma = jax.typeof(x).vma
+    axes = tuple(a for a in axes if a not in vma)
     if not axes:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axes)
-    return x
+    return lax.pcast(x, axes, to="varying")
 
 
 def full_attention(q, k, v, causal=False, scale=None, q_offset=0, k_offset=0,
@@ -240,7 +232,7 @@ def _lse_combine(o, lse, o_b, lse_b):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def ring_flash_attention(q, k, v, axis_name, causal=False, scale=None,
-                         interpret=False, vary_axes=None, window=None):
+                         interpret=None, vary_axes=None, window=None):
     """:func:`ring_attention` with the fused Pallas flash kernel per
     block pair — O(S/n) memory per device AND no (S/n, S/n) score matrix
     materialized within a block.
@@ -581,7 +573,7 @@ def _zigzag_perm(seq_len, n):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def zigzag_flash_attention(q, k, v, axis_name, scale=None,
-                           interpret=False, vary_axes=None):
+                           interpret=None, vary_axes=None):
     """Load-balanced CAUSAL ring attention with the fused flash kernel.
 
     Plain causal ring attention is imbalanced: device 0's queries see one
@@ -843,7 +835,8 @@ def make_ring_attention(
     ``inner_attn`` (ulysses only) swaps the per-head-group full-sequence
     attention, e.g. for the fused Pallas flash kernel;
     ``impl='ring_flash'`` instead fuses the kernel into the ring itself
-    (``flash_interpret`` overrides the on/off-TPU interpreter choice).
+    (``flash_interpret`` is the kernel's ``interpret``: ``None`` follows
+    :func:`~blendjax.ops.flash_attention.resolve_interpret`).
     Composes with data parallelism (``batch_axis='data'``) and — ring
     variants only — with head-sharded tensor parallelism
     (``head_axis='model'``): each device then ring-rotates K/V for its
@@ -868,8 +861,6 @@ def make_ring_attention(
             vary_axes=vary, window=window,
         )
     elif impl == "ring_flash":
-        if flash_interpret is None:
-            flash_interpret = jax.default_backend() != "tpu"
 
         def inner(q, k, v, _axis=seq_axis, _vary=vary,
                   _interp=flash_interpret):
@@ -888,8 +879,6 @@ def make_ring_attention(
                 "zigzag_flash + window is pointless: the windowed ring "
                 "is already load-balanced — use impl='ring_flash'"
             )
-        if flash_interpret is None:
-            flash_interpret = jax.default_backend() != "tpu"
 
         def inner(q, k, v, _axis=seq_axis, _vary=vary,
                   _interp=flash_interpret):
@@ -907,24 +896,18 @@ def make_ring_attention(
         raise ValueError(f"unknown impl {impl!r} (want 'ring', "
                          "'ring_flash', 'zigzag_flash' or 'ulysses')")
     sm_kwargs = {}
-    if impl in ("ring_flash", "zigzag_flash") and flash_interpret:
+    if impl in ("ring_flash", "zigzag_flash") \
+            and resolve_interpret(flash_interpret):
         # The Pallas HLO interpreter's grid-carry slicing trips
         # shard_map's vma typing for non-causal kernel instances (jax
         # 0.9; the error text itself recommends this flag as the
         # workaround).  Interpreter-only: the compiled TPU path keeps
         # full vma checking, and the parity tests check the numbers.
         sm_kwargs["check_vma"] = False
-    try:
-        mapped = shard_map(
-            inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            **sm_kwargs,
-        )
-    except TypeError:
-        # older jax (the experimental shard_map fallback import) has no
-        # check_vma kwarg — and no vma typing to work around either
-        mapped = shard_map(
-            inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
-        )
+    mapped = shard_map(
+        inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        **sm_kwargs,
+    )
 
     n_seq = mesh.shape[seq_axis]
 

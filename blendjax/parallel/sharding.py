@@ -181,22 +181,15 @@ def make_seqformer_train_step(
         )
 
         attn_impl = "ulysses"
-        # compiled kernel on TPU; the interpreter elsewhere keeps the
-        # option runnable on the CPU mesh used in CI.
-        # ``flash_interpret`` overrides (tests/test_tpu_lowering.py
-        # forces the compiled path when EXPORTING for tpu from a CPU
-        # host — the auto rule would silently export the interpreter
-        # lowering and prove nothing about Mosaic)
-        if flash_interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        else:
-            interpret = flash_interpret
 
         def inner_attn(q, k, v, causal=False, scale=None, window=None):
-            # one tile-selection policy for the ulysses and ring paths
+            # one tile-selection policy for the ulysses and ring paths.
+            # flash_interpret=None follows the kernel's own backend
+            # rule; tests/test_tpu_lowering.py passes False to force
+            # the compiled path when EXPORTING for tpu from a CPU host
             blk = flash_block_size(q.shape[1])
             return flash_attention(
-                q, k, v, causal, scale, blk, blk, interpret, window
+                q, k, v, causal, scale, blk, blk, flash_interpret, window
             )
     attn = make_ring_attention(
         mesh,

@@ -15,7 +15,6 @@ from __future__ import annotations
 import argparse
 import json
 import logging
-import os
 import signal
 import threading
 
@@ -47,8 +46,6 @@ def main(argv=None):
         format=f"%(asctime)s stage{args.proc_index} %(levelname)s "
                "%(message)s",
     )
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
     from blendjax.parallel.mpmd import MpmdStage
 
     stage = MpmdStage(

@@ -16,9 +16,14 @@ drive it with a CPU physics stub.
 from __future__ import annotations
 
 import argparse
+import os
 from pathlib import Path
 
-import jax
+from blendjax.btt.launcher import place_compile_cache
+
+place_compile_cache(os.environ)  # before jax reads its configuration
+
+import jax  # noqa: E402
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -15,9 +15,14 @@ scripts) can drive it with any batch iterator.
 from __future__ import annotations
 
 import argparse
+import os
 from pathlib import Path
 
-import jax
+from blendjax.btt.launcher import place_compile_cache
+
+place_compile_cache(os.environ)  # before jax reads its configuration
+
+import jax  # noqa: E402
 import numpy as np
 import optax
 

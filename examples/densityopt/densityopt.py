@@ -22,9 +22,14 @@ tests can swap Blender for a synthetic renderer.
 from __future__ import annotations
 
 import argparse
+import os
 from pathlib import Path
 
-import jax
+from blendjax.btt.launcher import place_compile_cache
+
+place_compile_cache(os.environ)  # before jax reads its configuration
+
+import jax  # noqa: E402
 import jax.numpy as jnp
 import numpy as np
 import optax

@@ -24,9 +24,14 @@ are sliced on device.  The training loop is factored into
 from __future__ import annotations
 
 import argparse
+import os
 from pathlib import Path
 
-import jax
+from blendjax.btt.launcher import place_compile_cache
+
+place_compile_cache(os.environ)  # before jax reads its configuration
+
+import jax  # noqa: E402
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -78,8 +83,7 @@ def make_attn(name, seq_len, window=None):
 
     blk = flash_block_size(seq_len)  # T must divide the flash tile
     return make_flash_attention(
-        causal=True, block_q=blk, block_kv=blk,
-        interpret=jax.default_backend() != "tpu", window=window,
+        causal=True, block_q=blk, block_kv=blk, window=window,
     )
 
 

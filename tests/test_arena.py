@@ -383,7 +383,7 @@ class TestFeedBoundBench:
             "stages": {"scatter": {"count": 1, "total_s": 0.1,
                                    "mean_ms": 100.0}},
         }
-        out = bench.assemble({}, host_fallback=lambda: 1.0, feed_bound=fb)
+        out = bench.assemble({"host_stream": {"items_per_sec": 1.0}}, feed_bound=fb)
         assert out["feed_bound"] is fb
         assert out["feed_bound"]["feed_limit_batches_per_sec"]["arena"] == 140.0
         line = bench.headline(out)

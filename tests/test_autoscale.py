@@ -729,7 +729,7 @@ def test_bench_headline_carries_autoscale_metrics():
         "drain_error_x": 0.0,
         "window_s": 0.75,
     }
-    out = bench.assemble({}, host_fallback=lambda: 1.0,
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}},
                          autoscale_bench=ab)
     assert out["autoscale_bench"]["resize_settle_s"] == 0.77
     line = bench.headline(out)

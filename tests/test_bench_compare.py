@@ -96,17 +96,6 @@ def test_extract_driver_wrapper_and_truncated_tail(tmp_path):
     assert m["value"] == 100.0                # parsed headline
 
 
-def test_real_checked_in_artifacts_extract():
-    old = bench_compare.extract_metrics(os.path.join(REPO, "BENCH_r04.json"))
-    new = bench_compare.extract_metrics(os.path.join(REPO, "BENCH_r05.json"))
-    assert old["rl_steps_per_sec"] > 0
-    assert new["value"] > 0
-    rows, regressions = bench_compare.compare(
-        old, new, bench_compare.DEFAULT_FLOORS
-    )
-    assert regressions == 0
-
-
 def test_regression_verdict_and_exit_code(tmp_path):
     old = _write(tmp_path, "old.json", json.dumps(HEADLINE))
     bad = dict(HEADLINE, feed_arena_x=0.9)  # 1.4 -> 0.9: x0.64 < 0.90

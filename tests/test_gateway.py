@@ -902,8 +902,8 @@ def test_bench_headline_carries_gateway_metrics():
         "serve_prefill_x": 14.9,
         "serve_qps_modes": {}, "stages": {},
     }
-    out = bench.assemble({}, host_fallback=lambda: 1.0, serve_bench=sb,
-                         gateway_bench=gb)
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}},
+                         serve_bench=sb, gateway_bench=gb)
     assert out["gateway_bench"]["gateway_scale_x"] == 2.24
     assert out["gateway_bench"]["gateway_shard_x"] == 1.39
     assert out["serve_bench"]["serve_prefill_x"] == 14.9

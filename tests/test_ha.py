@@ -381,7 +381,7 @@ def test_bench_headline_carries_ha_metrics():
         "learner_recovery_s": 2.5,
         "window_s": 1.5,
     }
-    out = bench.assemble({}, host_fallback=lambda: 1.0, ha_bench=ha)
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}}, ha_bench=ha)
     assert out["ha_bench"]["ckpt_overhead_x"] == 0.97
     line = bench.headline(out)
     assert line["ckpt_overhead_x"] == 0.97

@@ -370,7 +370,7 @@ def test_bench_headline_carries_pipe_mpmd_x():
     pb = {"phase": "pipeline_bench", "pipe_mpmd_x": 1.78,
           "pipe_stages": 3, "mpmd_updates_per_sec": 8.2,
           "single_updates_per_sec": 4.6}
-    out = bench.assemble({}, host_fallback=lambda: 1.0,
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}},
                          pipeline_bench=pb)
     assert out["pipeline_bench"]["pipe_mpmd_x"] == 1.78
     assert out["pipeline_bench"]["mpmd_updates_per_sec"] == 8.2

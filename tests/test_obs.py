@@ -934,7 +934,7 @@ def test_bench_headline_carries_telemetry_overhead():
         "telemetry_overhead_x": 0.97,
         "stages": {},
     }
-    out = bench.assemble({}, host_fallback=lambda: 1.0, feed_bound=fb)
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}}, feed_bound=fb)
     line = bench.headline(out)
     assert line["telemetry_overhead_x"] == 0.97
     assert len(json.dumps(line)) + 1 <= bench.HEADLINE_BYTE_BUDGET

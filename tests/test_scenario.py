@@ -596,7 +596,7 @@ def test_bench_headline_carries_scenario_metrics():
     import bench
 
     out = bench.assemble(
-        {},
+        {"host_stream": {"items_per_sec": 1.0}},
         scenario_bench={
             "phase": "scenario_bench",
             "scenarios": ["lite", "rich"],

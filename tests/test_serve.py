@@ -920,7 +920,8 @@ def test_bench_headline_carries_serve_metrics():
                             "int8": 2600.0},
         "stages": {},
     }
-    out = bench.assemble({}, host_fallback=lambda: 1.0, serve_bench=sb)
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}},
+                         serve_bench=sb)
     assert out["serve_bench"]["serve_qps"] == 2650.0
     line = bench.headline(out)
     assert line["serve_qps"] == 2650.0

@@ -157,8 +157,7 @@ def test_phase_kernel_microverdicts_banks_incrementally(capsys):
     """The bare-kernel verdict phase emits one record per measurement
     the moment it exists (kernel_flash -> kernel_flash_vs_full ->
     kernel_topk -> kernel_topk_vs_dense), each preceded by a progress
-    heartbeat — a relay death at any point keeps everything banked so
-    far.  Tiny shapes; interpret-mode flash off-TPU."""
+    heartbeat — a kill at any point keeps everything banked so far.  Tiny shapes; interpret-mode flash off-TPU."""
     import argparse
     import json
 
@@ -234,11 +233,9 @@ def test_phase_kernel_microverdicts_windowed_witness(capsys):
     assert rec["windowed_step_ms"] > 0
 
 
-def test_apply_config_n_layers_sentinel():
-    """--n-layers default is a None sentinel so the confirm-first
-    tunneled-TPU path can tell 'unset' (downshift to live-window depth)
-    from an explicit operator choice (always wins, even at --config
-    small)."""
+def test_apply_config_n_layers_default_and_override():
+    """--n-layers defaults by config (8 big / 2 small); an explicit
+    value always wins."""
     import argparse
 
     from benchmarks.suite_device import apply_config
@@ -249,14 +246,20 @@ def test_apply_config_n_layers_sentinel():
             n_heads=8, seq_instances=2, width=640, height=480,
         )
 
-    a = apply_config(ns("big", None))
-    assert a.n_layers == 8 and a.n_layers_explicit is False
-    a = apply_config(ns("small", None))
-    assert a.n_layers == 2 and a.n_layers_explicit is False
-    a = apply_config(ns("small", 4))
-    assert a.n_layers == 4 and a.n_layers_explicit is True
-    a = apply_config(ns("big", 2))
-    assert a.n_layers == 2 and a.n_layers_explicit is True
+    assert apply_config(ns("big", None)).n_layers == 8
+    assert apply_config(ns("small", None)).n_layers == 2
+    assert apply_config(ns("small", 4)).n_layers == 4
+    assert apply_config(ns("big", 2)).n_layers == 2
+
+
+def test_peak_flops_raises_on_unknown_device():
+    """A device that is not in the peak table is an error, not a
+    default (here: the CPU backend)."""
+    from benchmarks.suite_device import mfu_peak, peak_flops
+
+    with pytest.raises(LookupError):
+        peak_flops()
+    assert mfu_peak({"platform": "cpu"}) is None
 
 
 def test_phase_put_strategy_emits_winner_and_loser(capsys):

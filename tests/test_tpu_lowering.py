@@ -15,10 +15,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "export"), reason="jax.export unavailable"
-)
-
 
 def _export_ok(fn, *args):
     exp = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)

@@ -1143,7 +1143,7 @@ def test_bench_headline_carries_weight_metrics():
         "swaps_observed": 8, "swap_ms_all": [], "publish_ms_p50": 2.9,
         "weight_counters": {}, "stages": {},
     }
-    out = bench.assemble({}, host_fallback=lambda: 1.0,
+    out = bench.assemble({"host_stream": {"items_per_sec": 1.0}},
                          weight_bench=wb)
     assert out["weight_bench"]["weight_swap_ms"] == 6.1
     line = bench.headline(out)
