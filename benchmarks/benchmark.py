@@ -120,15 +120,12 @@ def run(args):
                 "xy": batch["xy"].astype(np.float32),
             }
 
-        from blendjax.utils.timing import StageTimer
-
         stream = JaxStream(
             ds,
             batch_size=args.batch,
             num_workers=args.workers,
             transform=transform,
             prefetch=args.prefetch,
-            timer=StageTimer(trace=True) if args.trace else None,
         )
 
         # Two stopping modes: fixed item count (args.items drives stream
@@ -178,6 +175,10 @@ def run(args):
                     if overdue and train_alive:
                         train_alive = False  # degrade: measure the feed only
                     if warm or overdue:
+                        if args.trace:
+                            # the measured window under the profiler: the
+                            # stream's stages are spans in its trace
+                            jax.profiler.start_trace(args.trace)
                         t0 = time.perf_counter()
                         step_time = 0.0
                     continue
@@ -199,6 +200,8 @@ def run(args):
             # not be billed to the measurement
             elapsed = time.perf_counter() - t0 if t0 is not None else None
         finally:
+            if args.trace and t0 is not None:
+                jax.profiler.stop_trace()
             it.close()  # unwinds the prefetch thread promptly
             stream.close()
         if t0 is None or measured == 0:
@@ -207,10 +210,10 @@ def run(args):
 
         stats = stream.timer.summary()
         if args.trace:
-            n_events = stream.timer.export_chrome_trace(args.trace)
             print(
-                f"wrote {n_events} trace events to {args.trace} "
-                "(chrome://tracing / Perfetto)",
+                f"wrote a jax.profiler trace under {args.trace} (feed "
+                "stages beside the device's operations; open it with "
+                "xprof / TensorBoard, or jax.profiler.ProfileData)",
                 file=sys.stderr,
             )
         return {
@@ -254,10 +257,11 @@ def parse_args(argv=None):
     ap.add_argument("--warmup-batches", type=int, default=8)
     ap.add_argument(
         "--trace",
-        metavar="PATH",
+        metavar="DIR",
         default=None,
-        help="record per-stage intervals and write a Chrome trace-event "
-        "JSON (chrome://tracing / Perfetto) to PATH",
+        help="run the measured window under jax.profiler and write its "
+        "trace directory to DIR: the feed's StageTimer stages are host "
+        "spans in it, beside the device's operations",
     )
     ap.add_argument(
         "--prefetch",

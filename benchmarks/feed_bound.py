@@ -151,7 +151,7 @@ def _run_arena_instrumented(msgs, batch, seconds, pool_size, timer,
         s0 = clock()
         arena = pool.acquire()
         s1 = clock()
-        add("arena_wait", s1 - s0, _t0=s0)
+        add("arena_wait", s1 - s0)
         builder.reset(arena)
         addmsg = builder.add_message
         for j in range(batch):
@@ -159,11 +159,11 @@ def _run_arena_instrumented(msgs, batch, seconds, pool_size, timer,
         s2 = clock()
         out = builder.finish()
         s3 = clock()
-        add("scatter", s3 - s2, _t0=s2)
+        add("scatter", s3 - s2)
         out["image"][0, 0, 0, 0]  # trivial train step: touch the batch
         s4 = clock()
         arena.release()
-        add("recycle", clock() - s4, _t0=s4)
+        add("recycle", clock() - s4)
         i += batch
         n += 1
     dt = clock() - t0

@@ -190,7 +190,7 @@ class AutoscaleController:
             return self._decide()
         finally:
             self.timer.add("autoscale_tick",
-                           time.perf_counter() - t0, _t0=t0)
+                           time.perf_counter() - t0)
 
     def _adopt(self):
         """Idempotence against a controller death mid-transition: a
@@ -314,7 +314,7 @@ class AutoscaleController:
         self._transition = None
         self._cooldown_until["up"] = now + self.cooldown_up_s
         dt = now - tr["t0"]
-        self.timer.add("autoscale_resize", dt, _t0=tr["t0"])
+        self.timer.add("autoscale_resize", dt)
         self.counters.incr("autoscale_scale_ups")
         logger.warning(
             "autoscale: scale-up committed — %s healthy through the "
@@ -351,7 +351,7 @@ class AutoscaleController:
         if tr["stage"] == "drain":
             if self.gateway.lease_count(rid) == 0:
                 dt = now - tr["t0"]
-                self.timer.add("autoscale_drain", dt, _t0=tr["t0"])
+                self.timer.add("autoscale_drain", dt)
                 tr["stage"] = "verify"
                 tr["deadline"] = now + self.healthy_window_s
                 logger.info(
@@ -398,7 +398,7 @@ class AutoscaleController:
         self._transition = None
         self._cooldown_until["down"] = now + self.cooldown_down_s
         dt = now - tr["t0"]
-        self.timer.add("autoscale_resize", dt, _t0=tr["t0"])
+        self.timer.add("autoscale_resize", dt)
         self.counters.incr("autoscale_replicas_retired")
         self.counters.incr("autoscale_scale_downs")
         logger.warning(

@@ -96,7 +96,7 @@ def reshard_replay(replay, fleet, *, source=None, fraction=0.5,
         fleet.retire(idx)
         raise
     dt = time.perf_counter() - t0
-    timer.add("autoscale_resize", dt, _t0=t0)
+    timer.add("autoscale_resize", dt)
     logger.warning(
         "reshard: shard %d live at %s, %d shards serving (%.2fs "
         "decision-to-settle)", shard, addr, replay.num_shards, dt,

@@ -290,7 +290,7 @@ class TrainCheckpointer:
             return None
         finally:
             dt = time.perf_counter() - t0
-            self.timer.add("ha_snapshot", dt, _t0=t0)
+            self.timer.add("ha_snapshot", dt)
         if dt > self.stall_budget_s:
             now = time.monotonic()
             if now >= self._next_stall_warn:
@@ -359,8 +359,7 @@ class TrainCheckpointer:
                 "covering recovery)", update,
             )
         finally:
-            self.timer.add("ha_serialize", time.perf_counter() - t0,
-                           _t0=t0)
+            self.timer.add("ha_serialize", time.perf_counter() - t0)
 
     def _retain(self):
         paths = _manifest_paths(self.directory)
@@ -420,7 +419,7 @@ class TrainCheckpointer:
         self._last_ckpt_update = int(manifest["update"])
         self._last_ckpt_time = time.monotonic()
         self.counters.incr("ha_restores")
-        self.timer.add("ha_restore", time.perf_counter() - t0, _t0=t0)
+        self.timer.add("ha_restore", time.perf_counter() - t0)
         flight_recorder.note(
             "learner_restored", target="learner",
             update=int(manifest["update"]),

@@ -72,6 +72,7 @@ def _ln_init(d):
     return {"scale": jnp.ones((d,)), "bias": jnp.zeros((d,))}
 
 
+@jax.named_scope("ln")
 def _ln_apply(p, x):
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
@@ -213,40 +214,42 @@ def _forward(params, obs, attn_fn, compute_dtype, moe_impl, moe_k,
     else:
         x = x + params["pos"][:t].astype(compute_dtype)[None]
     for blk in params["blocks"]:
-        h = _ln_apply(blk["ln1"], x)
-        q, k, v = (
-            _proj_mq(blk[n], h, "btd,dhk->bthk", compute_dtype)
-            for n in ("wq", "wk", "wv")
-        )
-        if use_rope:
-            # rotate BEFORE the kv sink and the attn seam: caches store
-            # rotated keys, and every attention scheme sees pre-rotated
-            # q/k (rotation by absolute position makes scores relative)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        if kv_sink is not None:
-            kv_sink.append((k, v))
-        a = attn_fn(q, k, v)
-        x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd", compute_dtype)
-        h = _ln_apply(blk["ln2"], x)
-        if "moe" in blk:
-            if moe_impl == "topk":
-                from blendjax.models.moe import moe_apply_topk
+        with jax.named_scope("attn"):
+            h = _ln_apply(blk["ln1"], x)
+            q, k, v = (
+                _proj_mq(blk[n], h, "btd,dhk->bthk", compute_dtype)
+                for n in ("wq", "wk", "wv")
+            )
+            if use_rope:
+                # rotate BEFORE the kv sink and the attn seam: caches store
+                # rotated keys, and every attention scheme sees pre-rotated
+                # q/k (rotation by absolute position makes scores relative)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            if kv_sink is not None:
+                kv_sink.append((k, v))
+            a = attn_fn(q, k, v)
+            x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd", compute_dtype)
+        with jax.named_scope("mlp"):
+            h = _ln_apply(blk["ln2"], x)
+            if "moe" in blk:
+                if moe_impl == "topk":
+                    from blendjax.models.moe import moe_apply_topk
 
-                y, aux = moe_apply_topk(
-                    blk["moe"], h, compute_dtype, k=moe_k,
-                    capacity_factor=moe_capacity_factor,
-                    dispatch=moe_dispatch,
-                )
-                auxs.append(aux)
-                x = x + y
-            elif moe_impl == "dense":
-                x = x + _moe_apply(blk["moe"], h, compute_dtype)
+                    y, aux = moe_apply_topk(
+                        blk["moe"], h, compute_dtype, k=moe_k,
+                        capacity_factor=moe_capacity_factor,
+                        dispatch=moe_dispatch,
+                    )
+                    auxs.append(aux)
+                    x = x + y
+                elif moe_impl == "dense":
+                    x = x + _moe_apply(blk["moe"], h, compute_dtype)
+                else:
+                    raise ValueError(f"unknown moe_impl {moe_impl!r}")
             else:
-                raise ValueError(f"unknown moe_impl {moe_impl!r}")
-        else:
-            h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
-            x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
+                h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
+                x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
     x = _ln_apply(params["ln_f"], x)
     return _dense_mq(params["head"], x, jnp.float32), auxs
 
@@ -347,7 +350,9 @@ def episode_loss_fn(params, batch, **kwargs):
     device-side shift would need a cross-shard neighbor exchange there
     (see :func:`loss_fn`'s note on the sharded target).
     """
-    return loss_fn(params, make_episode_batch(batch["episode"]), **kwargs)
+    with jax.named_scope("loss"):
+        return loss_fn(
+            params, make_episode_batch(batch["episode"]), **kwargs)
 
 
 def train_flops(batch_size, seq_len, obs_dim, d_model, n_heads, n_layers,
@@ -544,68 +549,70 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     new_cache = {"k": [], "v": [], "pos": pos + 1}
     rows = jnp.arange(obs_t.shape[0]) if per_row else None
     for i, blk in enumerate(params["blocks"]):
-        h = _ln_apply(blk["ln1"], x)
-        q = _proj_mq(blk["wq"], h, "bd,dhk->bhk", compute_dtype)
-        k_new = _proj_mq(blk["wk"], h, "bd,dhk->bhk", compute_dtype)
-        v_new = _proj_mq(blk["wv"], h, "bd,dhk->bhk", compute_dtype)
-        if use_rope:
+        with jax.named_scope("attn"):
+            h = _ln_apply(blk["ln1"], x)
+            q = _proj_mq(blk["wq"], h, "bd,dhk->bhk", compute_dtype)
+            k_new = _proj_mq(blk["wk"], h, "bd,dhk->bhk", compute_dtype)
+            v_new = _proj_mq(blk["wv"], h, "bd,dhk->bhk", compute_dtype)
+            if use_rope:
+                if per_row:
+                    q = apply_rope_rows(q, cos, sin)
+                    k_new = apply_rope_rows(k_new, cos, sin)
+                else:
+                    q = apply_rope(q, cos, sin)
+                    k_new = apply_rope(k_new, cos, sin)
+            slot = pos % cache["k"][i].shape[1]  # ring buffer (see _attn_one)
             if per_row:
-                q = apply_rope_rows(q, cos, sin)
-                k_new = apply_rope_rows(k_new, cos, sin)
-            else:
-                q = apply_rope(q, cos, sin)
-                k_new = apply_rope(k_new, cos, sin)
-        slot = pos % cache["k"][i].shape[1]  # ring buffer (see _attn_one)
-        if per_row:
-            # scatter each row's k/v at ITS ring slot
-            kc = cache["k"][i].at[rows, slot].set(
-                k_new.astype(cache["k"][i].dtype)
-            )
-            vc = cache["v"][i].at[rows, slot].set(
-                v_new.astype(cache["v"][i].dtype)
-            )
-        else:
-            kc = lax.dynamic_update_slice_in_dim(
-                cache["k"][i], k_new[:, None].astype(cache["k"][i].dtype),
-                slot, axis=1,
-            )
-            vc = lax.dynamic_update_slice_in_dim(
-                cache["v"][i], v_new[:, None].astype(cache["v"][i].dtype),
-                slot, axis=1,
-            )
-        new_cache["k"].append(kc)
-        new_cache["v"].append(vc)
-        dh = q.shape[-1]
-        a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
-                      window=window).astype(compute_dtype)
-        x = x + _proj_mq(blk["wo"], a, "bhk,hkd->bd", compute_dtype)
-        h = _ln_apply(blk["ln2"], x)
-        if "moe" in blk:
-            h3 = h[:, None]  # the moe layers take (B, T, d)
-            if moe_impl == "topk":
-                from blendjax.models.moe import moe_apply_topk
-
-                # decode-time routing is DROP-FREE: the capacity bound
-                # exists to balance batched training dispatch, and its
-                # value depends on the total token count — so
-                # capacity-bounded routing is not causal and can never
-                # match between incremental and full-sequence evaluation.
-                # cf >= e/k guarantees a slot for every assignment here.
-                e = blk["moe"]["w1"].shape[0]
-                y, _ = moe_apply_topk(
-                    blk["moe"], h3, compute_dtype, k=moe_k,
-                    capacity_factor=max(moe_capacity_factor,
-                                        e / min(moe_k, e)),
-                    dispatch=moe_dispatch,
+                # scatter each row's k/v at ITS ring slot
+                kc = cache["k"][i].at[rows, slot].set(
+                    k_new.astype(cache["k"][i].dtype)
                 )
-            elif moe_impl == "dense":
-                y = _moe_apply(blk["moe"], h3, compute_dtype)
+                vc = cache["v"][i].at[rows, slot].set(
+                    v_new.astype(cache["v"][i].dtype)
+                )
             else:
-                raise ValueError(f"unknown moe_impl {moe_impl!r}")
-            x = x + y[:, 0]
-        else:
-            h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
-            x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
+                kc = lax.dynamic_update_slice_in_dim(
+                    cache["k"][i], k_new[:, None].astype(cache["k"][i].dtype),
+                    slot, axis=1,
+                )
+                vc = lax.dynamic_update_slice_in_dim(
+                    cache["v"][i], v_new[:, None].astype(cache["v"][i].dtype),
+                    slot, axis=1,
+                )
+            new_cache["k"].append(kc)
+            new_cache["v"].append(vc)
+            dh = q.shape[-1]
+            a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
+                          window=window).astype(compute_dtype)
+            x = x + _proj_mq(blk["wo"], a, "bhk,hkd->bd", compute_dtype)
+        with jax.named_scope("mlp"):
+            h = _ln_apply(blk["ln2"], x)
+            if "moe" in blk:
+                h3 = h[:, None]  # the moe layers take (B, T, d)
+                if moe_impl == "topk":
+                    from blendjax.models.moe import moe_apply_topk
+
+                    # decode-time routing is DROP-FREE: the capacity bound
+                    # exists to balance batched training dispatch, and its
+                    # value depends on the total token count — so
+                    # capacity-bounded routing is not causal and can never
+                    # match between incremental and full-sequence evaluation.
+                    # cf >= e/k guarantees a slot for every assignment here.
+                    e = blk["moe"]["w1"].shape[0]
+                    y, _ = moe_apply_topk(
+                        blk["moe"], h3, compute_dtype, k=moe_k,
+                        capacity_factor=max(moe_capacity_factor,
+                                            e / min(moe_k, e)),
+                        dispatch=moe_dispatch,
+                    )
+                elif moe_impl == "dense":
+                    y = _moe_apply(blk["moe"], h3, compute_dtype)
+                else:
+                    raise ValueError(f"unknown moe_impl {moe_impl!r}")
+                x = x + y[:, 0]
+            else:
+                h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
+                x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
     x = _ln_apply(params["ln_f"], x)
     return _dense_mq(params["head"], x, jnp.float32), new_cache
 

@@ -41,13 +41,17 @@ def make_train_step(loss_fn, optimizer=None, donate=True):
     """
     optimizer = optimizer or optax.adam(1e-3)
 
-    def step(state, batch):
+    # the function's name is the jitted module's in a profiler trace and
+    # in the compile log
+    def train_step(state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1), loss
 
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
 
 def make_eval_step(loss_fn):

@@ -443,6 +443,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_kv, interpret,
             (block_q, d), (block_q, 128), (block_q, 128)
         ]),
         interpret=resolve_interpret(interpret),
+        name="flash_fwd",
     )(qf, kf, vf)
     return _unflat(of, b, h), (qf, kf, vf, of, lse)
 
@@ -546,6 +547,7 @@ def _dq_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
         out_shape=_sds((bh, tq, d), out_dtype or qf.dtype, qf),
         scratch_shapes=_scratch([(block_q, d)]),
         interpret=resolve_interpret(interpret),
+        name="flash_bwd_dq",
     )(qf, kf, vf, dof, lse, delta)
 
 
@@ -596,6 +598,7 @@ def _dkv_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
         ],
         scratch_shapes=_scratch([(block_kv, d), (block_kv, d)]),
         interpret=resolve_interpret(interpret),
+        name="flash_bwd_dkv",
     )(qf, kf, vf, dof, lse, delta)
 
 

@@ -112,6 +112,7 @@ def decode_frames_pallas(
         in_specs=[pl.BlockSpec((block_rows, _LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, _LANE), lambda i: (i, 0)),
         interpret=resolve_interpret(interpret),
+        name="decode_frames",
     )(x2d)
     return out.reshape(-1)[:total].reshape(orig_shape)
 

@@ -294,7 +294,7 @@ class ReplayBuffer:
             self._appends += 1
             self.counters.incr("replay_appends")
             self._cond.notify_all()
-        self.timer.add("replay_append", time.perf_counter() - t0, _t0=t0)
+        self.timer.add("replay_append", time.perf_counter() - t0)
         return slot
 
     def extend(self, transitions, *, healthy=None, scenarios=None):
@@ -478,13 +478,13 @@ class ReplayBuffer:
                 while self._num_valid < need:
                     if stop_event is not None and stop_event.is_set():
                         self.timer.add(
-                            "sample_wait", time.perf_counter() - t0, _t0=t0
+                            "sample_wait", time.perf_counter() - t0
                         )
                         return None
                     remaining = deadline - time.monotonic()
                     if remaining <= 0:
                         self.timer.add(
-                            "sample_wait", time.perf_counter() - t0, _t0=t0
+                            "sample_wait", time.perf_counter() - t0
                         )
                         raise TimeoutError(
                             f"{self.name}: underfilled — {self._num_valid} "
@@ -498,7 +498,7 @@ class ReplayBuffer:
                         waited = True
                         self.counters.incr("replay_sample_waits")
                     self._cond.wait(min(0.1, remaining))
-                self.timer.add("sample_wait", time.perf_counter() - t0, _t0=t0)
+                self.timer.add("sample_wait", time.perf_counter() - t0)
             t0 = time.perf_counter()
             mix = self._effective_mix_locked(scenario_mix)
             if mix is None:
@@ -513,7 +513,7 @@ class ReplayBuffer:
             data = self.store.gather(idx, out=out, keys=keys)
             self._samples += 1
             self.counters.incr("replay_samples")
-        self.timer.add("sample_gather", time.perf_counter() - t0, _t0=t0)
+        self.timer.add("sample_gather", time.perf_counter() - t0)
         return data, idx, weights
 
     def update_priorities(self, indices, priorities):
@@ -550,7 +550,7 @@ class ReplayBuffer:
                 self._max_priority = max(self._max_priority, tp)
                 self.tree.set(int(i), tp)
             self.counters.incr("replay_priority_updates")
-        self.timer.add("priority_update", time.perf_counter() - t0, _t0=t0)
+        self.timer.add("priority_update", time.perf_counter() - t0)
 
     def sample_batches(self, batch_size, *, arena_pool=None, beta=None,
                        stop_event=None, timeout=30.0, keys=None,

@@ -319,7 +319,7 @@ class ReplayShard:
             f"shard_srv_{cmd}"
             if hasattr(self, f"_cmd_{cmd}") else "shard_srv_unknown"
         )
-        self.timer.add(stage, time.perf_counter() - t0, _t0=t0)
+        self.timer.add(stage, time.perf_counter() - t0)
         if isinstance(span_ctx, dict) and span_ctx.get("trace") is not None:
             reply[wire.SPANS_KEY] = [make_span(
                 f"shard{self.shard_id}:{cmd}", t0_us,
@@ -542,8 +542,7 @@ class ReplayShard:
                 # the reservation must never dangle
                 views[0][: min(8, len(head_bytes))] = 0
             self._shm.commit_send(chan)
-        self.timer.add("shard_srv_gather", time.perf_counter() - t0,
-                       _t0=t0)
+        self.timer.add("shard_srv_gather", time.perf_counter() - t0)
         return True
 
     def serve_forever(self, stop_event=None, poll_ms=100):

@@ -250,7 +250,7 @@ class _ShardedStore:
             o._journal_row_locked(slot, row)
             return
         finally:
-            o.timer.add("shard_append", time.perf_counter() - t0, _t0=t0)
+            o.timer.add("shard_append", time.perf_counter() - t0)
         o._acked[s] += 1
 
     def read_row(self, slot):
@@ -323,7 +323,7 @@ class _ShardedStore:
                 if exc is not None:
                     raise exc
         finally:
-            o.timer.add("shard_gather", time.perf_counter() - t0, _t0=t0)
+            o.timer.add("shard_gather", time.perf_counter() - t0)
         return batch
 
     def _fetch_shard(self, job, selected, batch):
@@ -1149,7 +1149,7 @@ class ShardedReplay(ReplayBuffer):
             self._owner[moved] = t
             self._cond.notify_all()
         dt = time.perf_counter() - t0
-        self.timer.add("autoscale_handoff", dt, _t0=t0)
+        self.timer.add("autoscale_handoff", dt)
         self.counters.incr("autoscale_reshard_handoffs")
         self.counters.incr("autoscale_reshard_rows_copied", len(delta))
         flight_recorder.note(

@@ -218,8 +218,7 @@ class CurriculumScheduler:
         self.counters.incr("scenario_curriculum_updates")
         if changed:
             self.counters.incr("scenario_mix_changes")
-        self.timer.add("scenario_reweight", time.perf_counter() - t0,
-                       _t0=t0)
+        self.timer.add("scenario_reweight", time.perf_counter() - t0)
         return dict(fresh)
 
     def tick(self, scenario_stats_fn=None):

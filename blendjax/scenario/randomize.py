@@ -164,8 +164,7 @@ class DomainRandomizer:
         t0 = time.perf_counter()
         params = spec.sample(self._rng)
         self.counters.incr("scenario_samples")
-        self.timer.add("scenario_sample", time.perf_counter() - t0,
-                       _t0=t0)
+        self.timer.add("scenario_sample", time.perf_counter() - t0)
         return params
 
     def assign(self, fleet_id, scenario, *, fresh_channel=False,
@@ -208,7 +207,7 @@ class DomainRandomizer:
         except zmq.Again:
             self.counters.incr("scenario_push_failures")
             self.timer.add("scenario_push",
-                           time.perf_counter() - t0, _t0=t0)
+                           time.perf_counter() - t0)
             logger.warning(
                 "scenario push to fleet %d env %d timed out "
                 "(producer dead or stalled); continuing", f, i,
@@ -217,13 +216,13 @@ class DomainRandomizer:
         except zmq.ZMQError as exc:
             self.counters.incr("scenario_push_failures")
             self.timer.add("scenario_push",
-                           time.perf_counter() - t0, _t0=t0)
+                           time.perf_counter() - t0)
             logger.warning(
                 "scenario push to fleet %d env %d failed (%s)", f, i, exc,
             )
             return False
         self.counters.incr("scenario_pushes")
-        self.timer.add("scenario_push", time.perf_counter() - t0, _t0=t0)
+        self.timer.add("scenario_push", time.perf_counter() - t0)
         return True
 
     def apply_assignment(self, assignment):

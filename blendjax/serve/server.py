@@ -70,7 +70,7 @@ import numpy as np
 from blendjax import wire
 from blendjax.btt import shm_rpc
 from blendjax.obs.spans import make_span, now_us
-from blendjax.utils.timing import StageTimer, fleet_counters
+from blendjax.utils.timing import StageTimer, fleet_counters, span
 
 logger = logging.getLogger("blendjax")
 
@@ -329,31 +329,36 @@ class SeqFormerModel:
         )
         self._jnp = jnp
 
-        def _step(params, cache, idx, obs):
-            rows = {
-                "pos": cache["pos"][idx],
-                "k": [k[idx] for k in cache["k"]],
-                "v": [v[idx] for v in cache["v"]],
-            }
-            pred, new = seqformer.decode_step(
-                params, rows, obs, compute_dtype=cdt, window=window,
-            )
+        # the functions' names and scopes are what a profiler trace
+        # and the compile log show: keep them (PERF.md section 3)
+        def serve_step(params, cache, idx, obs):
+            with jax.named_scope("gather"):
+                rows = {
+                    "pos": cache["pos"][idx],
+                    "k": [k[idx] for k in cache["k"]],
+                    "v": [v[idx] for v in cache["v"]],
+                }
+            with jax.named_scope("decode"):
+                pred, new = seqformer.decode_step(
+                    params, rows, obs, compute_dtype=cdt, window=window,
+                )
             # scatter the stepped rows back; padding duplicates all
             # land on the pad row, whose contents are never read
-            cache = {
-                "pos": cache["pos"].at[idx].set(new["pos"]),
-                "k": [c.at[idx].set(nk)
-                      for c, nk in zip(cache["k"], new["k"])],
-                "v": [c.at[idx].set(nv)
-                      for c, nv in zip(cache["v"], new["v"])],
-            }
+            with jax.named_scope("scatter"):
+                cache = {
+                    "pos": cache["pos"].at[idx].set(new["pos"]),
+                    "k": [c.at[idx].set(nk)
+                          for c, nk in zip(cache["k"], new["k"])],
+                    "v": [c.at[idx].set(nv)
+                          for c, nv in zip(cache["v"], new["v"])],
+                }
             return pred, cache
 
         # one compilation per (bucket,) shape — the bucket/recompile
         # tradeoff the admission queue pads for
-        self._step = jax.jit(_step)
+        self._step = jax.jit(serve_step)
 
-        def _prefill(params, cache, row, prefix):
+        def serve_prefill(params, cache, row, prefix):
             # ONE teacher-forced pass fills the slot's KV rows (the
             # standard prefill/decode split, exactly rollout()'s
             # prefill phase) instead of T serial decode_steps.  k/v
@@ -364,31 +369,36 @@ class SeqFormerModel:
             from blendjax.parallel.ring_attention import full_attention
 
             kvs = []
-            preds, _ = seqformer._forward(
-                params, prefix[None],
-                lambda q, k, v: full_attention(
-                    q, k, v, causal=True, window=window
-                ),
-                cdt, "dense", 2, 1.25, kv_sink=kvs,
-            )
+            with jax.named_scope("forward"):
+                preds, _ = seqformer._forward(
+                    params, prefix[None],
+                    lambda q, k, v: full_attention(
+                        q, k, v, causal=True, window=window
+                    ),
+                    cdt, "dense", 2, 1.25, kv_sink=kvs,
+                )
             t0 = prefix.shape[0]
             ring = cache["k"][0].shape[1]
             keep_n = min(t0, ring)
             slots_ax = (jnp.arange(keep_n) + (t0 - keep_n)) % ring
-            new = {"pos": cache["pos"].at[row].set(t0), "k": [], "v": []}
-            for i, (k, v) in enumerate(kvs):
-                new["k"].append(cache["k"][i].at[row[0], slots_ax].set(
-                    k[0, t0 - keep_n:].astype(cache["k"][i].dtype)
-                ))
-                new["v"].append(cache["v"][i].at[row[0], slots_ax].set(
-                    v[0, t0 - keep_n:].astype(cache["v"][i].dtype)
-                ))
+            with jax.named_scope("scatter"):
+                new = {"pos": cache["pos"].at[row].set(t0),
+                       "k": [], "v": []}
+                for i, (k, v) in enumerate(kvs):
+                    new["k"].append(
+                        cache["k"][i].at[row[0], slots_ax].set(
+                            k[0, t0 - keep_n:].astype(cache["k"][i].dtype)
+                        ))
+                    new["v"].append(
+                        cache["v"][i].at[row[0], slots_ax].set(
+                            v[0, t0 - keep_n:].astype(cache["v"][i].dtype)
+                        ))
             return preds[0, -1], new
 
         # one compilation per prefix LENGTH (prefix rows are real
         # observations — padding them would write fabricated positions
         # into the cache, so lengths are not bucketed)
-        self._prefill = jax.jit(_prefill)
+        self._prefill = jax.jit(serve_prefill)
 
     def prefill_rows(self, idx, prefix):
         """Admit a T-step observation prefix into slot ``idx`` with one
@@ -414,11 +424,13 @@ class SeqFormerModel:
                 f"table ({self.params['pos'].shape[0]}); use "
                 "pos_encoding='rope' for longer prefixes"
             )
-        pred, self._cache = self._prefill(
-            self.params, self._cache, self._jnp.asarray(idx),
-            self._jnp.asarray(prefix),
-        )
-        return np.asarray(pred)
+        with span("serve.prefill.dispatch"):
+            pred, self._cache = self._prefill(
+                self.params, self._cache, self._jnp.asarray(idx),
+                self._jnp.asarray(prefix),
+            )
+        with span("serve.prefill.fence"):
+            return np.asarray(pred)
 
     def apply_weights(self, tree):
         """WeightBus hot-swap: adopt a published seqformer pytree (the
@@ -446,16 +458,20 @@ class SeqFormerModel:
         # rewinding pos to 0 is sufficient: _attn_one masks by each
         # slot's absolute position, so the stale k/v rows of the slot's
         # previous tenant sit at negative positions and never attend
-        self._cache["pos"] = self._cache["pos"].at[
-            self._jnp.asarray(idx)
-        ].set(0)
+        with span("serve.reset_rows"):
+            self._cache["pos"] = self._cache["pos"].at[
+                self._jnp.asarray(idx)
+            ].set(0)
 
     def step_rows(self, idx, obs):
-        pred, self._cache = self._step(
-            self.params, self._cache, self._jnp.asarray(idx),
-            self._jnp.asarray(obs),
-        )
-        return np.asarray(pred)  # fence: compute timing stays honest
+        with span("serve.step.dispatch"):
+            pred, self._cache = self._step(
+                self.params, self._cache, self._jnp.asarray(idx),
+                self._jnp.asarray(obs),
+            )
+        with span("serve.step.fence"):
+            # fence: compute timing stays honest
+            return np.asarray(pred)
 
 
 # ---------------------------------------------------------------------------
@@ -800,11 +816,17 @@ class PolicyServer:
                 f"prefix shape {prefix.shape} != (T >= 1, "
                 f"{st.model.obs_dim})"
             )
+        t0 = time.perf_counter()
         try:
-            pred = st.model.prefill_rows(np.asarray([slot]), prefix)
+            with span("serve.prefill", len=int(prefix.shape[0])):
+                pred = st.model.prefill_rows(np.asarray([slot]), prefix)
         except Exception as exc:  # noqa: BLE001 - surfaced to client
             logger.exception("policy server: prefill failed")
             return fail(f"prefill failed: {type(exc).__name__}: {exc}")
+        # the one record of the time the server's thread spent in
+        # prefill (no tick can start meanwhile)
+        self.counters.incr("serve_prefill_us",
+                           int((time.perf_counter() - t0) * 1e6))
         self.counters.incr("serve_prefills")
         # the prediction for position T (what the T'th serial step
         # would have returned) and the position the next step consumes
@@ -943,7 +965,8 @@ class PolicyServer:
                     f"snapshot for unhosted model {target!r} "
                     f"(hosted: {sorted(self._models)})"
                 )
-            st.model.apply_weights(snap.tree())
+            with span("serve.weights"):
+                st.model.apply_weights(snap.tree())
         except Exception as exc:  # noqa: BLE001 - keep serving last good
             self.counters.incr("weight_apply_failed")
             logger.warning(
@@ -1114,119 +1137,126 @@ class PolicyServer:
         left in order and the return value says so, so the serve loop
         ticks again immediately instead of making them wait out another
         admission window."""
-        t_assemble = time.perf_counter()
-        head = None
-        skipped = deque()
-        batch = []
-        while self._queue and len(batch) < self.max_batch:
-            ent = self._queue.popleft()
-            if head is None:
-                head = ent.mstate
-            elif ent.mstate is not head:
-                skipped.append(ent)
-                continue
-            if ent.mid is not None:
-                self._pending.pop(ent.mid, None)
-            st = ent.mstate
-            stateful = st.model.slots > 0
-            slot = int(ent.msg.get("slot", -1)) if stateful else -1
-            if not stateful:
-                ep = ent.msg.get("episode")
-                if ep is not None:
-                    # touch (or re-register, after a server restart)
-                    # the episode's liveness for window targeting —
-                    # stateless steps are never refused
-                    st.stateless_eps[ep] = time.monotonic()
-            if stateful:
-                lease = st.live.get(slot)
-                if lease is None:
-                    self._step_entry_error(ent, (
-                        f"unknown episode slot {slot} (closed, evicted, "
-                        "or a restarted server): reset() and resume"
-                    ), lease="unknown")
-                    continue
-                if ent.msg.get("episode") not in (None, lease[0]):
-                    # slot number reused by a NEW episode: the stale
-                    # client must not advance the new tenant's cache
-                    self._step_entry_error(ent, (
-                        f"stale episode lease for slot {slot} (evicted "
-                        "and reassigned): reset() and resume"
-                    ), lease="stale")
-                    continue
-            try:
-                obs = np.asarray(ent.msg.get("obs"), np.float32)
-            except (TypeError, ValueError) as exc:
-                self._step_entry_error(
-                    ent, f"step obs not coercible to float32: {exc}"
-                )
-                continue
-            if obs.shape != (head.model.obs_dim,):
-                self._step_entry_error(ent, (
-                    f"step obs shape {obs.shape} != "
-                    f"({head.model.obs_dim},)"
-                ))
-                continue
-            batch.append((ent, slot, obs))
-        # skipped other-model entries return to the FRONT in order:
-        # they are older than anything still queued behind them —
-        # ``more`` asks the serve loop to tick again NOW for them
-        # (same-model overflow keeps the admission-window pacing)
-        more = bool(skipped)
-        while skipped:
-            self._queue.appendleft(skipped.pop())
-        if not batch:
+        with span("serve.tick") as tick:
+            with span("serve.tick.assemble"):
+                t_assemble = time.perf_counter()
+                head = None
+                skipped = deque()
+                batch = []
+                while self._queue and len(batch) < self.max_batch:
+                    ent = self._queue.popleft()
+                    if head is None:
+                        head = ent.mstate
+                    elif ent.mstate is not head:
+                        skipped.append(ent)
+                        continue
+                    if ent.mid is not None:
+                        self._pending.pop(ent.mid, None)
+                    st = ent.mstate
+                    stateful = st.model.slots > 0
+                    slot = int(ent.msg.get("slot", -1)) if stateful else -1
+                    if not stateful:
+                        ep = ent.msg.get("episode")
+                        if ep is not None:
+                            # touch (or re-register, after a server
+                            # restart) the episode's liveness for window
+                            # targeting — stateless steps are never refused
+                            st.stateless_eps[ep] = time.monotonic()
+                    if stateful:
+                        lease = st.live.get(slot)
+                        if lease is None:
+                            self._step_entry_error(ent, (
+                                f"unknown episode slot {slot} (closed, "
+                                "evicted, or a restarted server): reset() "
+                                "and resume"
+                            ), lease="unknown")
+                            continue
+                        if ent.msg.get("episode") not in (None, lease[0]):
+                            # slot number reused by a NEW episode: the
+                            # stale client must not advance the new
+                            # tenant's cache
+                            self._step_entry_error(ent, (
+                                f"stale episode lease for slot {slot} "
+                                "(evicted and reassigned): reset() and resume"
+                            ), lease="stale")
+                            continue
+                    try:
+                        obs = np.asarray(ent.msg.get("obs"), np.float32)
+                    except (TypeError, ValueError) as exc:
+                        self._step_entry_error(
+                            ent, f"step obs not coercible to float32: {exc}"
+                        )
+                        continue
+                    if obs.shape != (head.model.obs_dim,):
+                        self._step_entry_error(ent, (
+                            f"step obs shape {obs.shape} != "
+                            f"({head.model.obs_dim},)"
+                        ))
+                        continue
+                    batch.append((ent, slot, obs))
+                # skipped other-model entries return to the FRONT in order:
+                # they are older than anything still queued behind them —
+                # ``more`` asks the serve loop to tick again NOW for them
+                # (same-model overflow keeps the admission-window pacing)
+                more = bool(skipped)
+                while skipped:
+                    self._queue.appendleft(skipped.pop())
+                if not batch:
+                    return more
+                model = head.model
+                stateful = model.slots > 0
+                n = len(batch)
+                bucket = next((b for b in self.buckets if b >= n),
+                              self.buckets[-1])
+                for ent, _, _ in batch:
+                    self.timer.add("queue_wait", t_assemble - ent.t_enq)
+                idx = np.full(bucket, model.pad_slot, np.int64)
+                obs_arr = np.zeros((bucket, model.obs_dim), np.float32)
+                pos_before = []
+                now = time.monotonic()
+                for j, (ent, slot, obs) in enumerate(batch):
+                    idx[j] = slot if stateful else j
+                    obs_arr[j] = obs
+                    if stateful:
+                        head.live[slot][1] = now
+                    pos_before.append(
+                        int(model.pos[slot])
+                        if hasattr(model, "pos") and stateful else None
+                    )
+                t_compute = time.perf_counter()
+                self.timer.add("batch_assemble", t_compute - t_assemble)
+            tick.set_metadata(rows=n, bucket=bucket)
+            with span("serve.tick.compute"):
+                try:
+                    preds = model.step_rows(idx, obs_arr)
+                except Exception as exc:  # noqa: BLE001 - must survive
+                    logger.exception("policy server: batched step failed")
+                    for ent, _, _ in batch:
+                        self._step_entry_error(
+                            ent, "batched step failed: "
+                                 f"{type(exc).__name__}: {exc}"
+                        )
+                    return more
+                t_reply = time.perf_counter()
+                self.timer.add("compute", t_reply - t_compute)
+            with span("serve.tick.reply"):
+                self.counters.incr("serve_batches")
+                if bucket > n:
+                    self.counters.incr("serve_batch_pad", bucket - n)
+                for j, (ent, slot, _) in enumerate(batch):
+                    reply = {"pred": np.ascontiguousarray(preds[j])}
+                    if pos_before[j] is not None:
+                        reply["pos"] = pos_before[j]
+                    # deferred doorbells: the whole batch's shm replies
+                    # ride ONE wake per channel (flushed below), not one
+                    # ding per record
+                    self._finish(ent.ident, ent.msg, reply,
+                                 span_name="serve:step", t0_us=ent.t0_us,
+                                 ding=False)
+                if self._shm is not None:
+                    self._shm.flush_bells()
+                self.timer.add("reply", time.perf_counter() - t_reply)
             return more
-        model = head.model
-        stateful = model.slots > 0
-        n = len(batch)
-        bucket = next((b for b in self.buckets if b >= n),
-                      self.buckets[-1])
-        for ent, _, _ in batch:
-            self.timer.add("queue_wait", t_assemble - ent.t_enq)
-        idx = np.full(bucket, model.pad_slot, np.int64)
-        obs_arr = np.zeros((bucket, model.obs_dim), np.float32)
-        pos_before = []
-        now = time.monotonic()
-        for j, (ent, slot, obs) in enumerate(batch):
-            idx[j] = slot if stateful else j
-            obs_arr[j] = obs
-            if stateful:
-                head.live[slot][1] = now
-            pos_before.append(
-                int(model.pos[slot])
-                if hasattr(model, "pos") and stateful else None
-            )
-        t_compute = time.perf_counter()
-        self.timer.add("batch_assemble", t_compute - t_assemble)
-        try:
-            preds = model.step_rows(idx, obs_arr)
-        except Exception as exc:  # noqa: BLE001 - server must survive
-            logger.exception("policy server: batched step failed")
-            for ent, _, _ in batch:
-                self._step_entry_error(
-                    ent, f"batched step failed: {type(exc).__name__}: "
-                         f"{exc}"
-                )
-            return more
-        t_reply = time.perf_counter()
-        self.timer.add("compute", t_reply - t_compute)
-        self.counters.incr("serve_batches")
-        if bucket > n:
-            self.counters.incr("serve_batch_pad", bucket - n)
-        for j, (ent, slot, _) in enumerate(batch):
-            reply = {"pred": np.ascontiguousarray(preds[j])}
-            if pos_before[j] is not None:
-                reply["pos"] = pos_before[j]
-            # deferred doorbells: the whole batch's shm replies ride
-            # ONE wake per channel (flushed below), not one ding per
-            # record
-            self._finish(ent.ident, ent.msg, reply,
-                         span_name="serve:step", t0_us=ent.t0_us,
-                         ding=False)
-        if self._shm is not None:
-            self._shm.flush_bells()
-        self.timer.add("reply", time.perf_counter() - t_reply)
-        return more
 
     # -- serving -------------------------------------------------------------
 
@@ -1294,6 +1324,24 @@ class PolicyServer:
         if self._shm is not None:
             self._shm.pump(self._handle_shm_msg)
 
+    def _admit_ready(self):
+        """Admit what has arrived on either wire (resets, and so
+        prefills, run in here)."""
+        with span("serve.admit"):
+            self._drain()
+            self._drain_shm()
+
+    def _idle_poll(self, poll_ms):
+        """Wait, with nothing queued, for the next request to arrive:
+        the clients' turnaround, which no change to the server
+        recovers.  ``serve_idle_us`` is the one record of it."""
+        t0 = time.perf_counter()
+        with span("serve.idle"):
+            events = self._poller.poll(poll_ms)
+        self.counters.incr("serve_idle_us",
+                           int((time.perf_counter() - t0) * 1e6))
+        return events
+
     def serve_forever(self, stop_event=None, poll_ms=50):
         import zmq
 
@@ -1306,9 +1354,8 @@ class PolicyServer:
                 # flight, every queued entry still un-executed)
                 self._poll_weights()
                 if not self._queue:
-                    self._poller.poll(poll_ms)
-                    self._drain()
-                    self._drain_shm()
+                    self._idle_poll(poll_ms)
+                    self._admit_ready()
                     if not self._queue:
                         continue
                 # admission window: work is queued — wait up to tick_ms
@@ -1319,14 +1366,14 @@ class PolicyServer:
                 # at a time, so nobody else can arrive — waiting out
                 # the window would be pure latency)
                 t_end = time.perf_counter() + self.tick_ms / 1000.0
-                while len(self._queue) < self._window_target():
-                    rem_ms = (t_end - time.perf_counter()) * 1e3
-                    if rem_ms <= 0:
-                        break
-                    if not self._poller.poll(max(1, int(rem_ms))):
-                        break  # window elapsed with nothing new
-                    self._drain()
-                    self._drain_shm()
+                with span("serve.window"):
+                    while len(self._queue) < self._window_target():
+                        rem_ms = (t_end - time.perf_counter()) * 1e3
+                        if rem_ms <= 0:
+                            break
+                        if not self._poller.poll(max(1, int(rem_ms))):
+                            break  # window elapsed with nothing new
+                        self._admit_ready()
             except zmq.ZMQError:
                 return  # socket closed under us: clean shutdown
             if self._queue:
@@ -1345,7 +1392,7 @@ class PolicyServer:
 
         while stop_event is None or not stop_event.is_set():
             try:
-                events = dict(self._poller.poll(poll_ms))
+                events = dict(self._idle_poll(poll_ms))
                 self._poll_weights()  # between (batch-1) ticks
                 self._drain_shm()  # ticks per message (serial handler)
                 if self._sock not in events:

@@ -20,24 +20,23 @@ summary carries per-stage p50/p90/p99/max — the percentile surface the
 telemetry plane (docs/observability.md) scrapes and merges across
 processes.  ``histograms=False`` opts out.
 
-Pass ``trace=True`` to additionally record one event per stage interval
-and ``export_chrome_trace(path)`` them as Chrome trace-event JSON —
-loadable in ``chrome://tracing`` / Perfetto, with loader workers, the
-prefetch thread and the train loop on separate rows so feed stalls are
-visible as gaps.  Tracing is off by default (zero per-stage overhead
-beyond the two timestamps), and the event ring is bounded
-(``trace_cap``; evictions counted in ``trace_dropped``) so multi-hour
-traced runs cannot exhaust host memory.
+Every ``stage`` block is also a span on the profiler's clock: in a
+process that has imported jax it opens a
+``jax.profiler.TraceAnnotation`` of the same name, so under
+``jax.profiler.start_trace`` the loader workers, the prefetch thread,
+the train loop and the server's tick phases lie in the profiler's trace
+beside the device's operations.  :func:`span` is the same for places
+that have no timer.  This module never imports jax: in a jax-free
+process (producers, replay shards) both are plain no-ops.
 """
 
 from __future__ import annotations
 
-import json
 import math
-import os
+import sys
 import threading
 import time
-from collections import defaultdict, deque
+from collections import defaultdict
 from contextlib import contextmanager
 
 from blendjax.obs import histogram as _histogram
@@ -156,13 +155,22 @@ REPLAY_EVENTS = (
 #: per-request wire-bytes accounting (docs/transport.md): the server
 #: counts every request/reply payload byte it moves, split by wire —
 #: ``serve_wire_bytes`` over the ZMQ socket, ``serve_shm_bytes``
-#: through the ShmRPC rings.
+#: through the ShmRPC rings;
+#: two running quantities in microseconds, the one record of two
+#: phases of the server's single thread (the same intervals are the
+#: ``serve.prefill`` and ``serve.idle`` spans of a profiler trace):
+#: ``serve_prefill_us`` — spent inside ``model.prefill_rows`` (over
+#: ``serve_prefills``: one prefill; over the wall: the share in which
+#: no tick could start);
+#: ``serve_idle_us`` — spent polling with nothing queued (over
+#: ``serve_batches``: the clients' turnaround per tick).
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
     "serve_resets", "serve_closes", "serve_evictions",
     "serve_slot_denied", "serve_errors", "serve_prefills",
     "serve_wire_bytes", "serve_shm_bytes",
+    "serve_prefill_us", "serve_idle_us",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md
@@ -554,13 +562,34 @@ class EventCounters:
 fleet_counters = EventCounters()
 
 
-#: Default bound on the ``trace=True`` event ring: ~64k intervals is
-#: hours of feed-stage tracing at typical batch rates while holding a
-#: few MB at most.  Beyond it the OLDEST events are dropped (and counted
-#: in :attr:`StageTimer.trace_dropped`) — the recent window is what a
-#: stall investigation wants, and an unbounded list once exhausted host
-#: memory on multi-hour traced runs.
-DEFAULT_TRACE_CAP = 65536
+class _NullSpan:
+    """What :func:`span` hands out where jax is not loaded."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args):
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def span(name, **args):
+    """A host span named ``name`` in the profiler's trace: a
+    ``jax.profiler.TraceAnnotation`` (``args`` become the event's
+    stats; ``set_metadata(**more)`` adds to them inside the block) when
+    this process has imported jax, a no-op otherwise.  With no profiler
+    session running the annotation costs one object and two C calls."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NULL_SPAN
+    return profiler.TraceAnnotation(name, **args)
 
 
 class StageTimer:
@@ -576,12 +605,9 @@ class StageTimer:
     the ``telemetry_overhead_x`` bench compares against).
     """
 
-    def __init__(self, trace=False, histograms=True,
-                 trace_cap=DEFAULT_TRACE_CAP):
+    def __init__(self, histograms=True):
         self._lock = threading.Lock()
-        self._trace = bool(trace)
         self._histograms = bool(histograms)
-        self._trace_cap = int(trace_cap)
         self.reset()
 
     def reset(self):
@@ -589,19 +615,20 @@ class StageTimer:
             self._total = defaultdict(float)
             self._count = defaultdict(int)
             self._hist = {}
-            self._events = deque(maxlen=self._trace_cap)
-            self._trace_dropped = 0
             self._start = time.perf_counter()
 
     @contextmanager
     def stage(self, name):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0, _t0=t0)
+        """Time the block under ``name``, and lay it into the profiler's
+        trace as a :func:`span` of the same name."""
+        with span(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
-    def add(self, name, seconds, _t0=None, _frexp=_hist_frexp,
+    def add(self, name, seconds, _frexp=_hist_frexp,
             _top=_HIST_TOP, _sub=_HIST_SUBBITS):
         with self._lock:
             self._total[name] += seconds
@@ -629,20 +656,12 @@ class StageTimer:
                 h.counts[idx] += 1
                 if seconds > h.max_s:
                     h.max_s = seconds
-            if self._trace:
-                start = _t0 if _t0 is not None else time.perf_counter() - seconds
-                if len(self._events) == self._trace_cap:
-                    self._trace_dropped += 1
-                self._events.append(
-                    (name, start, seconds, threading.get_ident())
-                )
 
     def add_bulk(self, name, total_seconds, count):
         """Accumulate ``count`` pre-aggregated intervals in one locked
         update — for hot loops (e.g. the arena feed path at ~100 us per
         batch) where a per-interval :meth:`add` would itself be a
-        measurable stage.  Not recorded as trace events (aggregates have
-        no start times), and histogram entries land at the aggregate's
+        measurable stage.  Histogram entries land at the aggregate's
         MEAN (per-interval spread is already lost) — percentiles for a
         stage fed only through here degenerate to that mean."""
         if count <= 0:
@@ -678,12 +697,6 @@ class StageTimer:
         wall = self.wall_s
         with self._lock:
             return self._total.get(name, 0.0) / wall if wall > 0 else 0.0
-
-    @property
-    def trace_dropped(self):
-        """Trace events evicted from the bounded ring (oldest first)."""
-        with self._lock:
-            return self._trace_dropped
 
     def _sync_hist_locked(self, name):
         """The stage's histogram with ``n``/``sum_s`` derived from
@@ -757,31 +770,3 @@ class StageTimer:
             }
             for name, rec in self.snapshot().items()
         }
-
-    def export_chrome_trace(self, path):
-        """Write recorded intervals as Chrome trace-event JSON
-        (``chrome://tracing`` / Perfetto).  Requires ``trace=True``;
-        raises RuntimeError otherwise.  One row per thread; timestamps are
-        relative to the last :meth:`reset`."""
-        if not self._trace:
-            raise RuntimeError(
-                "tracing is off; construct StageTimer(trace=True)"
-            )
-        with self._lock:
-            events = list(self._events)
-            origin = self._start
-        pid = os.getpid()
-        out = [
-            {
-                "name": name,
-                "ph": "X",  # complete event: begin + duration
-                "pid": pid,
-                "tid": tid,
-                "ts": (start - origin) * 1e6,  # microseconds
-                "dur": dur * 1e6,
-            }
-            for name, start, dur, tid in events
-        ]
-        with open(path, "w") as f:
-            json.dump({"traceEvents": out, "displayTimeUnit": "ms"}, f)
-        return len(out)

@@ -179,23 +179,6 @@ def test_stagetimer_add_bulk_lands_at_mean():
     t.add_bulk("scatter", 0.0, 0)  # no-op, no div-by-zero
 
 
-def test_trace_ring_bounded_with_drop_count():
-    """The ISSUE-9 satellite: trace=True must not grow without bound —
-    the ring keeps the most recent ``trace_cap`` events and counts
-    evictions."""
-    t = StageTimer(trace=True, trace_cap=64)
-    for i in range(200):
-        t.add("x", 1e-6, _t0=float(i))
-    assert t.trace_dropped == 200 - 64
-    with t._lock:
-        events = list(t._events)
-    assert len(events) == 64
-    # the RECENT window survives (oldest evicted first)
-    assert events[0][1] == pytest.approx(200 - 64)
-    t.reset()
-    assert t.trace_dropped == 0
-
-
 def test_stagetimer_snapshot_copies_histograms():
     t = StageTimer()
     t.add("recv", 0.001)

@@ -1,11 +1,8 @@
-"""StageTimer tests: accumulation, duty cycle, thread safety, and the
-Chrome trace-event export."""
+"""StageTimer tests: accumulation, duty cycle, thread safety (its spans
+in the profiler's trace: tests/test_trace_spans.py)."""
 
-import json
 import threading
 import time
-
-import pytest
 
 from blendjax.utils.timing import StageTimer
 
@@ -40,40 +37,3 @@ def test_concurrent_stages():
     for th in threads:
         th.join()
     assert t.count("x") == 400
-
-
-def test_chrome_trace_export(tmp_path):
-    t = StageTimer(trace=True)
-    with t.stage("recv"):
-        time.sleep(0.005)
-    with t.stage("collate"):
-        time.sleep(0.002)
-    path = tmp_path / "trace.json"
-    n = t.export_chrome_trace(str(path))
-    assert n == 2
-    doc = json.loads(path.read_text())
-    events = doc["traceEvents"]
-    assert {e["name"] for e in events} == {"recv", "collate"}
-    for e in events:
-        assert e["ph"] == "X"
-        assert e["dur"] > 0
-        assert e["ts"] >= 0
-    # events from this (single) thread share a row
-    assert len({e["tid"] for e in events}) == 1
-
-
-def test_trace_off_raises():
-    t = StageTimer()
-    with t.stage("a"):
-        pass
-    with pytest.raises(RuntimeError):
-        t.export_chrome_trace("/tmp/never.json")
-
-
-def test_reset_clears_events(tmp_path):
-    t = StageTimer(trace=True)
-    with t.stage("a"):
-        pass
-    t.reset()
-    path = tmp_path / "trace.json"
-    assert t.export_chrome_trace(str(path)) == 0

@@ -1,0 +1,372 @@
+"""The program's spans on the profiler's clock, and the stable names a
+trace is read by (PERF.md section 3 holds the table of them).
+
+Host side: ``StageTimer.stage`` / ``timing.span`` open a
+``jax.profiler.TraceAnnotation`` where jax is loaded and import nothing
+where it is not; ``PolicyServer``'s loop and ``SeqFormerModel``'s calls
+leave the ``serve.*`` spans and the two phase counters.  Device side:
+the Pallas kernels' ``name=``, the jitted steps' names and the
+``named_scope``s — metadata only, so the arithmetic is bit-equal to the
+same program traced without them."""
+
+import contextlib
+import functools
+import glob
+import os
+import subprocess
+import sys
+import time
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from blendjax.models import seqformer
+from blendjax.obs.hub import TelemetryHub
+from blendjax.serve import LinearModel, ServeClient, start_server_thread
+from blendjax.serve.server import SeqFormerModel
+from blendjax.utils.timing import (
+    SERVE_EVENTS,
+    EventCounters,
+    StageTimer,
+    span,
+)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: every span name of the server's loop and the model's calls: the
+#: contract docs/serving.md and PERF.md tabulate
+SERVER_SPANS = (
+    "serve.idle", "serve.admit", "serve.prefill", "serve.window",
+    "serve.tick", "serve.tick.assemble", "serve.tick.compute",
+    "serve.tick.reply", "serve.weights",
+)
+MODEL_SPANS = (
+    "serve.step.dispatch", "serve.step.fence",
+    "serve.prefill.dispatch", "serve.prefill.fence", "serve.reset_rows",
+)
+TINY = dict(obs_dim=4, d_model=32, n_heads=2, n_layers=2, max_len=32)
+
+
+def _host_events(trace_dir):
+    """{name: [(start_ns, end_ns, stats)]} of the trace's host planes."""
+    from jax.profiler import ProfileData
+
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert files, f"no trace written under {trace_dir}"
+    out = {}
+    for plane in ProfileData.from_file(files[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)))
+    return out
+
+
+@contextlib.contextmanager
+def _profiled(trace_dir):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the annotations alone: a small file
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+class _OneSnapshot:
+    """A WeightBus subscription that yields one snapshot, then nothing."""
+
+    model = None
+
+    def __init__(self, tree):
+        self._snap = types.SimpleNamespace(
+            model=None, version=1, step=1, tree=lambda: tree)
+
+    def poll(self):
+        snap, self._snap = self._snap, None
+        return snap
+
+    def close(self):
+        pass
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One traced session: a ``LinearModel`` server answering a
+    ``reset(prefix=)``, a few ``step``s and a weight swap, then a tiny
+    ``SeqFormerModel`` driven the same way."""
+    trace_dir = tmp_path_factory.mktemp("trace")
+    counters = EventCounters()
+    t_wall = time.perf_counter()
+    with _profiled(trace_dir):
+        linear = LinearModel(obs_dim=4, slots=4)
+        with start_server_thread(linear, counters=counters,
+                                 timer=StageTimer()) as h:
+            c = ServeClient(h.address, timeoutms=20000)
+            before = c.stats()["counters"]
+            c.reset(prefix=np.ones((5, 4), np.float32))
+            for _ in range(3):
+                c.step(np.ones(4, np.float32))
+            h.server.subscriber = _OneSnapshot({"w": linear.w + 1.0})
+            c.step(np.ones(4, np.float32))
+            time.sleep(0.05)  # an empty poll or two
+            after = c.stats()["counters"]
+            c.close_episode()
+            c.close()
+        with start_server_thread(_tiny_served_model(), max_batch=2) as h:
+            c = ServeClient(h.address, timeoutms=60000)
+            c.reset(prefix=np.ones((3, 4), np.float32))
+            c.step(np.ones(4, np.float32))
+            c.close_episode()
+            c.close()
+    wall_us = (time.perf_counter() - t_wall) * 1e6
+    return types.SimpleNamespace(
+        events=_host_events(str(trace_dir)), before=before, after=after,
+        wall_us=wall_us)
+
+
+@pytest.mark.parametrize("name", SERVER_SPANS + MODEL_SPANS)
+def test_server_span_is_in_the_profilers_trace(served, name):
+    assert served.events.get(name), sorted(
+        n for n in served.events if n.startswith("serve"))
+
+
+def test_tick_phases_lie_inside_a_tick(served):
+    ticks = [(lo, hi) for lo, hi, _ in served.events["serve.tick"]]
+    for phase in ("assemble", "compute", "reply"):
+        for lo, hi, _ in served.events[f"serve.tick.{phase}"]:
+            assert any(t_lo <= lo and hi <= t_hi for t_lo, t_hi in ticks), \
+                phase
+    # and the model's own two halves inside a tick's compute
+    computes = [(lo, hi) for lo, hi, _ in
+                served.events["serve.tick.compute"]]
+    for half in ("dispatch", "fence"):
+        for lo, hi, _ in served.events[f"serve.step.{half}"]:
+            assert any(c_lo <= lo and hi <= c_hi for c_lo, c_hi in computes)
+
+
+def test_span_arguments_become_event_stats(served):
+    tick_stats = [st for _, _, st in served.events["serve.tick"] if st]
+    assert {"rows": 1, "bucket": 1} in tick_stats
+    assert {st.get("len") for _, _, st in
+            served.events["serve.prefill"]} == {5, 3}
+
+
+def test_prefill_and_idle_counters(served):
+    for name in ("serve_prefill_us", "serve_idle_us"):
+        assert name in SERVE_EVENTS
+        # the hub zero-fills them before any server has reported
+        assert TelemetryHub().scrape()["counters"][name] == 0
+    assert served.before.get("serve_prefill_us", 0) == 0
+    assert served.after["serve_prefills"] == 1
+    assert 0 < served.after["serve_prefill_us"] <= served.wall_us
+    assert (0 < served.after["serve_idle_us"] - served.before.get(
+        "serve_idle_us", 0) <= served.wall_us)
+    # the counter and the span are one interval, read twice
+    span_us = sum(hi - lo for lo, hi, st in served.events["serve.prefill"]
+                  if st.get("len") == 5) / 1e3
+    assert served.after["serve_prefill_us"] >= 0.5 * span_us
+
+
+def test_stage_and_span_annotate_when_jax_is_loaded(tmp_path):
+    timer = StageTimer()
+    with _profiled(tmp_path):
+        with timer.stage("device_put"):
+            with span("free.span", rows=3) as s:
+                s.set_metadata(bucket=4)
+    events = _host_events(str(tmp_path))
+    assert len(events["device_put"]) == 1
+    (lo, hi, stats), = events["free.span"]
+    assert stats == {"rows": 3, "bucket": 4}
+    d_lo, d_hi, _ = events["device_put"][0]
+    assert d_lo <= lo and hi <= d_hi
+    assert timer.count("device_put") == 1  # still the program's stage
+
+
+def test_stage_imports_nothing_where_jax_is_absent():
+    code = (
+        "import sys\n"
+        "class Block:\n"
+        "    def find_spec(self, name, path=None, target=None):\n"
+        "        if name == 'jax' or name.startswith('jax.'):\n"
+        "            raise ImportError('jax is blocked here')\n"
+        "sys.meta_path.insert(0, Block())\n"
+        "from blendjax.utils.timing import StageTimer, span\n"
+        "t = StageTimer()\n"
+        "with t.stage('recv'):\n"
+        "    with span('free', rows=1) as s:\n"
+        "        s.set_metadata(bucket=2)\n"
+        "assert t.count('recv') == 1\n"
+        "assert not [m for m in sys.modules if m.split('.')[0] == 'jax']\n"
+        "print('ok')\n"
+    )
+    env = dict(os.environ, PYTHONPATH=REPO)
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
+
+
+# -- device-side names ------------------------------------------------------
+
+
+def _pallas_names(jaxpr):
+    """The ``name`` of every ``pallas_call`` in a (closed) jaxpr."""
+    names = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+                continue  # the kernel body holds no further call
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return names
+
+
+def _flash_loss(q, k, v):
+    from blendjax.ops.flash_attention import flash_attention
+
+    out = flash_attention(q, k, v, True, None, 32, 32, True)
+    return (out.astype(jnp.float32) ** 2).sum()
+
+
+def _qkv(dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    return [jax.random.normal(k, (2, 64, 2, 32), dtype) for k in ks]
+
+
+def _decode_frames(x):
+    from blendjax.ops.image import decode_frames_pallas
+
+    return decode_frames_pallas(x, interpret=True)
+
+
+@pytest.mark.parametrize("name,fn,args", [
+    ("flash_fwd", _flash_loss, _qkv),
+    ("flash_bwd_dq", jax.grad(_flash_loss, argnums=(0, 1, 2)), _qkv),
+    ("flash_bwd_dkv", jax.grad(_flash_loss, argnums=(0, 1, 2)), _qkv),
+    ("decode_frames", _decode_frames,
+     lambda: [jnp.zeros((2, 8, 8, 3), jnp.uint8)]),
+])
+def test_pallas_calls_carry_their_names(name, fn, args):
+    assert name in _pallas_names(jax.make_jaxpr(fn)(*args()))
+
+
+def _train_step():
+    import optax
+
+    from blendjax.models.train import TrainState, make_train_step
+
+    params = seqformer.init(jax.random.PRNGKey(0), **TINY)
+    opt = optax.adam(1e-3)
+    step = make_train_step(functools.partial(
+        seqformer.episode_loss_fn, compute_dtype=jnp.float32), opt,
+        donate=False)
+    state = TrainState.create(params, opt)
+    return step.lower(state, {"episode": jnp.ones((2, 9, 4))})
+
+
+def _tiny_served_model():
+    return SeqFormerModel(
+        seqformer.init(jax.random.PRNGKey(0), **TINY), slots=2, length=16)
+
+
+def _serve_step():
+    model = _tiny_served_model()
+    return model._step.lower(
+        model.params, model._cache, jnp.zeros(2, jnp.int32),
+        jnp.ones((2, 4)))
+
+
+def _serve_prefill():
+    model = _tiny_served_model()
+    return model._prefill.lower(
+        model.params, model._cache, jnp.zeros(1, jnp.int32),
+        jnp.ones((3, 4)))
+
+
+@pytest.mark.parametrize("lower,module,scopes", [
+    (_train_step, "train_step",
+     ("loss", "optimizer", "attn", "mlp", "ln")),
+    (_serve_step, "serve_step",
+     ("gather", "decode", "scatter", "attn", "mlp", "ln")),
+    (_serve_prefill, "serve_prefill",
+     ("forward", "scatter", "attn", "mlp", "ln")),
+])
+def test_lowering_holds_the_step_and_scope_names(lower, module, scopes):
+    text = lower().as_text(debug_info=True)
+    assert f"jit_{module}" in text
+    for scope in scopes:
+        # a scope is one component of an operation's name stack
+        assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+# -- names change no arithmetic ---------------------------------------------
+
+
+@contextlib.contextmanager
+def _names_off(monkeypatch):
+    """The parent's program: no ``named_scope``, no kernel ``name=``."""
+    from jax.experimental import pallas as pl
+
+    real_call = pl.pallas_call
+
+    def unnamed_call(*args, name=None, **kwargs):
+        return real_call(*args, **kwargs)
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope",
+                  lambda name: contextlib.nullcontext())
+        m.setattr(pl, "pallas_call", unnamed_call)
+        m.setattr(seqformer, "_ln_apply", seqformer._ln_apply.__wrapped__)
+        yield
+
+
+def _decode_two_steps(params, cache, obs):
+    preds = []
+    for t in range(obs.shape[0]):
+        pred, cache = seqformer.decode_step(
+            params, cache, obs[t], compute_dtype=jnp.float32)
+        preds.append(pred)
+    return jnp.stack(preds), cache["k"][0]
+
+
+def _decode_case():
+    params = seqformer.init(jax.random.PRNGKey(3), **TINY)
+    cache = seqformer.init_cache(params, 3, dtype=jnp.float32, length=8,
+                                 per_row=True)
+    obs = jax.random.normal(jax.random.PRNGKey(4), (2, 3, 4))
+    return params, cache, obs
+
+
+@pytest.mark.parametrize("fn,args", [
+    (_flash_loss, _qkv),
+    (jax.grad(_flash_loss, argnums=(0, 1, 2)), _qkv),
+    (jax.grad(_flash_loss, argnums=(0, 1, 2)),
+     functools.partial(_qkv, jnp.bfloat16)),
+    (_decode_two_steps, _decode_case),
+], ids=["flash_fwd", "flash_bwd", "flash_bwd_bf16", "decode_step"])
+def test_names_change_no_arithmetic(monkeypatch, fn, args):
+    # a fresh wrapper each time: nothing traced with names is reused
+    # for the run without them
+    named = jax.jit(lambda *a: fn(*a))(*args())
+    named_text = jax.jit(lambda *a: fn(*a)).lower(*args()).as_text(
+        debug_info=True)
+    with _names_off(monkeypatch):
+        bare = jax.jit(lambda *a: fn(*a))(*args())
+        bare_text = jax.jit(lambda *a: fn(*a)).lower(*args()).as_text(
+            debug_info=True)
+    assert named_text != bare_text  # the names were there, and went
+    for a, b in zip(jax.tree.leaves(named), jax.tree.leaves(bare)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
