@@ -30,6 +30,8 @@ TPU-first design decisions:
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -502,9 +504,34 @@ def _attn_one(q, kc, vc, pos, scale, window=None):
     return jnp.einsum("bhl,blhd->bhd", p, vc.astype(jnp.float32))
 
 
+#: The largest slice, in elements, that one row of a gather may take
+#: before the TPU compiler (jax 0.9.0, libtpu 0.0.34) first cuts the
+#: WHOLE operand into slices that fit and materialises them
+#: (``mini-gather-slice`` in the compiled module): a gather of 16 rows
+#: of 1024 x 8 x 128 then copies all 65 rows of the operand.
+_GATHER_SLICE_ELEMS = 1 << 18
+
+
+def _pool_rows(pool, slots):
+    """``pool[slots]`` for one ``(S, C, Hkv, Dh)`` cache tensor,
+    gathered as pieces of ``C / n`` positions from the free
+    ``(S * n, C / n, Hkv, Dh)`` view with the smallest ``n`` whose
+    pieces stay under :data:`_GATHER_SLICE_ELEMS`, so that only the
+    stepped rows move.  The same values either way."""
+    s, c, *rest = pool.shape
+    per_pos = math.prod(rest)
+    n = next((n for n in range(1, c + 1)
+              if c % n == 0 and c // n * per_pos <= _GATHER_SLICE_ELEMS), c)
+    if n == 1:
+        return pool[slots]
+    at = (slots[:, None] * n + jnp.arange(n, dtype=slots.dtype)).reshape(-1)
+    return pool.reshape(s * n, c // n, *rest)[at].reshape(
+        slots.shape[0], c, *rest)
+
+
 def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
                 moe_impl="dense", moe_k=2, moe_capacity_factor=1.25,
-                moe_dispatch="sort", window=None):
+                moe_dispatch="sort", window=None, slots=None):
     """One incremental step: consume obs_t (B, obs_dim) at the cache's
     current position, return (next-obs prediction (B, obs_dim) float32,
     updated cache).  Mirrors :func:`_forward`'s block math exactly at a
@@ -525,11 +552,34 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     continuous-batching kernel (parity with per-episode scalar decode
     is locked by ``tests/test_serve.py``).  The scalar path is
     byte-for-byte the pre-serving code.
+
+    ``slots`` (a ``(B,)`` int vector; per-row caches only) says where
+    the batch's rows live in a cache LARGER than the batch — the
+    serving tier's slot pool.  Row ``j`` then embeds at
+    ``cache['pos'][slots[j]]``, writes ONE position of pool row
+    ``slots[j]`` per layer and attends over that row, read back from
+    the written pool (:func:`_pool_rows`); every other row of the
+    returned cache is the input's, so a caller that donates the cache
+    has it updated in place.  ``slots=None`` is the case ``slots =
+    arange(B)`` (the cache IS the batch and nothing is read back), so
+    there is one per-row path.  Duplicate slots (a padded bucket's pad
+    row) all write the same row; which one lands is unspecified.
     """
     from jax import lax
 
-    pos = cache["pos"]
-    per_row = jnp.ndim(pos) == 1
+    pool_pos = cache["pos"]
+    per_row = jnp.ndim(pool_pos) == 1
+    if slots is not None and not per_row:
+        raise ValueError(
+            "slots= indexes a per-row cache (init_cache(per_row=True))"
+        )
+    # the scopes `gather` (the read of the stepped rows) and `scatter`
+    # (the one-position write) are what a trace of the serving step
+    # shows (PERF.md section 3)
+    pos = pool_pos
+    if slots is not None:
+        with jax.named_scope("gather"):
+            pos = pool_pos[slots]
     use_rope = "pos" not in params
     x = _dense_mq(params["embed"], obs_t.astype(compute_dtype),
                   compute_dtype)
@@ -546,8 +596,14 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
         x = x + lax.dynamic_index_in_dim(
             params["pos"], pos, keepdims=False
         ).astype(compute_dtype)[None]
-    new_cache = {"k": [], "v": [], "pos": pos + 1}
-    rows = jnp.arange(obs_t.shape[0]) if per_row else None
+    if slots is None:
+        rows = jnp.arange(obs_t.shape[0]) if per_row else None
+        new_pos = pos + 1
+    else:
+        rows = slots
+        with jax.named_scope("scatter"):
+            new_pos = pool_pos.at[slots].set(pos + 1)
+    new_cache = {"k": [], "v": [], "pos": new_pos}
     for i, blk in enumerate(params["blocks"]):
         with jax.named_scope("attn"):
             h = _ln_apply(blk["ln1"], x)
@@ -563,13 +619,15 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
                     k_new = apply_rope(k_new, cos, sin)
             slot = pos % cache["k"][i].shape[1]  # ring buffer (see _attn_one)
             if per_row:
-                # scatter each row's k/v at ITS ring slot
-                kc = cache["k"][i].at[rows, slot].set(
-                    k_new.astype(cache["k"][i].dtype)
-                )
-                vc = cache["v"][i].at[rows, slot].set(
-                    v_new.astype(cache["v"][i].dtype)
-                )
+                # scatter each row's k/v at ITS ring slot: one position
+                # of the cache changes per row, the rest is the input's
+                with jax.named_scope("scatter"):
+                    kc = cache["k"][i].at[rows, slot].set(
+                        k_new.astype(cache["k"][i].dtype)
+                    )
+                    vc = cache["v"][i].at[rows, slot].set(
+                        v_new.astype(cache["v"][i].dtype)
+                    )
             else:
                 kc = lax.dynamic_update_slice_in_dim(
                     cache["k"][i], k_new[:, None].astype(cache["k"][i].dtype),
@@ -581,6 +639,11 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
                 )
             new_cache["k"].append(kc)
             new_cache["v"].append(vc)
+            if slots is not None:
+                # write first, then read: the stepped rows come out of
+                # the WRITTEN pool, so nothing orders a copy of it
+                with jax.named_scope("gather"):
+                    kc, vc = _pool_rows(kc, slots), _pool_rows(vc, slots)
             dh = q.shape[-1]
             a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
                           window=window).astype(compute_dtype)

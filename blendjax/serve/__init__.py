@@ -38,6 +38,7 @@ _EXPORTS = {
     "LinearModel": "blendjax.serve.server",
     "PolicyModel": "blendjax.serve.server",
     "SeqFormerModel": "blendjax.serve.server",
+    "SlotPoolLost": "blendjax.serve.server",
     "ServerProcess": "blendjax.serve.server",
     "ServerFleet": "blendjax.serve.server",
     "start_server_thread": "blendjax.serve.server",

@@ -18,7 +18,10 @@ of concurrent episodes from it:
   :func:`blendjax.models.seqformer.decode_step` runs with **per-row
   positions** (``init_cache(per_row=True)``) so one batched decode
   serves episodes at heterogeneous timesteps — parity with per-episode
-  serial decode is the correctness bar (tests/test_serve.py);
+  serial decode is the correctness bar (tests/test_serve.py).  The
+  pool is served IN PLACE: donated to the jitted step and prefill,
+  which write only the positions that changed
+  (tests/test_serve_pool.py);
 - **exactly-once RPCs**: every request carries a ``wire.BTMID_KEY``
   correlation id and a fault-policy retry re-sends the SAME id; the
   server answers a retried mutating request (``step``/``reset``/
@@ -289,11 +292,27 @@ class PolicyModel:
         return np.asarray(self._logits(self.params, obs))
 
 
+class SlotPoolLost(RuntimeError):
+    """A donated call failed after it had taken the slot pool: the
+    model holds a fresh, EMPTY pool and every lease on it is void (the
+    server drops them, so their clients ``reset()`` and resume)."""
+
+
 class SeqFormerModel:
     """Stateful world-model serving: a slot pool of batched KV caches
     (``init_cache(per_row=True)``) over ``slots + 1`` rows — the extra
-    row absorbs batch padding writes — stepped by ONE jitted gather ->
-    ``decode_step`` (per-row positions) -> scatter per bucket size.
+    row absorbs batch padding writes — served IN PLACE: the jitted step
+    and the jitted prefill take the pool donated, write only the
+    positions that changed (``decode_step(slots=idx)``: one K/V
+    position per layer and row; the prefill: one row's kept positions)
+    and hand the same buffers back, one compilation per bucket size or
+    prefix length.  Nothing else may hold the pool's arrays: a donated
+    call deletes them.
+
+    Everything that can refuse a call (shapes, lengths, a first
+    compilation) fails BEFORE the pool is donated and leaves it as it
+    was.  A call that fails after that raises :class:`SlotPoolLost`
+    over a rebuilt, empty pool (``pool_rebuilds`` counts them).
 
     ``int8=True`` serves :func:`~blendjax.ops.quant.quantize_seqformer`
     output — ``decode_step`` already dispatches per weight dict, so the
@@ -318,45 +337,32 @@ class SeqFormerModel:
         self.window = window
         self.int8 = bool(int8)
         self.pad_slot = self.slots
+        self.pool_rebuilds = 0
         emb = params["embed"]
         self.obs_dim = (
             emb["w"] if "w" in emb else emb["w_q"]
         ).shape[0]
         cdt = compute_dtype or jnp.float32
-        self._cache = seqformer.init_cache(
-            params, self.slots + 1, dtype=cache_dtype or cdt,
-            length=self.length, per_row=True,
-        )
+        self._cache_dtype = cache_dtype or cdt
         self._jnp = jnp
+        self._cache = self._new_pool()
 
         # the functions' names and scopes are what a profiler trace
-        # and the compile log show: keep them (PERF.md section 3)
+        # and the compile log show: keep them (PERF.md section 3).
+        # `gather` (the read of the stepped rows) and `scatter` (the
+        # one-position write) are decode_step's own, inside `decode`;
+        # padding duplicates in idx all land on the pad row, whose
+        # contents are never read
         def serve_step(params, cache, idx, obs):
-            with jax.named_scope("gather"):
-                rows = {
-                    "pos": cache["pos"][idx],
-                    "k": [k[idx] for k in cache["k"]],
-                    "v": [v[idx] for v in cache["v"]],
-                }
             with jax.named_scope("decode"):
-                pred, new = seqformer.decode_step(
-                    params, rows, obs, compute_dtype=cdt, window=window,
+                return seqformer.decode_step(
+                    params, cache, obs, compute_dtype=cdt, window=window,
+                    slots=idx,
                 )
-            # scatter the stepped rows back; padding duplicates all
-            # land on the pad row, whose contents are never read
-            with jax.named_scope("scatter"):
-                cache = {
-                    "pos": cache["pos"].at[idx].set(new["pos"]),
-                    "k": [c.at[idx].set(nk)
-                          for c, nk in zip(cache["k"], new["k"])],
-                    "v": [c.at[idx].set(nv)
-                          for c, nv in zip(cache["v"], new["v"])],
-                }
-            return pred, cache
 
         # one compilation per (bucket,) shape — the bucket/recompile
         # tradeoff the admission queue pads for
-        self._step = jax.jit(serve_step)
+        self._step = jax.jit(serve_step, donate_argnums=(1,))
 
         def serve_prefill(params, cache, row, prefix):
             # ONE teacher-forced pass fills the slot's KV rows (the
@@ -381,6 +387,8 @@ class SeqFormerModel:
             ring = cache["k"][0].shape[1]
             keep_n = min(t0, ring)
             slots_ax = (jnp.arange(keep_n) + (t0 - keep_n)) % ring
+            # keep_n positions of one row change; the pool is donated,
+            # so these writes are in place
             with jax.named_scope("scatter"):
                 new = {"pos": cache["pos"].at[row].set(t0),
                        "k": [], "v": []}
@@ -398,7 +406,43 @@ class SeqFormerModel:
         # one compilation per prefix LENGTH (prefix rows are real
         # observations — padding them would write fabricated positions
         # into the cache, so lengths are not bucketed)
-        self._prefill = jax.jit(serve_prefill)
+        self._prefill = jax.jit(serve_prefill, donate_argnums=(1,))
+
+    def _new_pool(self):
+        from blendjax.models import seqformer
+
+        return seqformer.init_cache(
+            self.params, self.slots + 1, dtype=self._cache_dtype,
+            length=self.length, per_row=True,
+        )
+
+    def _in_place(self, fn, what, idx, arr):
+        """Run ``fn`` (``self._step`` / ``self._prefill``) over the
+        donated pool and rebind it from the result.  A failure that
+        finds the buffers it was given deleted (the call took them:
+        one that surfaces at the fence always does) costs the pool: it
+        is rebuilt empty and :class:`SlotPoolLost` raised."""
+        pool = self._cache
+        try:
+            with span(f"serve.{what}.dispatch"):
+                pred, self._cache = fn(
+                    self.params, pool, self._jnp.asarray(idx),
+                    self._jnp.asarray(arr),
+                )
+            with span(f"serve.{what}.fence"):
+                # fence: compute timing stays honest
+                return np.asarray(pred)
+        except Exception as exc:
+            import jax
+
+            if not any(leaf.is_deleted() for leaf in jax.tree.leaves(pool)):
+                raise  # refused before donation: the pool is intact
+            self._cache = self._new_pool()
+            self.pool_rebuilds += 1
+            raise SlotPoolLost(
+                f"the {what} failed after the slot pool was donated "
+                f"({type(exc).__name__}: {exc}); pool rebuilt empty"
+            ) from exc
 
     def prefill_rows(self, idx, prefix):
         """Admit a T-step observation prefix into slot ``idx`` with one
@@ -406,6 +450,10 @@ class SeqFormerModel:
         parity within 1e-5, tests/test_serve.py).  Returns the
         prediction for position T (what the T'th serial step would have
         returned); the slot's next ``step`` decodes at position T."""
+        if np.ndim(prefix) != 2 or np.shape(prefix)[1] != self.obs_dim:
+            raise ValueError(
+                f"prefix shape {np.shape(prefix)} != (T, {self.obs_dim})"
+            )
         t0 = int(prefix.shape[0])
         if t0 > self.length:
             # the teacher-forced pass attends the WHOLE prefix; serial
@@ -424,13 +472,7 @@ class SeqFormerModel:
                 f"table ({self.params['pos'].shape[0]}); use "
                 "pos_encoding='rope' for longer prefixes"
             )
-        with span("serve.prefill.dispatch"):
-            pred, self._cache = self._prefill(
-                self.params, self._cache, self._jnp.asarray(idx),
-                self._jnp.asarray(prefix),
-            )
-        with span("serve.prefill.fence"):
-            return np.asarray(pred)
+        return self._in_place(self._prefill, "prefill", idx, prefix)
 
     def apply_weights(self, tree):
         """WeightBus hot-swap: adopt a published seqformer pytree (the
@@ -464,14 +506,11 @@ class SeqFormerModel:
             ].set(0)
 
     def step_rows(self, idx, obs):
-        with span("serve.step.dispatch"):
-            pred, self._cache = self._step(
-                self.params, self._cache, self._jnp.asarray(idx),
-                self._jnp.asarray(obs),
+        if np.shape(obs) != (len(idx), self.obs_dim):
+            raise ValueError(
+                f"obs shape {np.shape(obs)} != ({len(idx)}, {self.obs_dim})"
             )
-        with span("serve.step.fence"):
-            # fence: compute timing stays honest
-            return np.asarray(pred)
+        return self._in_place(self._step, "step", idx, obs)
 
 
 # ---------------------------------------------------------------------------
@@ -732,6 +771,19 @@ class PolicyServer:
         st.free.append(slot)
         return True
 
+    def _pool_lost(self, st):
+        """The model rebuilt its slot pool empty (:class:`SlotPoolLost`):
+        every lease on it is void.  Dropping them makes each tenant's
+        next step the ``unknown episode slot`` error — never an answer
+        computed from an empty cache."""
+        self.counters.incr("serve_pool_rebuilds")
+        logger.error(
+            "policy server: slot pool of model %r rebuilt empty, "
+            "%d live episodes dropped", st.mid, len(st.live),
+        )
+        st.live.clear()
+        st.free = list(range(st.model.slots))
+
     # -- request handling ----------------------------------------------------
 
     def _live_episodes(self):
@@ -822,6 +874,8 @@ class PolicyServer:
                 pred = st.model.prefill_rows(np.asarray([slot]), prefix)
         except Exception as exc:  # noqa: BLE001 - surfaced to client
             logger.exception("policy server: prefill failed")
+            if isinstance(exc, SlotPoolLost):
+                self._pool_lost(st)
             return fail(f"prefill failed: {type(exc).__name__}: {exc}")
         # the one record of the time the server's thread spent in
         # prefill (no tick can start meanwhile)
@@ -1231,6 +1285,8 @@ class PolicyServer:
                     preds = model.step_rows(idx, obs_arr)
                 except Exception as exc:  # noqa: BLE001 - must survive
                     logger.exception("policy server: batched step failed")
+                    if isinstance(exc, SlotPoolLost):
+                        self._pool_lost(head)
                     for ent, _, _ in batch:
                         self._step_entry_error(
                             ent, "batched step failed: "

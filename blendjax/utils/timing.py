@@ -163,14 +163,18 @@ REPLAY_EVENTS = (
 #: ``serve_prefills``: one prefill; over the wall: the share in which
 #: no tick could start);
 #: ``serve_idle_us`` — spent polling with nothing queued (over
-#: ``serve_batches``: the clients' turnaround per tick).
+#: ``serve_batches``: the clients' turnaround per tick);
+#: ``serve_pool_rebuilds`` — times a donated step or prefill failed
+#: after it had taken the slot pool: the pool was rebuilt empty and the
+#: model's leases dropped (docs/serving.md "KV-cache slot pool"); 0 in a
+#: healthy server.
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
     "serve_resets", "serve_closes", "serve_evictions",
     "serve_slot_denied", "serve_errors", "serve_prefills",
     "serve_wire_bytes", "serve_shm_bytes",
-    "serve_prefill_us", "serve_idle_us",
+    "serve_prefill_us", "serve_idle_us", "serve_pool_rebuilds",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md
