@@ -9,6 +9,8 @@ float32 params.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -38,6 +40,11 @@ def dense_init(key, d_in, d_out):
     return {"w": w, "b": jnp.zeros((d_out,))}
 
 
+def scaled_normal(key, shape, fan_in, dtype=jnp.float32):
+    """Normal weights with standard deviation ``fan_in ** -0.5``."""
+    return (jax.random.normal(key, shape) * fan_in ** -0.5).astype(dtype)
+
+
 def dense_apply(p, x, dtype=None):
     dtype = dtype or x.dtype
     return x.astype(dtype) @ p["w"].astype(dtype) + p["b"].astype(dtype)
@@ -47,10 +54,45 @@ def gelu(x):
     return jax.nn.gelu(x)
 
 
-def rope_table(positions, dh, base=10000.0):
+def rms_norm(scale, x, eps=1e-6):
+    """``x * rsqrt(mean(x^2) + eps) * scale``, computed in float32."""
+    x32 = x.astype(jnp.float32)
+    out = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (out * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention-magnitude correction ``0.1 * mscale * ln(factor)
+    + 1`` (1 at ``factor <= 1``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dh, base, factor, beta_fast, beta_slow, original_max):
+    """YaRN inverse frequencies (``deepseek_yarn``) over ``dh // 2`` rope
+    pairs: ``base^(-2i/dh)`` below the correction dimension of
+    ``beta_fast`` rotations over ``original_max`` positions, that over
+    ``factor`` above the one of ``beta_slow``, the linear ramp between."""
+    def correction_dim(rotations):
+        return dh * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dh - 1)
+    half = dh // 2
+    plain = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return plain * (1.0 - ramp) + plain / factor * ramp
+
+
+def rope_table(positions, dh, base=10000.0, yarn=None):
     """Rotary-embedding cos/sin tables for ``positions`` (any traced or
     static int array) at per-head dim ``dh`` (even).  f32: the rotation
     is applied in f32 and cast back by :func:`apply_rope`.
+
+    ``yarn`` (``factor, beta_fast, beta_slow, original_max, mscale,
+    mscale_all_dim``) scales the frequencies as :func:`yarn_inv_freq`
+    does and the tables by ``m(mscale) / m(mscale_all_dim)``.
 
     Precision bound: the highest-frequency angle equals the raw
     position, and f32's ulp at position p is ~p * 6e-8 radians — sub-
@@ -59,9 +101,16 @@ def rope_table(positions, dh, base=10000.0):
     horizon ~1e5-1e6 positions; a reduced-angle scheme would be needed
     beyond that."""
     half = dh // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32)[:, None] * freqs[None]
+        return jnp.cos(ang), jnp.sin(ang)
+    factor, beta_fast, beta_slow, original_max, mscale, mscale_all = yarn
+    freqs = yarn_inv_freq(dh, base, factor, beta_fast, beta_slow,
+                          original_max)
     ang = positions.astype(jnp.float32)[:, None] * freqs[None]
-    return jnp.cos(ang), jnp.sin(ang)
+    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
 def apply_rope(x, cos, sin):

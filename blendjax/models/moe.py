@@ -23,12 +23,13 @@ Reference: the blendtorch reference has no model zoo at all (SURVEY.md
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
 import jax.numpy as jnp
 
-from blendjax.models.layers import dense_apply, gelu
+from blendjax.models.layers import dense_apply, gelu, scaled_normal
 
 
 def expert_capacity(n_tokens, n_experts, k, capacity_factor):
@@ -223,3 +224,118 @@ def moe_apply_topk(p, x, dtype, k=2, capacity_factor=1.25, dispatch="sort"):
         "dispatch_fraction": keep.astype(jnp.float32).mean(),
     }
     return y, aux
+
+
+# -- the held share of a routed layer (expert parallelism's one rank) ---------
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class RouteSpec:
+    """What the shapes of a held-share ``"moe"`` entry do not say: the
+    experts chosen per token, the factor on the normalised weights, and
+    the id of the first routed expert held here (the count is the
+    stacked weights' leading axis)."""
+
+    top_k: int
+    scale: float = 1.0
+    first: int = 0
+
+
+def gated_mlp(p, x, dtype):
+    """``down(silu(gate x) * (up x))``, no biases: the dense MLP and the
+    shared expert."""
+    x = x.astype(dtype)
+    h = jax.nn.silu(x @ p["gate"].astype(dtype)) * (x @ p["up"].astype(dtype))
+    return h @ p["down"].astype(dtype)
+
+
+def gated_mlp_init(key, d, d_ff, dtype=jnp.float32, stack=()):
+    """``gate``/``up``/``down`` of a gated MLP, or of ``stack`` of them
+    on leading axes (experts)."""
+    kg, ku, kd = jax.random.split(key, 3)
+    return {"gate": scaled_normal(kg, (*stack, d, d_ff), d, dtype),
+            "up": scaled_normal(ku, (*stack, d, d_ff), d, dtype),
+            "down": scaled_normal(kd, (*stack, d_ff, d), d_ff, dtype)}
+
+
+def held_init(key, d, d_ff, n_routed, held, spec, shared=True,
+              dtype=jnp.float32):
+    """A held-share layer's parameters: the router over all ``n_routed``
+    (``bias`` enters the selection only), the ``held`` experts from
+    ``spec.first`` stacked, and the shared expert."""
+    kr, kb, ke, ks = jax.random.split(key, 4)
+    p = {
+        "router": {"w": scaled_normal(kr, (d, n_routed), d),
+                   "bias": jax.random.normal(kb, (n_routed,)) * 0.1},
+        **gated_mlp_init(ke, d, d_ff, dtype, stack=(held,)),
+        "route": spec,
+    }
+    if shared:
+        p["shared"] = gated_mlp_init(ks, d, d_ff, dtype)
+    return p
+
+
+def route_sigmoid(router, x, spec):
+    """Float32 routing over all routed experts: ``s = sigmoid(x W_r)``,
+    the ``top_k`` of ``s + bias`` selected, and the selected scores (the
+    bias not in them) normalised to sum ``spec.scale``.  Returns
+    ``(sel (n, k) int32, g (n, k) float32)``.  Nothing is dropped."""
+    s = jax.nn.sigmoid(x.astype(jnp.float32)
+                       @ router["w"].astype(jnp.float32))
+    _, sel = jax.lax.top_k(s + router["bias"].astype(jnp.float32),
+                           spec.top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    return sel, spec.scale * w / w.sum(-1, keepdims=True)
+
+
+def moe_apply_held(p, x, dtype, valid=None):
+    """The part of a routed layer that this rank's experts give, plus
+    the shared expert: ``x`` (n, d) is routed over all experts
+    (:func:`route_sigmoid`), the assignments whose expert lies in
+    ``[first, first + held)`` are sorted by expert and multiplied group
+    by group (``jax.lax.ragged_dot``: no capacity, no padding arena, no
+    drop), and every token's held contributions are summed under their
+    weights.  What the absent experts would add is left out; with
+    ``first = 0`` and every expert stacked it is the whole layer.
+
+    Returns ``(y (n, d), counts)``; ``counts`` is int32 ``[assignments
+    made, assignments held here, distinct held experts with a token]``
+    over the rows that ``valid`` (n,) marks (all, if None)."""
+    spec = p["route"]
+    n, d = x.shape
+    k = spec.top_k
+    held = p["gate"].shape[0]
+    with jax.named_scope("route"):
+        sel, g = route_sigmoid(p["router"], x, spec)
+        local = sel - spec.first
+        here = jnp.logical_and(local >= 0, local < held)
+        # held assignments sort to the front by expert, the rest last
+        key = jnp.where(here, local, held).reshape(n * k)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        if valid is None:
+            made, counted = n * k, sizes
+        else:
+            made = valid.sum() * k
+            counted = jnp.bincount(
+                key, weights=jnp.repeat(valid, k).astype(jnp.int32),
+                length=held + 1)[:held]
+        counts = jnp.stack([
+            jnp.asarray(made), counted.sum(), (counted > 0).sum(),
+        ]).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        xs = x.astype(dtype)[order // k]                     # (n * k, d)
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["gate"].astype(dtype),
+                                           sizes)) \
+            * jax.lax.ragged_dot(xs, p["up"].astype(dtype), sizes)
+        out = jax.lax.ragged_dot(h, p["down"].astype(dtype), sizes)
+        # back to (token, choice) order; rows past the held groups are
+        # whatever the product left there, so they are masked, not scaled
+        out = out[jnp.argsort(order)].reshape(n, k, d)
+        y = jnp.where(here[..., None], out * g[..., None].astype(dtype),
+                      0).sum(1)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            y = y + gated_mlp(p["shared"], x, dtype)
+    return y.astype(dtype), counts

@@ -26,6 +26,19 @@ TPU-first design decisions:
   expert parallelism — top-k gating with capacity factor, static-shaped
   GShard-style dispatch, dropped tokens ride the residual; see
   :mod:`blendjax.models.moe`).
+
+**The block is read off the params.**  Every function here dispatches,
+block by block, on what the pytree holds, so one `_forward`, one
+`init_cache` and one `decode_step` serve every model and no argument
+says which it is: `ln*` with a `bias` is LayerNorm, without one RMSNorm;
+`mla` in place of `wq/wk/wv/wo` is latent attention
+(:mod:`blendjax.models.mla`: one low-rank cache row a position,
+expanded in the forward pass, absorbed in a decode step); an `mlp` with
+a `gate` is the gated SiLU MLP; a `moe` with a `route` is the held share
+of a sigmoid-routed layer (:func:`blendjax.models.moe.moe_apply_held`);
+`embed` with a `table` takes int32 ids, and a `head` without a bias
+answers with float32 logits over its vocabulary slice
+(:func:`init_token_model`).
 """
 
 from __future__ import annotations
@@ -35,13 +48,23 @@ import math
 import jax
 import jax.numpy as jnp
 
+from blendjax.models import mla
 from blendjax.models.layers import (
     apply_rope,
     apply_rope_rows,
     dense_apply,
     dense_init,
     gelu,
+    rms_norm,
     rope_table,
+    scaled_normal,
+)
+from blendjax.models.moe import (
+    RouteSpec,
+    gated_mlp,
+    gated_mlp_init,
+    held_init,
+    moe_apply_held,
 )
 from blendjax.ops.quant import maybe_quantized_einsum
 from blendjax.parallel.ring_attention import full_attention
@@ -76,6 +99,8 @@ def _ln_init(d):
 
 @jax.named_scope("ln")
 def _ln_apply(p, x):
+    if "bias" not in p:
+        return rms_norm(p["scale"], x)
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
     var = x32.var(-1, keepdims=True)
@@ -104,6 +129,117 @@ def _moe_apply(p, x, dtype):
     y = jnp.einsum("betf,efd->betd", h, p["w2"].astype(dtype))
     y = y + p["b2"][None, :, None, :].astype(dtype)
     return jnp.einsum("bte,betd->btd", gates.astype(dtype), y)
+
+
+def _latent(params):
+    """Whether the model's attention is latent (``mla`` blocks)."""
+    return "mla" in params["blocks"][0]
+
+
+def _embed(params, obs, dtype):
+    """Observations through the dense projection, or int ids through the
+    table (an id outside it is clipped)."""
+    emb = params["embed"]
+    if "table" in emb:
+        return jnp.take(emb["table"], obs, axis=0, mode="clip").astype(dtype)
+    return _dense_mq(emb, obs.astype(dtype), dtype)
+
+
+@jax.named_scope("head")
+def _head(params, x, dtype):
+    """float32 predictions: the observation head, or logits over the
+    vocabulary slice (a bias-free head; products in ``dtype``,
+    accumulated in float32, the slice's weights never upcast)."""
+    head = params["head"]
+    if "b" in head:
+        return _dense_mq(head, x, jnp.float32)
+    return jnp.einsum("...d,dv->...v", x.astype(dtype),
+                      head["w"].astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _held_moe(blk, h, dtype, auxs, valid=None):
+    """The held-share expert layer over ``h`` of any leading shape; its
+    counts go to ``auxs``."""
+    with jax.named_scope("moe"):
+        y, counts = moe_apply_held(
+            blk["moe"], h.reshape(-1, h.shape[-1]), dtype, valid=valid)
+    auxs.append({"counts": counts})
+    return y.reshape(h.shape)
+
+
+def token_model_specs(config, first=0):
+    """The static entries of a token model's blocks, from the published
+    keys: ``(blk["mla"]["spec"], blk["moe"]["route"])``."""
+    c = config
+    ys = c.get("rope_scaling")
+    return (
+        mla.MlaSpec(
+            rope_dim=c["qk_rope_head_dim"], rope_base=float(c["rope_theta"]),
+            yarn=None if not ys else (
+                ys["factor"], ys["beta_fast"], ys["beta_slow"],
+                ys["original_max_position_embeddings"], ys["mscale"],
+                ys["mscale_all_dim"])),
+        RouteSpec(top_k=c["num_experts_per_tok"],
+                  scale=float(c["routed_scaling_factor"]), first=first),
+    )
+
+
+def describe_token_model(arrays, config, first=0):
+    """Weights made elsewhere in this layout (arrays only) become a model:
+    every block is given its static entries.  Returns ``arrays``."""
+    mla_spec, route = token_model_specs(config, first)
+    for blk in arrays["blocks"]:
+        blk["mla"]["spec"] = mla_spec
+        if "moe" in blk:
+            blk["moe"]["route"] = route
+    return arrays
+
+
+def init_token_model(key, config, held=None, dtype=jnp.float32):
+    """A token model with latent attention and sigmoid-routed experts,
+    from a configuration in the published (Hugging Face) keys:
+    ``hidden_size, num_attention_heads, kv_lora_rank, qk_nope_head_dim,
+    qk_rope_head_dim, v_head_dim, rope_theta, rope_scaling,
+    num_hidden_layers, first_k_dense_replace, intermediate_size,
+    moe_intermediate_size, num_experts, num_experts_per_tok,
+    routed_scaling_factor, num_shared_experts, vocab_size``.
+
+    ``held = (first, count)`` is the share of the routed experts this
+    rank holds (all by default): the router keeps its published width.
+    The pytree it returns is what :func:`_forward`, :func:`init_cache`
+    and :func:`decode_step` dispatch on (module docstring); the static
+    ``spec`` / ``route`` entries carry what shapes cannot."""
+    c = config
+    d = c["hidden_size"]
+    first, count = held or (0, c["num_experts"])
+    mla_spec, route = token_model_specs(c, first)
+    ke, kh, *kb = jax.random.split(key, 2 + c["num_hidden_layers"])
+
+    def norm(width):
+        return {"scale": jnp.ones((width,), dtype)}
+
+    blocks = []
+    for i, k in enumerate(kb):
+        ka, km = jax.random.split(k)
+        blk = {"ln1": norm(d), "ln2": norm(d),
+               "mla": mla.init(ka, d, c["num_attention_heads"],
+                               c["kv_lora_rank"], c["qk_nope_head_dim"],
+                               c["v_head_dim"], mla_spec, dtype)}
+        if i < c["first_k_dense_replace"]:
+            blk["mlp"] = gated_mlp_init(km, d, c["intermediate_size"], dtype)
+        else:
+            blk["moe"] = held_init(
+                km, d, c["moe_intermediate_size"], c["num_experts"], count,
+                route, shared=bool(c.get("num_shared_experts")), dtype=dtype)
+        blocks.append(blk)
+    return {
+        "embed": {"table": scaled_normal(ke, (c["vocab_size"], d), 1.0,
+                                         dtype)},
+        "blocks": blocks,
+        "ln_f": norm(d),
+        "head": {"w": scaled_normal(kh, (d, c["vocab_size"]), d, dtype)},
+    }
 
 
 def init(
@@ -195,43 +331,65 @@ def init(
     return params
 
 
-def _forward(params, obs, attn_fn, compute_dtype, moe_impl, moe_k,
-             moe_capacity_factor, moe_dispatch="sort", kv_sink=None):
+def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
+             moe_impl="dense", moe_k=2, moe_capacity_factor=1.25,
+             moe_dispatch="sort", kv_sink=None, last_only=False):
     """Shared forward: returns (prediction, list of per-layer MoE aux).
 
-    ``kv_sink`` (a list) collects each layer's (k, v) projections —
-    :func:`rollout`'s vectorized prefill fills its KV caches from one
-    teacher-forced pass instead of t0 serial decode steps."""
+    ``kv_sink`` (a list) collects each layer's cache entries, a ``(k,
+    v)`` pair or a latent block's ``(rows,)`` — :func:`rollout`'s
+    vectorized prefill fills its caches from one teacher-forced pass
+    instead of t0 serial decode steps.  ``last_only`` answers for the
+    last position alone (a prefill over a vocabulary wants no other
+    logits).  The ``moe_*`` arguments choose the evaluation of the
+    legacy expert entry only; a held-share layer carries its own."""
     if attn_fn is None:
         def attn_fn(q, k, v):
             return full_attention(q, k, v, causal=True)
 
-    b, t, _ = obs.shape
+    t = obs.shape[1]
     auxs = []
-    use_rope = "pos" not in params
-    x = _dense_mq(params["embed"], obs.astype(compute_dtype), compute_dtype)
+    use_rope = "pos" not in params and not _latent(params)
+    x = _embed(params, obs, compute_dtype)
     if use_rope:
         dh = _wq_head_dim(params)
         cos, sin = rope_table(jnp.arange(t), dh)
-    else:
+    elif "pos" in params:
         x = x + params["pos"][:t].astype(compute_dtype)[None]
     for blk in params["blocks"]:
-        with jax.named_scope("attn"):
-            h = _ln_apply(blk["ln1"], x)
-            q, k, v = (
-                _proj_mq(blk[n], h, "btd,dhk->bthk", compute_dtype)
-                for n in ("wq", "wk", "wv")
-            )
-            if use_rope:
-                # rotate BEFORE the kv sink and the attn seam: caches store
-                # rotated keys, and every attention scheme sees pre-rotated
-                # q/k (rotation by absolute position makes scores relative)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-            if kv_sink is not None:
-                kv_sink.append((k, v))
-            a = attn_fn(q, k, v)
-            x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd", compute_dtype)
+        if "mla" in blk:
+            with jax.named_scope("mla"):
+                h = _ln_apply(blk["ln1"], x)
+                q_nope, q_pe, rows = mla.project(
+                    blk["mla"], h, *mla.rope(blk["mla"], jnp.arange(t)),
+                    compute_dtype)
+                if kv_sink is not None:
+                    kv_sink.append((rows,))
+                x = x + mla.attend_expanded(blk["mla"], q_nope, q_pe, rows,
+                                            compute_dtype)
+        else:
+            with jax.named_scope("attn"):
+                h = _ln_apply(blk["ln1"], x)
+                q, k, v = (
+                    _proj_mq(blk[n], h, "btd,dhk->bthk", compute_dtype)
+                    for n in ("wq", "wk", "wv")
+                )
+                if use_rope:
+                    # rotate BEFORE the kv sink and the attn seam: caches
+                    # store rotated keys, and every attention scheme sees
+                    # pre-rotated q/k (rotation by absolute position makes
+                    # scores relative)
+                    q = apply_rope(q, cos, sin)
+                    k = apply_rope(k, cos, sin)
+                if kv_sink is not None:
+                    kv_sink.append((k, v))
+                a = attn_fn(q, k, v)
+                x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd",
+                                 compute_dtype)
+        if "moe" in blk and "route" in blk["moe"]:
+            x = x + _held_moe(blk, _ln_apply(blk["ln2"], x), compute_dtype,
+                              auxs)
+            continue
         with jax.named_scope("mlp"):
             h = _ln_apply(blk["ln2"], x)
             if "moe" in blk:
@@ -249,11 +407,15 @@ def _forward(params, obs, attn_fn, compute_dtype, moe_impl, moe_k,
                     x = x + _moe_apply(blk["moe"], h, compute_dtype)
                 else:
                     raise ValueError(f"unknown moe_impl {moe_impl!r}")
+            elif "gate" in blk["mlp"]:
+                x = x + gated_mlp(blk["mlp"], h, compute_dtype)
             else:
                 h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
                 x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
+    if last_only:
+        x = x[:, -1:]
     x = _ln_apply(params["ln_f"], x)
-    return _dense_mq(params["head"], x, jnp.float32), auxs
+    return _head(params, x, compute_dtype), auxs
 
 
 def apply(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
@@ -405,7 +567,9 @@ def train_flops(batch_size, seq_len, obs_dim, d_model, n_heads, n_layers,
 def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
                per_row=False):
     """Per-layer KV caches: ``{'k': [(B, L, Hkv, Dh)], 'v': [...],
-    'pos': 0}``.  ``length`` defaults to the model's ``max_len`` (the
+    'pos': 0}``, or for a latent-attention model ``{'kv': [(B, L,
+    kv_rank + rope)], 'pos': 0}``, one row a position
+    (:mod:`blendjax.models.mla`).  ``length`` defaults to the model's ``max_len`` (the
     ``pos`` table); pass the actual decode horizon to size the cache —
     and every step's attention — to the sequence you will run.  Rope
     models have no table and no inherent bound: ``length`` is required.
@@ -441,6 +605,11 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
         jnp.zeros((batch_size,), jnp.int32)
         if per_row else jnp.asarray(0, jnp.int32)
     )
+    if _latent(params):
+        # one latent row a position and layer: [RMSNorm(c) | rot(k_pe)]
+        return {"pos": pos0, "kv": [
+            jnp.zeros((batch_size, length, mla.row_width(blk["mla"])), dtype)
+            for blk in params["blocks"]]}
     caches = {"k": [], "v": [], "pos": pos0}
     for blk in params["blocks"]:
         wk = blk["wk"]
@@ -513,9 +682,9 @@ _GATHER_SLICE_ELEMS = 1 << 18
 
 
 def _pool_rows(pool, slots):
-    """``pool[slots]`` for one ``(S, C, Hkv, Dh)`` cache tensor,
-    gathered as pieces of ``C / n`` positions from the free
-    ``(S * n, C / n, Hkv, Dh)`` view with the smallest ``n`` whose
+    """``pool[slots]`` for one ``(S, C, Hkv, Dh)`` cache tensor (or a
+    latent ``(S, C, W)`` one), gathered as pieces of ``C / n`` positions
+    from the free ``(S * n, C / n, ...)`` view with the smallest ``n`` whose
     pieces stay under :data:`_GATHER_SLICE_ELEMS`, so that only the
     stepped rows move.  The same values either way."""
     s, c, *rest = pool.shape
@@ -565,6 +734,19 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     there is one per-row path.  Duplicate slots (a padded bucket's pad
     row) all write the same row; which one lands is unspecified.
     """
+    pred, new_cache, _ = _decode(
+        params, cache, obs_t, compute_dtype, moe_impl, moe_k,
+        moe_capacity_factor, moe_dispatch, window, slots)
+    return pred, new_cache
+
+
+def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
+            moe_impl="dense", moe_k=2, moe_capacity_factor=1.25,
+            moe_dispatch="sort", window=None, slots=None, valid=None):
+    """:func:`decode_step`, returning the held-share layers' counts too
+    (``(prediction, cache, auxs)``); ``valid`` (B,) marks the rows those
+    counts are over (a padded bucket's pad rows are computed like any
+    other and counted by nobody)."""
     from jax import lax
 
     pool_pos = cache["pos"]
@@ -580,12 +762,17 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     if slots is not None:
         with jax.named_scope("gather"):
             pos = pool_pos[slots]
-    use_rope = "pos" not in params
-    x = _dense_mq(params["embed"], obs_t.astype(compute_dtype),
-                  compute_dtype)
+    latent = _latent(params)
+    if latent and window is not None:
+        raise ValueError("latent attention has no windowed path")
+    use_rope = "pos" not in params and not latent
+    auxs = []
+    x = _embed(params, obs_t, compute_dtype)
     if use_rope:
         cos, sin = rope_table(pos if per_row else pos[None],
                               _wq_head_dim(params))
+    elif latent:
+        pass  # each latent block rotates by its own table
     elif per_row:
         # per-row table lookup; clip mirrors dynamic_index_in_dim's
         # out-of-bounds clamp on the scalar path (init_cache rejects
@@ -603,51 +790,64 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
         rows = slots
         with jax.named_scope("scatter"):
             new_pos = pool_pos.at[slots].set(pos + 1)
-    new_cache = {"k": [], "v": [], "pos": new_pos}
+    new_cache = {"pos": new_pos}
+    for name in cache:
+        if name != "pos":
+            new_cache[name] = []
     for i, blk in enumerate(params["blocks"]):
-        with jax.named_scope("attn"):
-            h = _ln_apply(blk["ln1"], x)
-            q = _proj_mq(blk["wq"], h, "bd,dhk->bhk", compute_dtype)
-            k_new = _proj_mq(blk["wk"], h, "bd,dhk->bhk", compute_dtype)
-            v_new = _proj_mq(blk["wv"], h, "bd,dhk->bhk", compute_dtype)
-            if use_rope:
+        if "mla" in blk:
+            with jax.named_scope("mla"):
+                x = x + _mla_step(blk, cache["kv"][i], new_cache["kv"], x,
+                                  pos, rows, slots, compute_dtype)
+        else:
+            with jax.named_scope("attn"):
+                h = _ln_apply(blk["ln1"], x)
+                q = _proj_mq(blk["wq"], h, "bd,dhk->bhk", compute_dtype)
+                k_new = _proj_mq(blk["wk"], h, "bd,dhk->bhk", compute_dtype)
+                v_new = _proj_mq(blk["wv"], h, "bd,dhk->bhk", compute_dtype)
+                if use_rope:
+                    if per_row:
+                        q = apply_rope_rows(q, cos, sin)
+                        k_new = apply_rope_rows(k_new, cos, sin)
+                    else:
+                        q = apply_rope(q, cos, sin)
+                        k_new = apply_rope(k_new, cos, sin)
+                # ring buffer (see _attn_one)
+                slot = pos % cache["k"][i].shape[1]
                 if per_row:
-                    q = apply_rope_rows(q, cos, sin)
-                    k_new = apply_rope_rows(k_new, cos, sin)
+                    # scatter each row's k/v at ITS ring slot: one position
+                    # of the cache changes per row, the rest is the input's
+                    with jax.named_scope("scatter"):
+                        kc = cache["k"][i].at[rows, slot].set(
+                            k_new.astype(cache["k"][i].dtype)
+                        )
+                        vc = cache["v"][i].at[rows, slot].set(
+                            v_new.astype(cache["v"][i].dtype)
+                        )
                 else:
-                    q = apply_rope(q, cos, sin)
-                    k_new = apply_rope(k_new, cos, sin)
-            slot = pos % cache["k"][i].shape[1]  # ring buffer (see _attn_one)
-            if per_row:
-                # scatter each row's k/v at ITS ring slot: one position
-                # of the cache changes per row, the rest is the input's
-                with jax.named_scope("scatter"):
-                    kc = cache["k"][i].at[rows, slot].set(
-                        k_new.astype(cache["k"][i].dtype)
+                    kc = lax.dynamic_update_slice_in_dim(
+                        cache["k"][i], k_new[:, None].astype(cache["k"][i].dtype),
+                        slot, axis=1,
                     )
-                    vc = cache["v"][i].at[rows, slot].set(
-                        v_new.astype(cache["v"][i].dtype)
+                    vc = lax.dynamic_update_slice_in_dim(
+                        cache["v"][i], v_new[:, None].astype(cache["v"][i].dtype),
+                        slot, axis=1,
                     )
-            else:
-                kc = lax.dynamic_update_slice_in_dim(
-                    cache["k"][i], k_new[:, None].astype(cache["k"][i].dtype),
-                    slot, axis=1,
-                )
-                vc = lax.dynamic_update_slice_in_dim(
-                    cache["v"][i], v_new[:, None].astype(cache["v"][i].dtype),
-                    slot, axis=1,
-                )
-            new_cache["k"].append(kc)
-            new_cache["v"].append(vc)
-            if slots is not None:
-                # write first, then read: the stepped rows come out of
-                # the WRITTEN pool, so nothing orders a copy of it
-                with jax.named_scope("gather"):
-                    kc, vc = _pool_rows(kc, slots), _pool_rows(vc, slots)
-            dh = q.shape[-1]
-            a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
-                          window=window).astype(compute_dtype)
-            x = x + _proj_mq(blk["wo"], a, "bhk,hkd->bd", compute_dtype)
+                new_cache["k"].append(kc)
+                new_cache["v"].append(vc)
+                if slots is not None:
+                    # write first, then read: the stepped rows come out of
+                    # the WRITTEN pool, so nothing orders a copy of it
+                    with jax.named_scope("gather"):
+                        kc, vc = _pool_rows(kc, slots), _pool_rows(vc, slots)
+                dh = q.shape[-1]
+                a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
+                              window=window).astype(compute_dtype)
+                x = x + _proj_mq(blk["wo"], a, "bhk,hkd->bd", compute_dtype)
+        if "moe" in blk and "route" in blk["moe"]:
+            x = x + _held_moe(blk, _ln_apply(blk["ln2"], x), compute_dtype,
+                              auxs, valid)
+            continue
         with jax.named_scope("mlp"):
             h = _ln_apply(blk["ln2"], x)
             if "moe" in blk:
@@ -673,11 +873,34 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
                 else:
                     raise ValueError(f"unknown moe_impl {moe_impl!r}")
                 x = x + y[:, 0]
+            elif "gate" in blk["mlp"]:
+                x = x + gated_mlp(blk["mlp"], h, compute_dtype)
             else:
                 h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
                 x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
     x = _ln_apply(params["ln_f"], x)
-    return _dense_mq(params["head"], x, jnp.float32), new_cache
+    return _head(params, x, compute_dtype), new_cache, auxs
+
+
+def _mla_step(blk, pool, sink, x, pos, rows, slots, dtype):
+    """A latent block's attention at one position a row: project, write
+    the position's row into ``pool`` at its ring slot (the written pool
+    goes to ``sink``), read the stepped rows back (``slots``) and attend
+    absorbed.  ``pos`` is a scalar or one position a row."""
+    b = x.shape[0]
+    pos = jnp.broadcast_to(pos, (b,))
+    if rows is None:
+        rows = jnp.arange(b)
+    h = _ln_apply(blk["ln1"], x)
+    q_nope, q_pe, row = mla.project(
+        blk["mla"], h, *mla.rope(blk["mla"], pos), dtype)
+    with jax.named_scope("scatter"):
+        pool = pool.at[rows, pos % pool.shape[1]].set(row.astype(pool.dtype))
+    sink.append(pool)
+    if slots is not None:
+        with jax.named_scope("gather"):
+            pool = _pool_rows(pool, slots)
+    return mla.attend_absorbed(blk["mla"], q_nope, q_pe, pool, pos, dtype)
 
 
 def rollout(params, prefix, n_steps, compute_dtype=jnp.bfloat16,

@@ -36,6 +36,14 @@ from blendjax.utils.timing import fleet_counters
 logger = logging.getLogger("blendjax")
 
 
+def _wire_rows(rows):
+    """What a request carries: int32 for whole numbers (a token model's
+    ids), float32 for everything else (observations)."""
+    rows = np.asarray(rows)
+    return rows.astype(np.int32 if rows.dtype.kind in "iu" else np.float32,
+                       copy=False)
+
+
 class ServeRPCError(TimeoutError):
     """A serve RPC failed at the transport level (no reply within the
     policy, circuit open).  Subclasses :class:`TimeoutError` so callers
@@ -273,7 +281,7 @@ class ServeClient:
         records (bare servers ignore it)."""
         payload = self._model_payload({})
         if prefix is not None:
-            payload["prefix"] = np.asarray(prefix, np.float32)
+            payload["prefix"] = _wire_rows(prefix)
         if scenario is not None:
             payload["scenario"] = str(scenario)
         reply = self.rpc("reset", payload, timeout_ms=timeout_ms,
@@ -296,7 +304,7 @@ class ServeClient:
             "step",
             self._model_payload(
                 {"slot": int(use), "episode": self.episode,
-                 "obs": np.asarray(obs, np.float32)}
+                 "obs": _wire_rows(obs)}
             ),
             timeout_ms=timeout_ms, raw_buffers=True,
         )

@@ -292,6 +292,16 @@ class PolicyModel:
         return np.asarray(self._logits(self.params, obs))
 
 
+#: a token model's reply row: this many top logits, their ids, and the
+#: logsumexp over the vocabulary slice (2 * TOKEN_REPLY_TOP + 1 numbers)
+TOKEN_REPLY_TOP = 8
+
+#: the three counts a routed (held-share) model's step returns, in
+#: ``moe_apply_held``'s order; all in ``SERVE_EVENTS``
+MOE_EVENTS = ("serve_moe_assignments", "serve_moe_assignments_held",
+              "serve_moe_experts_hit")
+
+
 class SlotPoolLost(RuntimeError):
     """A donated call failed after it had taken the slot pool: the
     model holds a fresh, EMPTY pool and every lease on it is void (the
@@ -339,13 +349,38 @@ class SeqFormerModel:
         self.pad_slot = self.slots
         self.pool_rebuilds = 0
         emb = params["embed"]
-        self.obs_dim = (
-            emb["w"] if "w" in emb else emb["w_q"]
-        ).shape[0]
+        # what a request row is and what a reply row is come from the
+        # model: observations (float32, the projection's width) answered
+        # by a prediction, or one int32 token id answered by
+        # TOKEN_REPLY_TOP logits, their ids and the logsumexp
+        self.tokens = "table" in emb
+        if self.tokens:
+            self.obs_dim, self.obs_dtype = 1, np.int32
+        else:
+            self.obs_dim = (emb["w"] if "w" in emb else emb["w_q"]).shape[0]
+            self.obs_dtype = np.float32
+        routed = any("route" in blk.get("moe", ()) for blk in params["blocks"])
+        if window is not None and seqformer._latent(params):
+            raise ValueError("latent attention has no windowed path")
+        self._events = {}
         cdt = compute_dtype or jnp.float32
         self._cache_dtype = cache_dtype or cdt
         self._jnp = jnp
         self._cache = self._new_pool()
+        self._pool_names = [n for n in self._cache if n != "pos"]
+        pad = self.pad_slot
+
+        def reply_row(pred):
+            """What goes on the wire for one prediction row."""
+            if not self.tokens:
+                return pred
+            # a vocabulary-wide row a step would make the wire the
+            # bottleneck: the top logits, their ids (exact in float32)
+            # and the logsumexp over the slice, computed here
+            top, ids = jax.lax.top_k(pred, TOKEN_REPLY_TOP)
+            lse = jax.nn.logsumexp(pred, axis=-1, keepdims=True)
+            return jnp.concatenate(
+                [top, ids.astype(jnp.float32), lse], axis=-1)
 
         # the functions' names and scopes are what a profiler trace
         # and the compile log show: keep them (PERF.md section 3).
@@ -355,53 +390,62 @@ class SeqFormerModel:
         # contents are never read
         def serve_step(params, cache, idx, obs):
             with jax.named_scope("decode"):
-                return seqformer.decode_step(
+                if self.tokens:
+                    obs = obs[:, 0]
+                pred, cache, auxs = seqformer._decode(
                     params, cache, obs, compute_dtype=cdt, window=window,
-                    slots=idx,
+                    slots=idx, valid=(idx != pad) if routed else None,
                 )
+            if routed:
+                # the held-share layers' counts over the real rows, summed
+                # over layers: they ride the reply's fence
+                return (reply_row(pred),
+                        sum(a["counts"] for a in auxs)), cache
+            return reply_row(pred), cache
 
         # one compilation per (bucket,) shape — the bucket/recompile
         # tradeoff the admission queue pads for
         self._step = jax.jit(serve_step, donate_argnums=(1,))
 
         def serve_prefill(params, cache, row, prefix):
-            # ONE teacher-forced pass fills the slot's KV rows (the
+            # ONE teacher-forced pass fills the slot's cache rows (the
             # standard prefill/decode split, exactly rollout()'s
             # prefill phase) instead of T serial decode_steps.  k/v
             # are rotated before the sink, so the cache holds the same
             # bytes serial decode would have written; positions past
             # the ring keep only the tail that fits, placed at each
-            # position's ring slot.
+            # position's ring slot.  The model evaluates its own expert
+            # layer; a latent model attends expanded here and sinks its
+            # latent rows.
             from blendjax.parallel.ring_attention import full_attention
 
             kvs = []
             with jax.named_scope("forward"):
                 preds, _ = seqformer._forward(
-                    params, prefix[None],
+                    params, prefix[:, 0][None] if self.tokens
+                    else prefix[None],
                     lambda q, k, v: full_attention(
                         q, k, v, causal=True, window=window
                     ),
-                    cdt, "dense", 2, 1.25, kv_sink=kvs,
+                    cdt, kv_sink=kvs, last_only=self.tokens,
                 )
             t0 = prefix.shape[0]
-            ring = cache["k"][0].shape[1]
+            ring = cache[self._pool_names[0]][0].shape[1]
             keep_n = min(t0, ring)
             slots_ax = (jnp.arange(keep_n) + (t0 - keep_n)) % ring
             # keep_n positions of one row change; the pool is donated,
             # so these writes are in place
             with jax.named_scope("scatter"):
-                new = {"pos": cache["pos"].at[row].set(t0),
-                       "k": [], "v": []}
-                for i, (k, v) in enumerate(kvs):
-                    new["k"].append(
-                        cache["k"][i].at[row[0], slots_ax].set(
-                            k[0, t0 - keep_n:].astype(cache["k"][i].dtype)
-                        ))
-                    new["v"].append(
-                        cache["v"][i].at[row[0], slots_ax].set(
-                            v[0, t0 - keep_n:].astype(cache["v"][i].dtype)
-                        ))
-            return preds[0, -1], new
+                new = {"pos": cache["pos"].at[row].set(t0)}
+                for name in self._pool_names:
+                    new[name] = []
+                for i, kept in enumerate(kvs):
+                    for name, t in zip(self._pool_names, kept):
+                        new[name].append(
+                            cache[name][i].at[row[0], slots_ax].set(
+                                t[0, t0 - keep_n:].astype(cache[name][i].dtype)
+                            ))
+            return reply_row(preds[0, -1]), new
 
         # one compilation per prefix LENGTH (prefix rows are real
         # observations — padding them would write fabricated positions
@@ -425,13 +469,22 @@ class SeqFormerModel:
         pool = self._cache
         try:
             with span(f"serve.{what}.dispatch"):
-                pred, self._cache = fn(
+                out, self._cache = fn(
                     self.params, pool, self._jnp.asarray(idx),
                     self._jnp.asarray(arr),
                 )
             with span(f"serve.{what}.fence"):
                 # fence: compute timing stays honest
-                return np.asarray(pred)
+                if not isinstance(out, tuple):
+                    return np.asarray(out)
+                # a routed model's step: the counts come over with the
+                # reply, one fence for both
+                import jax
+
+                pred, counts = jax.device_get(out)
+                for name, n in zip(MOE_EVENTS, counts):
+                    self._events[name] = self._events.get(name, 0) + int(n)
+                return pred
         except Exception as exc:
             import jax
 
@@ -495,6 +548,12 @@ class SeqFormerModel:
             )
         _check_tree_like(self.params, tree, "seqformer")
         self.params = jax.tree.map(jnp.asarray, tree)
+
+    def drain_events(self):
+        """Counts the model's steps made since the last call (the
+        routed layers' ``MOE_EVENTS``), for the server's counters."""
+        events, self._events = self._events, {}
+        return events
 
     def reset_rows(self, idx):
         # rewinding pos to 0 is sufficient: _attn_one masks by each
@@ -858,10 +917,12 @@ class PolicyServer:
                 f"model {st.mid!r} ({st.model.kind}) is stateless or "
                 "has no prefill path: admit without a prefix"
             )
+        dtype = getattr(st.model, "obs_dtype", np.float32)
         try:
-            prefix = np.asarray(prefix, np.float32)
+            prefix = np.asarray(prefix, dtype)
         except (TypeError, ValueError) as exc:
-            return fail(f"prefix not coercible to float32: {exc}")
+            return fail(
+                f"prefix not coercible to {np.dtype(dtype).name}: {exc}")
         if prefix.ndim != 2 or prefix.shape[0] < 1 \
                 or prefix.shape[1] != st.model.obs_dim:
             return fail(
@@ -1234,11 +1295,13 @@ class PolicyServer:
                                 "(evicted and reassigned): reset() and resume"
                             ), lease="stale")
                             continue
+                    dtype = getattr(head.model, "obs_dtype", np.float32)
                     try:
-                        obs = np.asarray(ent.msg.get("obs"), np.float32)
+                        obs = np.asarray(ent.msg.get("obs"), dtype)
                     except (TypeError, ValueError) as exc:
                         self._step_entry_error(
-                            ent, f"step obs not coercible to float32: {exc}"
+                            ent, "step obs not coercible to "
+                                 f"{np.dtype(dtype).name}: {exc}"
                         )
                         continue
                     if obs.shape != (head.model.obs_dim,):
@@ -1265,7 +1328,7 @@ class PolicyServer:
                 for ent, _, _ in batch:
                     self.timer.add("queue_wait", t_assemble - ent.t_enq)
                 idx = np.full(bucket, model.pad_slot, np.int64)
-                obs_arr = np.zeros((bucket, model.obs_dim), np.float32)
+                obs_arr = np.zeros((bucket, model.obs_dim), dtype)
                 pos_before = []
                 now = time.monotonic()
                 for j, (ent, slot, obs) in enumerate(batch):
@@ -1297,6 +1360,9 @@ class PolicyServer:
                 self.timer.add("compute", t_reply - t_compute)
             with span("serve.tick.reply"):
                 self.counters.incr("serve_batches")
+                if hasattr(model, "drain_events"):
+                    for name, count in model.drain_events().items():
+                        self.counters.incr(name, count)
                 if bucket > n:
                     self.counters.incr("serve_batch_pad", bucket - n)
                 for j, (ent, slot, _) in enumerate(batch):

@@ -168,6 +168,12 @@ REPLAY_EVENTS = (
 #: after it had taken the slot pool: the pool was rebuilt empty and the
 #: model's leases dropped (docs/serving.md "KV-cache slot pool"); 0 in a
 #: healthy server.
+#: ``serve_moe_assignments`` / ``serve_moe_assignments_held`` /
+#: ``serve_moe_experts_hit`` — a routed (held-share) model's decode
+#: ticks, real rows only, summed over its expert layers: (token, expert)
+#: assignments the router made, those whose expert this rank holds, and
+#: distinct held experts that got a token (whose weights a tick must
+#: read); they come over with the reply's fence.
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
@@ -175,6 +181,8 @@ SERVE_EVENTS = (
     "serve_slot_denied", "serve_errors", "serve_prefills",
     "serve_wire_bytes", "serve_shm_bytes",
     "serve_prefill_us", "serve_idle_us", "serve_pool_rebuilds",
+    "serve_moe_assignments", "serve_moe_assignments_held",
+    "serve_moe_experts_hit",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md

@@ -83,3 +83,80 @@ def test_serve_step_moves_no_pool_tensor_on_the_chip(one_chip, bucket):
     assert not [line for line in text.splitlines()
                 if re.search(r" copy\(", line) and shape in line]
     assert "mini-gather-slice" not in text
+
+
+# the token cell's widths (chipbench/configs/sarvam105b_ep4_serve_bf16.json)
+# over its dense layer and one of its four expert layers
+TOKEN_POOL = dict(slots=128, length=2048)
+TOKEN_WIDTHS = dict(
+    hidden_size=4096, num_attention_heads=64, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    rope_theta=10000, rope_scaling=dict(
+        factor=40, beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1,
+        original_max_position_embeddings=4096),
+    num_hidden_layers=2, first_k_dense_replace=1, intermediate_size=16384,
+    moe_intermediate_size=2048, num_experts=128, num_experts_per_tok=8,
+    routed_scaling_factor=2.5, num_shared_experts=1, vocab_size=65536)
+
+
+@pytest.mark.parametrize("what,n", [("step", 64), ("prefill", 256)])
+def test_latent_pool_is_served_in_place_on_the_chip(one_chip, what, n):
+    """A latent-attention, routed-expert token model at its published
+    widths: the donated latent pool is aliased to the output with no
+    pool-shaped `copy` (a row 576 wide would be laid out positions-minor
+    and copied twice a layer: `mla.row_width` pads it to whole lanes),
+    the rows are read by pieces, the grouped products are the TPU
+    compiler's own kernel, and the decode step's temporaries stay far
+    under one layer of per-head K and V (absorbed attention never forms
+    them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+    from blendjax.serve.server import SeqFormerModel
+
+    tiny_widths = dict(
+        TOKEN_WIDTHS, hidden_size=32, num_attention_heads=2, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        intermediate_size=32, moe_intermediate_size=16, num_experts=8,
+        num_experts_per_tok=2, vocab_size=64)
+    tiny = SeqFormerModel(
+        seqformer.init_token_model(jax.random.PRNGKey(0), tiny_widths,
+                                   held=(0, 4), dtype=jnp.bfloat16),
+        slots=2, length=16, compute_dtype=jnp.bfloat16,
+        cache_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: seqformer.init_token_model(
+        jax.random.PRNGKey(0), TOKEN_WIDTHS, held=(0, 32),
+        dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: seqformer.init_cache(
+        params, TOKEN_POOL["slots"] + 1, dtype=jnp.bfloat16,
+        length=TOKEN_POOL["length"], per_row=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    fn = tiny._step if what == "step" else tiny._prefill
+    compiled = fn.lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((n if what == "step" else 1,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32, sharding=one_chip),
+    ).compile()
+    tensor = cache["kv"][0]
+    assert tensor.shape == (129, 2048, 640)
+    tensor_bytes = int(np.prod(tensor.shape)) * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * tensor_bytes
+    text = compiled.as_text()
+    assert f"jit_serve_{what}" in text
+    assert not [line for line in text.splitlines()
+                if re.search(r" copy\(", line) and "[129,2048,640]" in line]
+    assert "mini-gather-slice" not in text
+    assert text.count("ragged-dot") >= 3  # gate, up, down: grouped kernels
+    if what == "step":
+        # the gathered latent rows of one layer and little else; one
+        # layer's per-head K and V of those rows would be 5.4 GB
+        rows_bytes = n * tensor_bytes // tensor.shape[0]
+        assert mem.temp_size_in_bytes < 1.25 * rows_bytes
+        assert rows_bytes * 20 < n * 2048 * 64 * 320 * 2

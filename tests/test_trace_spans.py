@@ -295,7 +295,50 @@ def _serve_prefill():
         jnp.ones((3, 4)))
 
 
+TINY_TOKENS = dict(
+    hidden_size=32, num_attention_heads=4, kv_lora_rank=16,
+    qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, rope_theta=10000,
+    rope_scaling=None, num_hidden_layers=2, first_k_dense_replace=1,
+    intermediate_size=64, moe_intermediate_size=16, num_experts=8,
+    num_experts_per_tok=2, routed_scaling_factor=2.5, num_shared_experts=1,
+    vocab_size=64)
+
+
+def _tiny_token_model():
+    return SeqFormerModel(
+        seqformer.init_token_model(jax.random.PRNGKey(0), TINY_TOKENS,
+                                   held=(2, 4)), slots=2, length=16)
+
+
+def _token_step():
+    model = _tiny_token_model()
+    return model._step.lower(
+        model.params, model._cache, jnp.zeros(2, jnp.int32),
+        jnp.ones((2, 1), jnp.int32))
+
+
+def _token_prefill():
+    model = _tiny_token_model()
+    return model._prefill.lower(
+        model.params, model._cache, jnp.zeros(1, jnp.int32),
+        jnp.ones((3, 1), jnp.int32))
+
+
+@pytest.mark.parametrize("name", [
+    "serve_moe_assignments", "serve_moe_assignments_held",
+    "serve_moe_experts_hit"])
+def test_routed_model_counters_are_in_the_vocabulary(name):
+    assert name in SERVE_EVENTS
+    assert TelemetryHub().scrape()["counters"][name] == 0
+
+
 @pytest.mark.parametrize("lower,module,scopes", [
+    (_token_step, "serve_step",
+     ("decode", "mla", "absorb", "scatter", "gather", "moe", "route",
+      "experts", "shared", "mlp", "ln", "head")),
+    (_token_prefill, "serve_prefill",
+     ("forward", "mla", "expand", "scatter", "moe", "route", "experts",
+      "shared", "mlp", "ln", "head")),
     (_train_step, "train_step",
      ("loss", "optimizer", "attn", "mlp", "ln")),
     (_serve_step, "serve_step",
