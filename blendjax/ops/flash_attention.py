@@ -12,15 +12,34 @@ traffic is O(T*D) and the MXU sees back-to-back (block_q, D) x
 Grid layout: ``(B*H, T/block_q, T/block_kv)`` with the KV dimension
 innermost — TPU grid steps run sequentially per core, so the f32
 accumulator/max/sum scratch carries across KV steps and is written to
-the output on the last one.
+the output on the last one; the two outer axes are declared ``parallel``
+to the compiler.  Where one KV block is the whole sequence (the train
+shape, T=512) the forward is a plain softmax with nothing carried.
 
 Differentiation is fully fused too (``custom_vjp``): the forward also
 emits the per-row logsumexp, and the backward runs two block-wise
 kernels — a dQ pass (KV innermost, dQ accumulator carried) and a dK/dV
-pass (Q innermost) — recomputing probabilities from the saved logsumexp
-(FlashAttention-2 recurrence, with ``D = rowsum(dO * O)`` as the
-softmax-jacobian correction).  No (T, T) matrix exists in either
+pass (Q innermost, its tiles transposed so p^T and ds^T are matmul
+operands as they stand) — recomputing probabilities from the saved
+logsumexp (FlashAttention-2 recurrence, with ``D = rowsum(dO * O)`` as
+the softmax-jacobian correction).  No (T, T) matrix exists in either
 direction; gradient parity vs the einsum reference is tested to ~5e-5.
+
+What a grid step hands the chip (PR 29; the numbers are in PERF.md):
+
+* **Operands follow the inputs' dtype, accumulation is float32.**
+  bfloat16 q/k/v/dO blocks go to the MXU as bfloat16, p and ds are
+  rounded to their partner's dtype before p@v, p^T@dO, ds@k, ds^T@q;
+  float32 inputs keep float32 products.  Softmax state (m, l, lse,
+  delta, exp, the mask) is float32 always.  (On a v5e Mosaic's default
+  float32 product is already one bfloat16 pass, so this changed neither
+  a time nor a result there; it halves the blocks' VMEM and registers.)
+* **Tiles as large as divide the sequence and fit VMEM**
+  (:func:`flash_block_size`): a grid step costs ~0.5-1 us whatever it
+  computes, so 128-tiles were step-bound: x5.6 at T=512, x6.9 at T=4096.
+* **lse and delta travel as lane-dense rows** (:func:`_row_blocks`), not
+  as (T, 1) columns whose every number is a DMA row of its own: at 512
+  tiles the columns alone were 0.8 ms of a 1.3 ms backward call.
 
 Interpret mode runs the same kernel on CPU for CI (parity against
 ``full_attention`` is tested both causal and not).  ONE rule picks it,
@@ -39,6 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
+_LANES = 128
 
 
 def resolve_interpret(interpret=None):
@@ -57,12 +77,52 @@ def _default_scale(scale, d):
     return scale if scale is not None else 1.0 / (d ** 0.5)
 
 
-def flash_block_size(seq_len):
-    """Largest flash tile dividing ``seq_len`` (or ``seq_len`` itself —
-    legal on TPU via the 'equal to the array dim' tiling clause).  THE
-    tile-selection policy, shared by the ring/ulysses parallel paths and
-    user code sizing the kernel for arbitrary sequence lengths."""
-    return next((b for b in (128, 64, 32) if seq_len % b == 0), seq_len)
+_TILES = (1024, 512, 256, 128, 64, 32)
+_WINDOW_TILE_CAP = 512
+_VMEM_BUDGET = 24 * 2 ** 20
+
+
+def _tile_vmem_bytes(block, head_dim, itemsize):
+    """VMEM one grid step of the dK/dV pass (the largest live set of the
+    three) holds at a square ``block`` tile: q, k, v, dO in and dK, dV
+    out, each double-buffered by the pipeline; two float32 accumulators;
+    three float32 (block, block) score-sized temporaries."""
+    io = 2 * 6 * block * head_dim * itemsize
+    acc = 2 * block * head_dim * 4
+    return io + acc + 3 * block * block * 4
+
+
+def flash_block_size(seq_len, head_dim=128, dtype=jnp.bfloat16, window=None):
+    """THE tile-selection policy (``block_q == block_kv``, all three
+    kernels), shared by ``block='auto'``, the ring/ulysses parallel paths
+    and user code: the LARGEST tile of 1024 ... 32 that divides
+    ``seq_len`` and fits VMEM, or ``seq_len`` itself where none divides
+    (legal on TPU via the 'equal to the array dim' tiling clause).
+
+    Why the largest: a grid step costs ~0.5-1 us whatever it computes (its
+    DMAs, its ``pl.when``s, the scratch read-modify-write), so on a v5e
+    small tiles are step-bound, and the causal work a big tile wastes above
+    the diagonal costs less than the steps it saves.  Measured, bfloat16,
+    d=128, one call of fwd + dq + dkv (PERF.md, PR 29): 64 x 8 heads at
+    T=512, 12.0 ms at 128 tiles, 5.8 at 256, 2.1 at 512 (one step per
+    head); 8 x 8 heads at T=4096, 76 ms at 128, 15.2 at 512, 11.1 at 1024.
+    The same order held for forward, dQ and dK/dV alone and for every
+    non-square pair tried, so one number serves all three.  Under a
+    sliding ``window`` the shrunk grids keep their O(T*W) step count at any
+    tile; 512 beat 256 and 128 at W=256 and W=1024, larger was not
+    measured, so windowed calls stop at 512.
+
+    Against VMEM: ``_tile_vmem_bytes`` under ``_VMEM_BUDGET``, set from
+    what the v5e compiler accepts (1024 tiles compile up to d=256 float32
+    and are refused at d=512, where 512 tiles compile;
+    ``tests/test_tpu_compile.py`` compiles the policy's choices)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    cap = _TILES[0] if window is None else _WINDOW_TILE_CAP
+    for block in _TILES:
+        if (seq_len % block == 0 and block <= cap and _tile_vmem_bytes(
+                block, head_dim, itemsize) <= _VMEM_BUDGET):
+            return block
+    return seq_len
 
 
 def _block_live(causal, qi, kj, block_q, block_kv, window=None,
@@ -183,12 +243,15 @@ def _kv_axis(num_kv, block_q, block_kv, window, q_offset, khm):
     return steps, im
 
 
-def _mask(s, i, j, block_q, block_kv, window=None, q_offset=0):
+def _mask(s, i, j, block_q, block_kv, window=None, q_offset=0,
+          transposed=False):
+    """Causal (and window) mask of a score tile; ``transposed`` for the
+    dK/dV pass's (block_kv, block_q) tiles, whose rows are key columns."""
     rows = q_offset + i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 0
+        jnp.int32, s.shape, 1 if transposed else 0
     )
     cols = j * block_kv + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1
+        jnp.int32, s.shape, 0 if transposed else 1
     )
     keep = cols <= rows
     if window is not None:
@@ -196,22 +259,69 @@ def _mask(s, i, j, block_q, block_kv, window=None, q_offset=0):
     return jnp.where(keep, s, _NEG)
 
 
+def _dot(a, b, ca, cb):
+    """``a`` x ``b`` contracting dims ``ca``/``cb``, accumulated in
+    float32.  The operands keep the dtype the caller passed in (bfloat16
+    blocks go to the MXU as bfloat16, float32 blocks as float32); a mixed
+    pair is promoted, never rounded down."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(
+        a.astype(dt), b.astype(dt), (((ca,), (cb,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _scores(q_ref, k_ref, qi, kj, scale, causal, block_q, block_kv,
             window=None, q_offset=0):
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+    s = _dot(q_ref[0], k_ref[0], 1, 1) * scale
     if causal:
         s = _mask(s, qi, kj, block_q, block_kv, window, q_offset)
-    return q, k, s
+    return s
+
+
+def _row_to_col(row):
+    """(1, n) float32 row -> (n, 1) column.  lse and delta travel as
+    lane-dense rows (a (n, 1) block moves one number per 512-byte DMA row,
+    which alone was most of the kernels' time at 512 tiles); the one
+    kernel that needs them down the sublanes turns them here."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _emit_rows(o_ref, lse_ref, acc, m, l):
+    """Normalize ``acc`` into the output block and write the lse row.
+    ``m`` / ``l`` are per-row columns, (block_q, 1) or lane-replicated
+    (block_q, 128); replicated, their transpose's first row IS the
+    lane-dense lse row (see :func:`_row_blocks` for why lse does not ride
+    as a column)."""
+    safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc / safe[:, :1]).astype(o_ref.dtype)
+    lse = jnp.broadcast_to(m + jnp.log(safe), (acc.shape[0], _LANES))
+    lse_ref[0, 0] = lse.T[:1]
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
             scale, causal, block_q, block_kv, num_kv, num_kv_total=None,
             window=None, q_offset=0):
+    i = pl.program_id(1)
     j = pl.program_id(2)
+    if num_kv_total == 1 and window is None:
+        # the whole KV sequence is this one block (always live: column 0
+        # is visible to every row): a plain softmax, no running max / sum
+        # / accumulator to initialize, rescale and read back.  Under a
+        # `pl.when` like every ref access of this file: at a kernel's top
+        # level the interpreter's block reads fail shard_map's
+        # varying-axes check (the ulysses path, on the CPU mesh)
+        @pl.when(j == 0)  # always: the one step there is
+        def _single():
+            s = _scores(q_ref, k_ref, i, 0, scale, causal, block_q,
+                        block_kv, None, q_offset)
+            v = v_ref[0]
+            m = s.max(axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            _emit_rows(o_ref, lse_ref, _dot(p.astype(v.dtype), v, 1, 0), m,
+                       p.sum(axis=-1, keepdims=True))
+
+        return
 
     @pl.when(j == 0)
     def _init():
@@ -219,7 +329,6 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    i = pl.program_id(1)
     # under a window the grid's kv axis is shrunk: step j maps to actual
     # kv block base(i) + j (overshoot steps are killed by _block_live)
     kj = j if window is None else _kv_base(
@@ -233,33 +342,24 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(live)
     def _compute():
-        _, _, s = _scores(q_ref, k_ref, i, kj, scale, causal, block_q,
-                          block_kv, window, q_offset)
-        v = v_ref[0].astype(jnp.float32)
+        s = _scores(q_ref, k_ref, i, kj, scale, causal, block_q,
+                    block_kv, window, q_offset)
+        v = v_ref[0]
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_cur)
         alpha = jnp.exp(m_prev - m_cur)
         l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        acc_ref[...] = acc_ref[...] * alpha + _dot(
+            p.astype(v.dtype), v, 1, 0
         )
         m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(j == num_kv - 1)
     def _emit():
-        l = l_ref[:, :1]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
-        # lse rides as (bh, t, 1) — a (block_q, 1) block keeps the
-        # Mosaic tiling rule (last two block dims divisible by (8, 128)
-        # or equal to the array dims); a flat (1, block_q) lse block is
-        # rejected by the TPU lowering (caught by the tpu-platform
-        # export test, tests/test_tpu_lowering.py)
-        lse_ref[0] = m_ref[:, :1] + jnp.log(safe)
+        _emit_rows(o_ref, lse_ref, acc_ref[...], m_ref[...], l_ref[...])
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -281,20 +381,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(live)
     def _compute():
-        _, k, s = _scores(q_ref, k_ref, i, kj, scale, causal, block_q,
-                          block_kv, window, q_offset)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        p = jnp.exp(s - lse_ref[0].astype(jnp.float32))  # (bq,1) bcast
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0].astype(jnp.float32)) * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        s = _scores(q_ref, k_ref, i, kj, scale, causal, block_q,
+                    block_kv, window, q_offset)
+        k = k_ref[0]
+        p = jnp.exp(s - _row_to_col(lse_ref[0, 0]))  # (bq,1) bcast
+        dp = _dot(do_ref[0], v_ref[0], 1, 1)
+        ds = p * (dp - _row_to_col(delta_ref[0, 0])) * scale
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, 1, 0)
 
     @pl.when(j == num_kv - 1)
     def _emit():
@@ -326,29 +419,32 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
 
     @pl.when(live)
     def _compute():
-        q, _, s = _scores(q_ref, k_ref, qi, j, scale, causal, block_q,
-                          block_kv, window, q_offset)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        p = jnp.exp(s - lse_ref[0].astype(jnp.float32))  # (bq,1) bcast
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0].astype(jnp.float32)) * scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        # every tile here is TRANSPOSED, (block_kv, block_q): p^T and ds^T
+        # are then the left operands of dV = p^T dO and dK = ds^T Q as
+        # they stand (no (block, block) transpose before either product),
+        # and lse / delta broadcast down the sublanes as the rows they are
+        q, do = q_ref[0], do_ref[0]
+        s_t = _dot(k_ref[0], q, 1, 1) * scale
+        if causal:
+            s_t = _mask(s_t, qi, j, block_q, block_kv, window, q_offset,
+                        transposed=True)
+        p_t = jnp.exp(s_t - lse_ref[0, 0])  # (1,bq) bcast
+        dv_acc[...] += _dot(p_t.astype(do.dtype), do, 1, 0)
+        dp_t = _dot(v_ref[0], do, 1, 1)
+        ds_t = p_t * (dp_t - delta_ref[0, 0]) * scale
+        dk_acc[...] += _dot(ds_t.astype(q.dtype), q, 1, 0)
 
     @pl.when(i == num_q - 1)
     def _emit():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# bh and the middle block axis are independent; the innermost axis carries
+# the accumulators (all three kernels)
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _scratch(shapes):
@@ -370,6 +466,29 @@ def _sds(shape, dtype, like):
     so the kernel composes inside shard_map (e.g. as Ulysses' inner
     attention) under vma typing."""
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
+
+
+def _row_spec(block_q, q_im):
+    """BlockSpec of the (1, block_q) lse / delta row that belongs to the
+    q block ``q_im`` picks; the arrays are ``_row_blocks``-shaped."""
+
+    def index_map(*grid):
+        b, i, _ = q_im(*grid)
+        return (b, i, 0, 0)
+
+    return pl.BlockSpec((1, 1, 1, block_q), index_map)
+
+
+def _row_blocks(x, block_q):
+    """Per-row statistics (lse, delta: ``bh * t`` float32 numbers in
+    ``(bh, t)`` order, any shape) as ``(bh, t / block_q, 1, block_q)``, so
+    that a kernel's block is one lane-dense ``(1, block_q)`` row whose last
+    two dims equal the array's (legal on TPU at any ``block_q``).  As
+    ``(bh, t, 1)`` columns — what the kernels read until PR 29 — a block
+    is ``block_q`` DMA rows of one number each: at 512 tiles that traffic
+    alone took 0.8 ms of a 1.3 ms backward call (PERF.md, PR 29)."""
+    bh = x.shape[0]
+    return x.reshape(bh, x.size // (bh * block_q), 1, block_q)
 
 
 def _check_blocks(t, block, name):
@@ -421,31 +540,29 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_kv, interpret,
         block_kv=block_kv, num_kv=kv_steps, num_kv_total=num_kv,
         window=window, q_offset=q_offset,
     )
+    q_im = lambda bh, i, j: (bh, i, 0)
+    q_spec = pl.BlockSpec((1, block_q, d), q_im)
     of, lse = pl.pallas_call(
         kernel,
         grid=(b * h, num_q, kv_steps),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            q_spec,
             pl.BlockSpec((1, block_kv, d), kv_im),
             pl.BlockSpec((1, block_kv, d), kv_im),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            # (bh, t, 1): a (block_q, 1) trailing block satisfies the
-            # Mosaic (8, 128)-or-equal tiling rule; (1, block_q) doesn't
-            pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0)),
-        ],
+        out_specs=[q_spec, _row_spec(block_q, q_im)],
         out_shape=[
             _sds((b * h, t, d), out_dtype or q.dtype, qf),
-            _sds((b * h, t, 1), jnp.float32, qf),
+            _sds((b * h, num_q, 1, block_q), jnp.float32, qf),
         ],
         scratch_shapes=_scratch([
-            (block_q, d), (block_q, 128), (block_q, 128)
+            (block_q, d), (block_q, _LANES), (block_q, _LANES)
         ]),
+        compiler_params=_GRID_SEMANTICS,
         interpret=resolve_interpret(interpret),
         name="flash_fwd",
     )(qf, kf, vf)
-    return _unflat(of, b, h), (qf, kf, vf, of, lse)
+    return _unflat(of, b, h), (qf, kf, vf, of, lse.reshape(b * h, 1, t))
 
 
 def _check_window(causal, window):
@@ -522,7 +639,8 @@ def _dq_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
     sequence by :func:`flash_attention`'s vjp and per ring-block pair by
     :func:`blendjax.parallel.ring_attention.ring_flash_attention` (which
     passes ``out_dtype=f32`` so its cross-block accumulation never sums
-    rounded partials)."""
+    rounded partials).  ``lse`` / ``delta``: one float32 per q row in
+    ``(bh, tq)`` order, in any shape (:func:`_row_blocks` shapes them)."""
     bh, tq, d = qf.shape
     tk = kf.shape[1]
     _check_window_overshoot(window, q_offset, tq, tk)
@@ -531,9 +649,10 @@ def _dq_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
     kv_steps, kv_im = _kv_axis(
         num_kv, block_q, block_kv, window, q_offset, khm
     )
-    q_spec_i = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
+    q_im = lambda bh, i, j: (bh, i, 0)
+    q_spec_i = pl.BlockSpec((1, block_q, d), q_im)
     kv_spec_j = pl.BlockSpec((1, block_kv, d), kv_im)
-    row_spec_i = pl.BlockSpec((1, block_q, 1), lambda bh, i, j: (bh, i, 0))
+    row_spec_i = _row_spec(block_q, q_im)
     return pl.pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -546,9 +665,11 @@ def _dq_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
         out_specs=q_spec_i,
         out_shape=_sds((bh, tq, d), out_dtype or qf.dtype, qf),
         scratch_shapes=_scratch([(block_q, d)]),
+        compiler_params=_GRID_SEMANTICS,
         interpret=resolve_interpret(interpret),
         name="flash_bwd_dq",
-    )(qf, kf, vf, dof, lse, delta)
+    )(qf, kf, vf, dof, _row_blocks(lse, block_q),
+      _row_blocks(delta, block_q))
 
 
 def _dkv_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
@@ -581,7 +702,7 @@ def _dkv_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
         kv_in_spec = pl.BlockSpec(
             (1, block_kv, d), lambda bh, j, i: (khm(bh), j, 0)
         )
-    row_spec_inner = pl.BlockSpec((1, block_q, 1), q_im)
+    row_spec_inner = _row_spec(block_q, q_im)
     return pl.pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -597,9 +718,18 @@ def _dkv_pass(qf, kf, vf, dof, lse, delta, causal, scale, block_q,
             _sds((bh, tk, d), out_dtype or vf.dtype, qf),
         ],
         scratch_shapes=_scratch([(block_kv, d), (block_kv, d)]),
+        compiler_params=_GRID_SEMANTICS,
         interpret=resolve_interpret(interpret),
         name="flash_bwd_dkv",
-    )(qf, kf, vf, dof, lse, delta)
+    )(qf, kf, vf, dof, _row_blocks(lse, block_q),
+      _row_blocks(delta, block_q))
+
+
+def _delta(dof, of):
+    """``D_i = rowsum(dO * O)``, the softmax-jacobian correction term, per
+    row in ``(bh, t)`` order like lse (the passes shape both themselves,
+    :func:`_row_blocks`)."""
+    return (dof.astype(jnp.float32) * of.astype(jnp.float32)).sum(-1)
 
 
 def _bwd(causal, scale, block_q, block_kv, interpret, window, res, g):
@@ -609,11 +739,7 @@ def _bwd(causal, scale, block_q, block_kv, interpret, window, res, g):
     heads = (h, h_kv) if h_kv != h else None
     scale_v = _default_scale(scale, d)
     dof = _flat(g)
-    # D_i = rowsum(dO * O): the softmax-jacobian correction term; rides
-    # as (bh, t, 1) like lse (Mosaic trailing-block tiling rule)
-    delta = (dof.astype(jnp.float32) * of.astype(jnp.float32)).sum(
-        -1, keepdims=True
-    )
+    delta = _delta(dof, of)
     dq = _dq_pass(qf, kf, vf, dof, lse, delta, causal, scale_v, block_q,
                   block_kv, interpret, window=window, heads=heads)
     dk, dv = _dkv_pass(qf, kf, vf, dof, lse, delta, causal, scale_v,
@@ -647,12 +773,14 @@ def make_flash_attention(causal=True, block_q=128, block_kv=128,
     drop-in for the default ``full_attention``.
 
     ``block_q``/``block_kv`` may be ``'auto'``: the tile is then sized
-    per call via :func:`flash_block_size`, so the closure works at any
+    per call via :func:`flash_block_size` (from the call's sequence
+    length, head size, dtype and window), so the closure works at any
     32-multiple sequence length (or any length up to 128, which fits a
     single tile) instead of requiring T to divide a fixed block.  Ragged
     lengths beyond that are rejected — the only "tile" dividing them is
     T itself, which would materialize the (T, T) score block the kernel
-    exists to avoid (pad upstream instead).
+    exists to avoid (pad upstream instead).  A number wins over the
+    policy.
 
     ``window=W`` enables sliding-window attention (causal only; see
     :func:`flash_attention`)."""
@@ -660,8 +788,8 @@ def make_flash_attention(causal=True, block_q=128, block_kv=128,
 
     def attn(q, k, v):
         t = q.shape[1]
-        auto = flash_block_size(t)
-        if (block_q == "auto" or block_kv == "auto") and auto == t and t > 128:
+        auto = flash_block_size(t, q.shape[-1], q.dtype, window)
+        if (block_q == "auto" or block_kv == "auto") and t % 32 and t > 128:
             raise ValueError(
                 f"sequence length {t} has no flash tile (not a multiple "
                 "of 32 and too long for a single tile); pad to a "

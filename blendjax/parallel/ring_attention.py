@@ -211,12 +211,12 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def _ring_blk(s_loc):
-    """Flash tile for a local shard — the shared policy from
+def _ring_blk(s_loc, q, window=None):
+    """Flash tile for a local shard of ``q`` — the shared policy from
     :func:`blendjax.ops.flash_attention.flash_block_size`."""
     from blendjax.ops.flash_attention import flash_block_size
 
-    return flash_block_size(s_loc)
+    return flash_block_size(s_loc, q.shape[-1], q.dtype, window)
 
 
 def _lse_combine(o, lse, o_b, lse_b):
@@ -288,7 +288,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret,
     me = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale_v = _default_scale(scale, d)
-    blk = _ring_blk(s_loc)
+    blk = _ring_blk(s_loc, q)
     perm = [(j, (j - 1) % n) for j in range(n)]
 
     def pair(kb, vb, diag):
@@ -356,7 +356,7 @@ def _ring_flash_fwd_windowed(q, k, v, axis_name, scale, interpret,
     me = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale_v = _default_scale(scale, d)
-    blk = _ring_blk(s_loc)
+    blk = _ring_blk(s_loc, q, window)
     perm_back = [(j, (j + 1) % n) for j in range(n)]
     dmax = _window_ring_deltas(window, s_loc, n)
 
@@ -399,6 +399,7 @@ def _ring_flash_bwd_windowed(axis_name, scale, interpret, window, res, g):
     through here."""
     from blendjax.ops.flash_attention import (
         _default_scale,
+        _delta,
         _dkv_pass,
         _dq_pass,
         _flat,
@@ -410,15 +411,13 @@ def _ring_flash_bwd_windowed(axis_name, scale, interpret, window, res, g):
     me = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale_v = _default_scale(scale, d)
-    blk = _ring_blk(s_loc)
+    blk = _ring_blk(s_loc, q, window)
     perm_back = [(j, (j + 1) % n) for j in range(n)]
     dmax = _window_ring_deltas(window, s_loc, n)
 
     qf, dof, of = _flat(q), _flat(g), _flat(out)
-    delta = (dof.astype(jnp.float32) * of.astype(jnp.float32)).sum(
-        -1, keepdims=True
-    )
-    lse_f = lse.reshape(b * h, s_loc, 1)
+    delta = _delta(dof, of)
+    lse_f = lse.reshape(b * h, s_loc)
 
     def pair_grads(kbf, vbf, q_offset):
         dq_c = _dq_pass(qf, kbf, vbf, dof, lse_f, delta, True, scale_v,
@@ -466,6 +465,7 @@ def _ring_flash_bwd(axis_name, causal, scale, interpret, vary_axes,
         )
     from blendjax.ops.flash_attention import (
         _default_scale,
+        _delta,
         _dkv_pass,
         _dq_pass,
         _flat,
@@ -477,14 +477,12 @@ def _ring_flash_bwd(axis_name, causal, scale, interpret, vary_axes,
     me = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale_v = _default_scale(scale, d)
-    blk = _ring_blk(s_loc)
+    blk = _ring_blk(s_loc, q)
     perm = [(j, (j - 1) % n) for j in range(n)]
 
     qf, dof, of = _flat(q), _flat(g), _flat(out)
-    delta = (dof.astype(jnp.float32) * of.astype(jnp.float32)).sum(
-        -1, keepdims=True
-    )
-    lse_f = lse.reshape(b * h, s_loc, 1)
+    delta = _delta(dof, of)
+    lse_f = lse.reshape(b * h, s_loc)
 
     def pair_grads(kbf, vbf, diag):
         # out_dtype=f32: per-pair gradients leave the kernels unrounded
@@ -612,7 +610,7 @@ def _zz_fwd(q, k, v, axis_name, scale, interpret, vary_axes):
     half = s_loc // 2
     c = 2 * n
     scale_v = _default_scale(scale, d)
-    blk = _ring_blk(half)
+    blk = _ring_blk(half, q)
     perm = [(j, (j - 1) % n) for j in range(n)]
 
     q_halves = (q[:, :half], q[:, half:])
@@ -674,6 +672,7 @@ def _zz_fwd(q, k, v, axis_name, scale, interpret, vary_axes):
 def _zz_bwd(axis_name, scale, interpret, vary_axes, res, g):
     from blendjax.ops.flash_attention import (
         _default_scale,
+        _delta,
         _dkv_pass,
         _dq_pass,
         _flat,
@@ -687,7 +686,7 @@ def _zz_bwd(axis_name, scale, interpret, vary_axes, res, g):
     half = s_loc // 2
     c = 2 * n
     scale_v = _default_scale(scale, d)
-    blk = _ring_blk(half)
+    blk = _ring_blk(half, q)
     perm = [(j, (j - 1) % n) for j in range(n)]
 
     def half_flat(x, i):  # (b, s_loc, h, d) -> flat (bh, half, d) half i
@@ -696,15 +695,10 @@ def _zz_bwd(axis_name, scale, interpret, vary_axes, res, g):
     qf_h = (half_flat(q, 0), half_flat(q, 1))
     dof_h = (half_flat(g, 0), half_flat(g, 1))
     of_h = (half_flat(out, 0), half_flat(out, 1))
-    delta_h = tuple(
-        (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-            -1, keepdims=True
-        )
-        for do, o in zip(dof_h, of_h)
-    )
+    delta_h = tuple(_delta(do, o) for do, o in zip(dof_h, of_h))
     lse_h = (
-        lse[:, :, :half].reshape(b * h, half, 1),
-        lse[:, :, half:].reshape(b * h, half, 1),
+        lse[:, :, :half].reshape(b * h, half),
+        lse[:, :, half:].reshape(b * h, half),
     )
     q_idx = (me, c - 1 - me)
 

@@ -187,7 +187,7 @@ def make_seqformer_train_step(
             # flash_interpret=None follows the kernel's own backend
             # rule; tests/test_tpu_lowering.py passes False to force
             # the compiled path when EXPORTING for tpu from a CPU host
-            blk = flash_block_size(q.shape[1])
+            blk = flash_block_size(q.shape[1], q.shape[-1], q.dtype, window)
             return flash_attention(
                 q, k, v, causal, scale, blk, blk, flash_interpret, window
             )

@@ -165,7 +165,7 @@ def leg_kernels(size):
     compile_s = 0.0
     mosaic = True
     for name, b, t, hq, hkv, d, window in size["kernel_cases"]:
-        blk = flash_block_size(t)
+        blk = flash_block_size(t, d, jnp.bfloat16, window)
         keys = jax.random.split(jax.random.PRNGKey(len(name) + t), 4)
         q = jax.random.normal(keys[0], (b, t, hq, d), jnp.bfloat16)
         k = jax.random.normal(keys[1], (b, t, hkv, d), jnp.bfloat16)

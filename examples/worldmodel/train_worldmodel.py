@@ -76,14 +76,11 @@ def make_attn(name, seq_len, window=None):
             f"--attn {name} is a parallel scheme; pass --mesh dp,sp,tp "
             "to use it (single-device options: full, flash)"
         )
-    from blendjax.ops.flash_attention import (
-        flash_block_size,
-        make_flash_attention,
-    )
+    from blendjax.ops.flash_attention import make_flash_attention
 
-    blk = flash_block_size(seq_len)  # T must divide the flash tile
+    # 'auto': the tile policy sees each call's T, head size, dtype, window
     return make_flash_attention(
-        causal=True, block_q=blk, block_kv=blk, window=window,
+        causal=True, block_q="auto", block_kv="auto", window=window,
     )
 
 

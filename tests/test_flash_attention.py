@@ -282,9 +282,19 @@ def test_make_flash_attention_auto_tiles_to_sequence():
     )
     from blendjax.parallel.ring_attention import full_attention
 
-    assert flash_block_size(512) == 128
+    # the largest tile that divides the length and fits VMEM: a grid step
+    # costs more than the causal work a big tile wastes (PERF.md, PR 29)
+    assert flash_block_size(512) == 512  # the train cell: one step a head
+    assert flash_block_size(4096) == 1024
+    assert flash_block_size(768) == 256
     assert flash_block_size(160) == 32
     assert flash_block_size(20) == 20  # falls back to the length itself
+    # a function of what the call sees: head size and dtype against
+    # VMEM, and windowed calls stop at the largest tile measured for them
+    assert flash_block_size(4096, 256, jnp.float32) == 512
+    assert flash_block_size(4096, 128, jnp.float32) == 1024
+    assert flash_block_size(4096, 1024, jnp.float32) == 256
+    assert flash_block_size(4096, window=256) == 512
 
     attn = make_flash_attention(causal=True, block_q="auto",
                                 block_kv="auto", interpret=True)
@@ -300,3 +310,80 @@ def test_make_flash_attention_auto_tiles_to_sequence():
                             jnp.float32)
     with pytest.raises(ValueError, match="pad to a 32-multiple"):
         attn(bad, bad, bad)
+
+
+def _ref_out_and_grads(q, k, v, w):
+    """float32 reference: out and the gradients of sum(out * w)."""
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+
+    def loss(q, k, v):
+        o = full_attention(q, k, v, causal=True)
+        return jnp.sum(o * w), o
+
+    (_, o), g = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(*f32)
+    return (o, *g)
+
+
+@pytest.mark.parametrize("blocks", ["auto", (128, 128)])
+@pytest.mark.parametrize("dtype,out_tol,grad_tol", [
+    (jnp.bfloat16, 2e-2, 5e-2), (jnp.float32, 2e-5, 5e-5),
+])
+def test_train_cell_shape_matches_reference(blocks, dtype, out_tol, grad_tol):
+    """The train cell's per-head shape (T=512, d=128, causal) under the
+    tiles the policy picks (one 512 tile a head: the single-block forward,
+    the transposed dK/dV tiles, lse and delta as rows) and under explicit
+    128 x 128 (the carried accumulators): bfloat16 inputs, whose blocks
+    go to the products as bfloat16, within the bfloat16 tolerance of the
+    float32 reference; float32 inputs within the float32 one."""
+    q, k, v = _qkv(b=1, t=512, h=2, d=128, dtype=dtype, seed=3)
+    w = jax.random.normal(jax.random.PRNGKey(4), q.shape, jnp.float32)
+    bq, bkv = (blocks, blocks) if blocks == "auto" else blocks
+    attn = make_flash_attention(causal=True, block_q=bq, block_kv=bkv,
+                                interpret=True)
+
+    def loss(q, k, v):
+        o = attn(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+
+    (_, o), g = jax.jit(
+        jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+    assert o.dtype == dtype and all(x.dtype == dtype for x in g)
+    ref = _ref_out_and_grads(q, k, v, w)
+    for got, want, tol in zip((o, *g), ref, (out_tol,) + (grad_tol,) * 3):
+        scale = float(np.abs(np.asarray(want)).max())
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32) / scale, np.asarray(want) / scale,
+            atol=tol, rtol=tol,
+        )
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general equation under ``jaxpr``, kernels' bodies too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _dot_generals(inner)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_products_take_the_inputs_dtype(dtype):
+    """bfloat16 blocks reach every product of the three kernels as
+    bfloat16 (p and ds are rounded to their partner's dtype,
+    FlashAttention-2's convention), float32 blocks as float32 (what they
+    lowered to before); every product accumulates in float32."""
+    q, k, v = _qkv(b=1, t=128, h=1, d=128, dtype=dtype)
+
+    def loss(q, k, v):
+        return flash_attention(
+            q, k, v, True, None, 64, 64, True).astype(jnp.float32).sum()
+
+    dots = list(_dot_generals(
+        jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr))
+    assert len(dots) == 9  # forward 2, dQ pass 3, dK/dV pass 4
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
+        assert eqn.params["preferred_element_type"] == jnp.float32

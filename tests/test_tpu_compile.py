@@ -160,3 +160,62 @@ def test_latent_pool_is_served_in_place_on_the_chip(one_chip, what, n):
         rows_bytes = n * tensor_bytes // tensor.shape[0]
         assert mem.temp_size_in_bytes < 1.25 * rows_bytes
         assert rows_bytes * 20 < n * 2048 * 64 * 320 * 2
+
+
+# the train cell (chipbench/configs/seqformer_wm100m_train_bf16.json: batch
+# 64 x 512, 8 heads of 128, bfloat16 compute, block 'auto'), the long
+# sequence of chip_smoke.py's kernel leg, and the widest float32 head the
+# policy gives its second-largest tile (what `_VMEM_BUDGET` is set against)
+@pytest.mark.parametrize("name,b,t,h,d,dtype,tile", [
+    ("train_cell", 64, 512, 8, 128, "bfloat16", 512),
+    ("t4096", 8, 4096, 8, 128, "bfloat16", 1024),
+    ("t4096_f32", 2, 4096, 2, 128, "float32", 1024),
+    ("t4096_f32_d256", 2, 4096, 2, 256, "float32", 512),
+])
+def test_flash_kernels_compile_at_the_policys_tiles(
+        one_chip, name, b, t, h, d, dtype, tile):
+    """Forward and backward of `flash_attention` under `block='auto'`:
+    the three kernels are in the compiled module under their names (the
+    benchmark's `kernel.*` readers find device seconds by them), the
+    chip's compiler takes the policy's tile within VMEM, q, k, v and dO
+    reach the kernels in the dtype they were passed in (bfloat16 blocks
+    are not converted to float32 ahead of the call), and lse and delta
+    travel as lane-dense rows of one block each, not as columns."""
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.ops.flash_attention import (
+        flash_block_size,
+        make_flash_attention,
+    )
+
+    dtype = jnp.dtype(dtype)
+    assert flash_block_size(t, d, dtype) == tile
+    attn = make_flash_attention(causal=True, block_q="auto",
+                                block_kv="auto", interpret=False)
+    arg = jax.ShapeDtypeStruct((b, t, h, d), dtype, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((b, t, h, d), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, w):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        arg, arg, arg, w).compile().as_text()
+    calls = {}
+    for line in text.splitlines():
+        if "custom_call_target=\"tpu_custom_call\"" not in line:
+            continue
+        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            if re.search(rf"%\S*{kernel}\S* = ", line):
+                calls[kernel] = line
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    short = {"bfloat16": "bf16", "float32": "f32"}[dtype.name]
+    block = f"{short}[{b * h},{t},{d}]"
+    rows = f"f32[{b * h},{t // tile},1,{tile}]"
+    for kernel, line in calls.items():
+        operands = re.search(
+            r"operand_layout_constraints=\{(.*?\})\}", line).group(1)
+        n_blocks = 3 if kernel == "flash_fwd" else 4
+        assert operands.count(block) == n_blocks, (kernel, operands)
+        assert operands.count(rows) == (0 if kernel == "flash_fwd" else 2)
+    assert rows in calls["flash_fwd"].split(" custom-call(")[0]  # lse out

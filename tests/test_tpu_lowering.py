@@ -8,7 +8,9 @@ was real: the flash kernel's original flat ``(1, block_q)`` lse output
 block violated the Mosaic trailing-block tiling rule (last two block
 dims divisible by (8, 128) or equal to the array dims) and would have
 failed its first-ever compiled run on the chip (round 5; the artifact
-would have silently degraded to full attention).
+would have silently degraded to full attention).  Since PR 29 lse rides
+as ``(1, block_q)`` rows of a ``(bh, T/block_q, 1, block_q)`` array,
+whose last two block dims EQUAL the array's: the legal form of a row.
 """
 
 import jax
@@ -360,7 +362,8 @@ def test_windowed_ring_flash_sharded_step_lowers_for_tpu():
 
 def test_flash_attention_32_tile_lowers_for_tpu():
     """The bench gate now admits any 32-multiple length; sub-128 tiles
-    (lse blocks (32, 1), scratch (32, 128)) must lower too — a Mosaic
+    (lse rows (1, 32) of a (bh, T/32, 1, 32) array, scratch (32, 128))
+    must lower too — a Mosaic
     rejection specific to small tiles must surface here, not mid-bench
     on the chip."""
     from blendjax.ops.flash_attention import make_flash_attention
