@@ -186,11 +186,10 @@ shmbench:
 
 # Policy-serving microbench (docs/serving.md): 8 concurrent episode
 # clients against one continuously-batched seqformer world-model
-# server (KV-cache slot pool, per-row positions) vs the serial
-# one-request-per-REP baseline vs the int8-quantized server, in
-# interleaved order-rotated rounds.  One JSON line with the serving
-# headline: serve_qps, serve_p99_ms (client-observed union p99),
-# serve_batch_x (floor > 1 at 8 clients), serve_int8_x.
+# server (KV-cache slot pool, per-row positions) vs the int8-quantized
+# server, in interleaved order-rotated rounds.  One JSON line with the
+# serving headline: serve_qps, serve_p99_ms (client-observed union
+# p99), serve_int8_x.
 servebench:
 	env JAX_PLATFORMS=cpu \
 		$(PYTHON) benchmarks/serve_benchmark.py \

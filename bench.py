@@ -279,9 +279,8 @@ def main():
     # fifth configuration: the policy-serving inference tier
     # (docs/serving.md) — 8 concurrent episode clients against one
     # continuously-batched seqformer world-model server, interleaved
-    # against the serial one-request-per-REP baseline and the int8
-    # server: serve_qps + serve_p99_ms headline, serve_batch_x /
-    # serve_int8_x ratios.  CPU-pinned child (jax, loopback wire).
+    # against the int8 server: serve_qps + serve_p99_ms headline, the
+    # serve_int8_x ratio.  CPU-pinned child (jax, loopback wire).
     serve_bench = None
     remaining = TOTAL_BUDGET_S - (time.monotonic() - t_start) - 20
     if remaining > 45:
@@ -470,7 +469,6 @@ HEADLINE_TRIM_ORDER = (
     ("serve_prefill_x",),
     ("shm_rpc_x",),
     ("replay_shard_x", "replay_degraded_x"),
-    ("serve_batch_x",),
     ("gateway_qps", "gateway_p99_ms"),
     ("rl_sharded_x",),
     ("replay_sample_x",),
@@ -528,13 +526,11 @@ def headline(out):
     sb = out.get("serve_bench")
     if sb and sb.get("serve_qps") is not None:
         # the policy-serving tier headline: batched QPS + client-
-        # observed p99 at 8 concurrent episodes, with the continuous-
-        # batching-over-serial-REP and int8-over-float ratios
+        # observed p99 at 8 concurrent episodes, with the
+        # int8-over-float ratio
         line["serve_qps"] = sb["serve_qps"]
         if sb.get("serve_p99_ms") is not None:
             line["serve_p99_ms"] = sb["serve_p99_ms"]
-        if sb.get("serve_batch_x") is not None:
-            line["serve_batch_x"] = sb["serve_batch_x"]
         if sb.get("serve_int8_x") is not None:
             line["serve_int8_x"] = sb["serve_int8_x"]
         if sb.get("serve_prefill_x") is not None:
@@ -643,17 +639,16 @@ def assemble(phases, rl=None, rl_physics=None, feed_bound=None, rl_pipelined=Non
     unit-testable (tests/test_bench_assembly.py)."""
     extras = {"includes_rendering": False}
     if serve_bench and serve_bench.get("phase") == "serve_bench":
-        # the inference-tier ceiling: continuous-batched QPS/p99 over
-        # the serial baseline + the int8 ratio, stage percentiles
-        # included — see benchmarks/serve_benchmark.py
+        # the inference-tier ceiling: continuous-batched QPS/p99 + the
+        # int8 ratio, stage percentiles included — see
+        # benchmarks/serve_benchmark.py
         extras["serve_bench"] = {
             k: serve_bench[k]
             for k in (
                 "model", "clients", "slots", "rounds", "window_s",
                 "serve_qps", "serve_p50_ms", "serve_p99_ms",
-                "serve_batch_x", "serve_int8_x", "serve_prefill_x",
-                "prefill", "serve_qps_modes",
-                "pair_ratios", "stages",
+                "serve_int8_x", "serve_prefill_x",
+                "prefill", "serve_qps_modes", "stages",
             )
             if k in serve_bench
         }

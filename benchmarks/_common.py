@@ -58,22 +58,19 @@ REPLAY_SHARD_KEYS = (
 #: (phase ``serve_bench``); ``bench.py`` keys off these and
 #: ``tests/test_serve.py`` locks emission against this tuple.
 #: ``serve_qps``/``serve_p99_ms`` are the headline pair (median batched
-#: round; client-observed union p99); ``serve_batch_x`` is continuous
-#: batching over the one-request-per-REP serial baseline at the median
-#: interleaved round; ``serve_int8_x`` is the quantized server's QPS
-#: over the float one (None when ``--no-int8``).
+#: round; client-observed union p99); ``serve_int8_x`` is the quantized
+#: server's QPS over the float one (None when ``--no-int8``).
 SERVE_BENCH_KEYS = (
     "model", "clients", "slots", "obs_dim", "rounds", "window_s",
     "episode_len",
     "serve_qps", "serve_p50_ms", "serve_p99_ms",
-    "serve_batch_x", "serve_int8_x",
+    "serve_int8_x",
     # batched prefill admission (reset with a T-step prefix replayed in
     # ONE teacher-forced pass) vs T serial steps, median interleaved
     # pair; None for stateless served models
     "serve_prefill_x",
     "prefill",           # the sub-record (prefix_len/admissions/rates)
-    "serve_qps_modes",   # {"batched": .., "serial": .., "int8": ..}
-    "pair_ratios",
+    "serve_qps_modes",   # {"batched": .., "int8": ..}
     "stages",
 )
 

@@ -3,15 +3,12 @@
 
 Measures the ``blendjax/serve`` tier end-to-end over loopback TCP — N
 concurrent episode clients (threads) against an in-process
-:class:`~blendjax.serve.server.PolicyServer` — in three modes kept
+:class:`~blendjax.serve.server.PolicyServer` — in two modes kept
 alive for the whole run and compared over interleaved, order-rotated
 rounds (the drift-immune house scheme):
 
 - **batched**: continuous batching over the ROUTER socket (admission
   queue -> pad-to-bucket -> one jitted call per tick);
-- **serial**: the one-request-per-REP baseline (batch size 1) — the
-  ratio ``serve_batch_x = batched/serial`` at the median round is the
-  headline scheduling win (floor: > 1 at >= 8 clients);
 - **int8** (``--int8``, default on): the same batched server on the
   ``ops/quant``-quantized model — ``serve_int8_x = int8/batched``.
 
@@ -68,12 +65,12 @@ from blendjax.obs.histogram import LatencyHistogram  # noqa: E402
 
 def _build_models(model, *, obs_dim, d_model, n_heads, n_layers, slots,
                   length, seed, int8):
-    """(float_model, serial_model, int8_model|None) sharing weights."""
+    """(float_model, int8_model|None) sharing weights."""
     if model == "linear":
         from blendjax.serve.server import LinearModel
 
         mk = lambda: LinearModel(obs_dim=obs_dim, slots=slots, seed=seed)
-        return mk(), mk(), (mk() if int8 else None)
+        return mk(), (mk() if int8 else None)
     if model == "policy":
         import jax
 
@@ -82,7 +79,6 @@ def _build_models(model, *, obs_dim, d_model, n_heads, n_layers, slots,
 
         params = policy.init(jax.random.PRNGKey(seed), obs_dim, 8)
         return (
-            PolicyModel(params, obs_dim),
             PolicyModel(params, obs_dim),
             PolicyModel(params, obs_dim, int8=True) if int8 else None,
         )
@@ -99,7 +95,7 @@ def _build_models(model, *, obs_dim, d_model, n_heads, n_layers, slots,
             n_heads=n_heads, n_layers=n_layers, pos_encoding="rope",
         )
         mk = lambda **kw: SeqFormerModel(params, slots, length, **kw)
-        return mk(), mk(), (mk(int8=True) if int8 else None)
+        return mk(), (mk(int8=True) if int8 else None)
     raise ValueError(f"unknown model {model!r}")
 
 
@@ -338,27 +334,23 @@ def measure(seconds=12.0, clients=8, model="seqformer", *, obs_dim=8,
             d_model=64, n_heads=4, n_layers=2, slots=None, length=64,
             episode_len=32, rounds=None, int8=True, seed=0,
             tick_ms=1.0):
-    """Run the three-mode comparison; returns the serve_bench record."""
+    """Run the batched/int8 comparison; returns the serve_bench record."""
     from blendjax.serve.server import start_server_thread
     from blendjax.utils.timing import EventCounters, StageTimer
 
     slots = slots or max(2 * clients, 16)
-    f_model, s_model, q_model = _build_models(
+    f_model, q_model = _build_models(
         model, obs_dim=obs_dim, d_model=d_model, n_heads=n_heads,
         n_layers=n_layers, slots=slots, length=length, seed=seed,
         int8=int8,
     )
     rounds = rounds or 3
-    window_s = max(0.5, seconds / (rounds * (3 if int8 else 2)))
+    window_s = max(0.5, seconds / (rounds * (2 if int8 else 1)))
     timer = StageTimer()
     servers = {
         "batched": start_server_thread(
             f_model, counters=EventCounters(), timer=timer,
             tick_ms=tick_ms,
-        ),
-        "serial": start_server_thread(
-            s_model, serial=True, counters=EventCounters(),
-            timer=StageTimer(),
         ),
     }
     if int8:
@@ -396,8 +388,6 @@ def measure(seconds=12.0, clients=8, model="seqformer", *, obs_dim=8,
         for h in servers.values():
             h.close()
     med = {name: float(np.median(rates)) for name, rates in qps.items()}
-    pair_ratios = [round(b / s, 3)
-                   for b, s in zip(qps["batched"], qps["serial"]) if s]
     pct = batched_hist.percentiles()
     out = {
         "model": model,
@@ -410,10 +400,6 @@ def measure(seconds=12.0, clients=8, model="seqformer", *, obs_dim=8,
         "serve_qps": round(med["batched"], 2),
         "serve_p50_ms": pct["p50_ms"],
         "serve_p99_ms": pct["p99_ms"],
-        "serve_batch_x": (
-            round(float(np.median(pair_ratios)), 3)
-            if pair_ratios else None
-        ),
         "serve_int8_x": (
             round(med["int8"] / med["batched"], 3)
             if int8 and med.get("batched") else None
@@ -423,7 +409,6 @@ def measure(seconds=12.0, clients=8, model="seqformer", *, obs_dim=8,
         ),
         "prefill": prefill,
         "serve_qps_modes": {k: round(v, 2) for k, v in med.items()},
-        "pair_ratios": pair_ratios,
         "stages": {
             k: v for k, v in timer.summary().items()
             if k in ("queue_wait", "batch_assemble", "compute", "reply")
@@ -762,7 +747,7 @@ def measure_mix(seconds=12.0, clients=8, model="linear", *, obs_dim=8,
                 else parse_mix(mix or DEFAULT_MIX, obs_dim))
     slots = slots or max(2 * clients, 16)
     window_s = max(0.5, seconds / max(rounds, 1))
-    f_model, _, _ = _build_models(
+    f_model, _ = _build_models(
         model, obs_dim=obs_dim, d_model=64, n_heads=4, n_layers=2,
         slots=slots, length=64, seed=seed, int8=False,
     )

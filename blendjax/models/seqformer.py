@@ -65,6 +65,7 @@ from blendjax.models.moe import (
     gated_mlp_init,
     held_init,
     moe_apply_held,
+    moe_apply_topk,
 )
 from blendjax.ops.quant import maybe_quantized_einsum
 from blendjax.parallel.ring_attention import full_attention
@@ -166,6 +167,54 @@ def _held_moe(blk, h, dtype, auxs, valid=None):
             blk["moe"], h.reshape(-1, h.shape[-1]), dtype, valid=valid)
     auxs.append({"counts": counts})
     return y.reshape(h.shape)
+
+
+def _ffn(blk, x, dtype, auxs, valid, moe_impl, moe_k, moe_capacity_factor,
+         moe_dispatch):
+    """``x`` plus the block's feed-forward branch, over ``(B, T, d)``
+    positions or a decode step's ``(B, d)`` rows: the held share of a
+    routed layer (its counts over the ``valid`` rows go to ``auxs``),
+    the legacy expert entry as the ``moe_*`` arguments evaluate it (a
+    routed evaluation's aux goes to ``auxs`` too), the gated MLP or the
+    GELU MLP.  The one place the choice is made."""
+    if "moe" in blk and "route" in blk["moe"]:
+        return x + _held_moe(blk, _ln_apply(blk["ln2"], x), dtype, auxs,
+                             valid)
+    with jax.named_scope("mlp"):
+        h = _ln_apply(blk["ln2"], x)
+        if "moe" not in blk:
+            if "gate" in blk["mlp"]:
+                return x + gated_mlp(blk["mlp"], h, dtype)
+            h = gelu(_dense_mq(blk["mlp"]["fc"], h, dtype))
+            return x + _dense_mq(blk["mlp"]["proj"], h, dtype)
+        rows = h.ndim == 2
+        if rows:
+            h = h[:, None]  # the legacy layers take (B, T, d)
+        if moe_impl == "topk":
+            y, aux = moe_apply_topk(
+                blk["moe"], h, dtype, k=moe_k,
+                capacity_factor=moe_capacity_factor, dispatch=moe_dispatch)
+            auxs.append(aux)
+        elif moe_impl == "dense":
+            y = _moe_apply(blk["moe"], h, dtype)
+        else:
+            raise ValueError(f"unknown moe_impl {moe_impl!r}")
+        return x + (y[:, 0] if rows else y)
+
+
+def _drop_free(params, moe_k, capacity_factor):
+    """The capacity factor that leaves no assignment of the legacy
+    routed layer without a slot (``>= e / k``).  The capacity bound
+    exists to balance batched training dispatch and its value depends
+    on the total token count, so capacity-bounded routing is not causal
+    and can never match between incremental and full-sequence
+    evaluation: decode steps, and a prefill they must agree with, route
+    drop-free."""
+    for blk in params["blocks"]:
+        if "moe" in blk and "route" not in blk["moe"]:
+            e = blk["moe"]["w1"].shape[0]
+            return max(capacity_factor, e / min(moe_k, e))
+    return capacity_factor
 
 
 def token_model_specs(config, first=0):
@@ -386,32 +435,8 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
                 a = attn_fn(q, k, v)
                 x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd",
                                  compute_dtype)
-        if "moe" in blk and "route" in blk["moe"]:
-            x = x + _held_moe(blk, _ln_apply(blk["ln2"], x), compute_dtype,
-                              auxs)
-            continue
-        with jax.named_scope("mlp"):
-            h = _ln_apply(blk["ln2"], x)
-            if "moe" in blk:
-                if moe_impl == "topk":
-                    from blendjax.models.moe import moe_apply_topk
-
-                    y, aux = moe_apply_topk(
-                        blk["moe"], h, compute_dtype, k=moe_k,
-                        capacity_factor=moe_capacity_factor,
-                        dispatch=moe_dispatch,
-                    )
-                    auxs.append(aux)
-                    x = x + y
-                elif moe_impl == "dense":
-                    x = x + _moe_apply(blk["moe"], h, compute_dtype)
-                else:
-                    raise ValueError(f"unknown moe_impl {moe_impl!r}")
-            elif "gate" in blk["mlp"]:
-                x = x + gated_mlp(blk["mlp"], h, compute_dtype)
-            else:
-                h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
-                x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
+        x = _ffn(blk, x, compute_dtype, auxs, None, moe_impl, moe_k,
+                 moe_capacity_factor, moe_dispatch)
     if last_only:
         x = x[:, -1:]
     x = _ln_apply(params["ln_f"], x)
@@ -578,11 +603,10 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
     the batch-uniform scalar: every cache row then decodes at its OWN
     position (:func:`decode_step` dispatches on ``pos``'s rank), which
     is what a serving tier needs to run ONE batched decode over live
-    episodes at heterogeneous timesteps (``blendjax/serve``).  Resetting
-    a single episode is ``cache['pos'].at[i].set(0)`` — stale k/v rows
-    need no zeroing because :func:`_attn_one` masks by each slot's
-    absolute position, which turns negative the moment the row's
-    position rewinds.
+    episodes at heterogeneous timesteps (``blendjax/serve``).
+    :func:`prefill` admits a whole prefix into such rows and
+    :func:`rewind_rows` resets them; with :func:`decode_step` they are
+    everything that reads or writes the layout.
     """
     if length is None:
         if "pos" not in params:
@@ -766,6 +790,7 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     if latent and window is not None:
         raise ValueError("latent attention has no windowed path")
     use_rope = "pos" not in params and not latent
+    moe_capacity_factor = _drop_free(params, moe_k, moe_capacity_factor)
     auxs = []
     x = _embed(params, obs_t, compute_dtype)
     if use_rope:
@@ -844,40 +869,8 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
                 a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
                               window=window).astype(compute_dtype)
                 x = x + _proj_mq(blk["wo"], a, "bhk,hkd->bd", compute_dtype)
-        if "moe" in blk and "route" in blk["moe"]:
-            x = x + _held_moe(blk, _ln_apply(blk["ln2"], x), compute_dtype,
-                              auxs, valid)
-            continue
-        with jax.named_scope("mlp"):
-            h = _ln_apply(blk["ln2"], x)
-            if "moe" in blk:
-                h3 = h[:, None]  # the moe layers take (B, T, d)
-                if moe_impl == "topk":
-                    from blendjax.models.moe import moe_apply_topk
-
-                    # decode-time routing is DROP-FREE: the capacity bound
-                    # exists to balance batched training dispatch, and its
-                    # value depends on the total token count — so
-                    # capacity-bounded routing is not causal and can never
-                    # match between incremental and full-sequence evaluation.
-                    # cf >= e/k guarantees a slot for every assignment here.
-                    e = blk["moe"]["w1"].shape[0]
-                    y, _ = moe_apply_topk(
-                        blk["moe"], h3, compute_dtype, k=moe_k,
-                        capacity_factor=max(moe_capacity_factor,
-                                            e / min(moe_k, e)),
-                        dispatch=moe_dispatch,
-                    )
-                elif moe_impl == "dense":
-                    y = _moe_apply(blk["moe"], h3, compute_dtype)
-                else:
-                    raise ValueError(f"unknown moe_impl {moe_impl!r}")
-                x = x + y[:, 0]
-            elif "gate" in blk["mlp"]:
-                x = x + gated_mlp(blk["mlp"], h, compute_dtype)
-            else:
-                h = gelu(_dense_mq(blk["mlp"]["fc"], h, compute_dtype))
-                x = x + _dense_mq(blk["mlp"]["proj"], h, compute_dtype)
+        x = _ffn(blk, x, compute_dtype, auxs, valid, moe_impl, moe_k,
+                 moe_capacity_factor, moe_dispatch)
     x = _ln_apply(params["ln_f"], x)
     return _head(params, x, compute_dtype), new_cache, auxs
 
@@ -903,6 +896,70 @@ def _mla_step(blk, pool, sink, x, pos, rows, slots, dtype):
     return mla.attend_absorbed(blk["mla"], q_nope, q_pe, pool, pos, dtype)
 
 
+def prefill(params, cache, prefix, rows=None, *,
+            compute_dtype=jnp.bfloat16, window=None, last_only=False,
+            **moe):
+    """Admit the prefixes ``(B, T0, obs_dim)`` (``(B, T0)`` ids for a
+    token model) into ``cache`` with ONE teacher-forced pass (the
+    standard prefill/decode split) instead of T0 serial
+    :func:`decode_step`s: returns ``(predictions, cache)``, the
+    predictions ``(B, T0, ...)`` float32 (``last_only``: position T0's
+    alone, ``(B, 1, ...)``), the cache holding the bytes serial decode
+    would have written (k/v are rotated before the sink; a latent model
+    attends expanded and sinks its latent rows) with ``pos`` at T0.
+
+    ``rows=None`` fills every row of the cache (which then has ``B`` of
+    them: what :func:`rollout` does); ``rows`` ``(B,)`` names the rows of
+    a per-row cache LARGER than the batch (the serving tier's slot pool)
+    that prefix ``j`` goes to.  Every other row, and every position of
+    a named row that is not written, is the input's, so a caller that
+    donates the cache has it updated in place.  A prefix longer than the
+    ring keeps only the tail that fits, placed at each position's ring
+    slot (distinct, since at most ``C`` consecutive ones are kept).
+    ``moe`` are the legacy expert layer's ``moe_*`` arguments, as
+    :func:`apply` takes them."""
+    kvs = []
+    with jax.named_scope("forward"):
+        preds, _ = _forward(
+            params, prefix,
+            lambda q, k, v: full_attention(q, k, v, causal=True,
+                                           window=window),
+            compute_dtype, kv_sink=kvs, last_only=last_only, **moe)
+    names = ("kv",) if _latent(params) else ("k", "v")
+    t0 = prefix.shape[1]
+    ring = cache[names[0]][0].shape[1]
+    keep_n = min(t0, ring)
+    slots_ax = (jnp.arange(keep_n) + (t0 - keep_n)) % ring
+    with jax.named_scope("scatter"):
+        if rows is None:
+            new = {"pos": jnp.full_like(cache["pos"], t0)}
+        else:
+            new = {"pos": cache["pos"].at[rows].set(t0)}
+        for name in names:
+            new[name] = []
+        for i, kept in enumerate(kvs):
+            for name, t in zip(names, kept):
+                pool = cache[name][i]
+                if rows is None:
+                    pool = pool.at[:, slots_ax].set(
+                        t[:, t0 - keep_n:].astype(pool.dtype))
+                else:
+                    for j in range(t.shape[0]):
+                        pool = pool.at[rows[j], slots_ax].set(
+                            t[j, t0 - keep_n:].astype(pool.dtype))
+                new[name].append(pool)
+    return preds, new
+
+
+def rewind_rows(cache, rows):
+    """``cache`` (per-row) with ``rows`` rewound to position 0, ready for
+    their next tenants.  Rewinding ``pos`` is sufficient: :func:`_attn_one`
+    masks by each slot's absolute position, so the stale k/v (or latent)
+    rows of the previous tenant sit at negative positions and never
+    attend."""
+    return {**cache, "pos": cache["pos"].at[rows].set(0)}
+
+
 def rollout(params, prefix, n_steps, compute_dtype=jnp.bfloat16,
             moe_impl="dense", moe_k=2, moe_capacity_factor=1.25,
             moe_dispatch="sort", window=None, cache_dtype=None):
@@ -924,6 +981,12 @@ def rollout(params, prefix, n_steps, compute_dtype=jnp.bfloat16,
     (SURVEY.md §5); this completes the world-model workload the
     framework adds.
     """
+    if _latent(params):
+        raise ValueError(
+            "rollout() feeds a model its own predictions; a token model "
+            "answers with logits and nothing here samples an id from them "
+            "(ROADMAP M5): serve it (blendjax.serve) or drive prefill() and "
+            "decode_step() yourself")
     b, t0, obs_dim = prefix.shape
     if n_steps < 1:
         raise ValueError(f"n_steps must be >= 1, got {n_steps}")
@@ -937,54 +1000,27 @@ def rollout(params, prefix, n_steps, compute_dtype=jnp.bfloat16,
         )
     from jax import lax
 
-    # drop-free MoE routing on BOTH phases (see decode_step): routing
+    # drop-free MoE routing on BOTH phases (see _drop_free): routing
     # must be per-token independent for the vectorized prefill and the
     # incremental decode to agree
-    cf = moe_capacity_factor
-    for blk in params["blocks"]:
-        if "moe" in blk:
-            e = blk["moe"]["w1"].shape[0]
-            cf = max(cf, e / min(moe_k, e))
-            break
-    step_kwargs = dict(
-        compute_dtype=compute_dtype, moe_impl=moe_impl, moe_k=moe_k,
-        moe_capacity_factor=cf, moe_dispatch=moe_dispatch, window=window,
-    )
-
-    # vectorized prefill: ONE teacher-forced pass fills every layer's
-    # KV cache (the standard prefill/decode split) — not t0 serial
-    # decode steps
-    kvs = []
-    preds, _ = _forward(
-        params, prefix,
-        lambda q, k, v: full_attention(q, k, v, causal=True,
-                                       window=window),
-        compute_dtype, moe_impl, moe_k, cf, moe_dispatch, kv_sink=kvs,
-    )
-    last_pred = preds[:, -1]  # prediction for position t0
-    cache_dt = cache_dtype or compute_dtype
+    moe = dict(moe_impl=moe_impl, moe_k=moe_k, moe_dispatch=moe_dispatch,
+               moe_capacity_factor=_drop_free(params, moe_k,
+                                              moe_capacity_factor))
     total = t0 + n_steps
     # windowed: a ring buffer of `window` slots bounds memory at
     # O(window) no matter the horizon (decode_step writes at pos % C,
     # _attn_one masks by slot position)
-    length = total if window is None else min(total, window)
-    cache = init_cache(params, b, dtype=cache_dt, length=length)
-    cache["pos"] = jnp.asarray(t0, jnp.int32)
-    # keep only the prefix tail that fits the ring, placed at each
-    # position's slot (distinct since we keep <= C consecutive ones)
-    keep_n = min(t0, length)
-    slots = (jnp.arange(keep_n) + (t0 - keep_n)) % length
-    for i, (k, v) in enumerate(kvs):
-        cache["k"][i] = cache["k"][i].at[:, slots].set(
-            k[:, t0 - keep_n:].astype(cache_dt)
-        )
-        cache["v"][i] = cache["v"][i].at[:, slots].set(
-            v[:, t0 - keep_n:].astype(cache_dt)
-        )
+    cache = init_cache(params, b, dtype=cache_dtype or compute_dtype,
+                       length=total if window is None else min(total, window))
+    preds, cache = prefill(params, cache, prefix,
+                           compute_dtype=compute_dtype, window=window, **moe)
+    last_pred = preds[:, -1]  # prediction for position t0
 
     def dream(carry, _):
         cache, obs_t = carry
-        pred, cache = decode_step(params, cache, obs_t, **step_kwargs)
+        pred, cache = decode_step(params, cache, obs_t,
+                                  compute_dtype=compute_dtype, window=window,
+                                  **moe)
         return (cache, pred), obs_t
 
     (_, final), dreamed = lax.scan(

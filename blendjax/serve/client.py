@@ -53,8 +53,7 @@ class ServeRPCError(TimeoutError):
 
 class ServeClient:
     """Blocking exactly-once RPCs to one :class:`~blendjax.serve.server.
-    PolicyServer` (ROUTER/batched or REP/serial — the DEALER framing
-    serves both unmodified)."""
+    PolicyServer` (DEALER framing against its ROUTER socket)."""
 
     def __init__(self, address, *, fault_policy=None, counters=None,
                  timeoutms=5000, context=None, span_recorder=None,

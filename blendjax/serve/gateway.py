@@ -964,7 +964,7 @@ class ServeGateway:
         out = {}
         if caps is not None:
             # a representative replica's PR-10 capability fields
-            # (obs_dim, slots, max_batch, buckets, int8, serial, model)
+            # (obs_dim, slots, max_batch, buckets, int8, model)
             # so hello consumers written against a bare server work
             # unchanged pointed at a gateway
             out.update(caps)
@@ -1476,9 +1476,9 @@ class ServeGateway:
             # the gateway's own fields on THIS reply
             rep.caps = {
                 k: reply[k]
-                for k in ("model", "obs_dim", "slots", "serial", "int8",
-                          "max_batch", "buckets", "platform",
-                          "device_kind", "device_count")
+                for k in ("model", "obs_dim", "slots", "int8", "max_batch",
+                          "buckets", "platform", "device_kind",
+                          "device_count")
                 if k in reply
             }
             reply.update(self._cmd_hello({}))

@@ -48,10 +48,7 @@ and fast-starting, so chaos tests SIGKILL/respawn it cheaply)::
 or in-process via :func:`start_server_thread`, or supervised via
 :class:`ServerProcess` (a launcher-compatible surface, so
 :class:`~blendjax.btt.watchdog.FleetWatchdog` respawns a dead server
-and clients resume after ``reset()``).  The **serial** mode
-(``serial=True``: a REP socket answering one request per exchange,
-batch size 1) is the baseline the benchmark's ``serve_batch_x``
-compares continuous batching against.
+and clients resume after ``reset()``).
 
 See docs/serving.md.
 """
@@ -367,7 +364,6 @@ class SeqFormerModel:
         self._cache_dtype = cache_dtype or cdt
         self._jnp = jnp
         self._cache = self._new_pool()
-        self._pool_names = [n for n in self._cache if n != "pos"]
         pad = self.pad_slot
 
         def reply_row(pred):
@@ -408,44 +404,17 @@ class SeqFormerModel:
         self._step = jax.jit(serve_step, donate_argnums=(1,))
 
         def serve_prefill(params, cache, row, prefix):
-            # ONE teacher-forced pass fills the slot's cache rows (the
-            # standard prefill/decode split, exactly rollout()'s
-            # prefill phase) instead of T serial decode_steps.  k/v
-            # are rotated before the sink, so the cache holds the same
-            # bytes serial decode would have written; positions past
-            # the ring keep only the tail that fits, placed at each
-            # position's ring slot.  The model evaluates its own expert
-            # layer; a latent model attends expanded here and sinks its
-            # latent rows.
-            from blendjax.parallel.ring_attention import full_attention
-
-            kvs = []
-            with jax.named_scope("forward"):
-                preds, _ = seqformer._forward(
-                    params, prefix[:, 0][None] if self.tokens
-                    else prefix[None],
-                    lambda q, k, v: full_attention(
-                        q, k, v, causal=True, window=window
-                    ),
-                    cdt, kv_sink=kvs, last_only=self.tokens,
-                )
-            t0 = prefix.shape[0]
-            ring = cache[self._pool_names[0]][0].shape[1]
-            keep_n = min(t0, ring)
-            slots_ax = (jnp.arange(keep_n) + (t0 - keep_n)) % ring
-            # keep_n positions of one row change; the pool is donated,
-            # so these writes are in place
-            with jax.named_scope("scatter"):
-                new = {"pos": cache["pos"].at[row].set(t0)}
-                for name in self._pool_names:
-                    new[name] = []
-                for i, kept in enumerate(kvs):
-                    for name, t in zip(self._pool_names, kept):
-                        new[name].append(
-                            cache[name][i].at[row[0], slots_ax].set(
-                                t[0, t0 - keep_n:].astype(cache[name][i].dtype)
-                            ))
-            return reply_row(preds[0, -1]), new
+            # ONE teacher-forced pass fills the slot's cache rows
+            # (seqformer.prefill, rollout()'s prefill phase too) instead
+            # of T serial decode_steps; its `forward` and `scatter`
+            # scopes are what a trace shows.  The pool is donated, so
+            # the kept positions of the one row are written in place.
+            preds, cache = seqformer.prefill(
+                params, cache,
+                prefix[:, 0][None] if self.tokens else prefix[None], row,
+                compute_dtype=cdt, window=window, last_only=self.tokens,
+            )
+            return reply_row(preds[0, -1]), cache
 
         # one compilation per prefix LENGTH (prefix rows are real
         # observations — padding them would write fabricated positions
@@ -556,13 +525,11 @@ class SeqFormerModel:
         return events
 
     def reset_rows(self, idx):
-        # rewinding pos to 0 is sufficient: _attn_one masks by each
-        # slot's absolute position, so the stale k/v rows of the slot's
-        # previous tenant sit at negative positions and never attend
+        from blendjax.models import seqformer
+
         with span("serve.reset_rows"):
-            self._cache["pos"] = self._cache["pos"].at[
-                self._jnp.asarray(idx)
-            ].set(0)
+            self._cache = seqformer.rewind_rows(
+                self._cache, self._jnp.asarray(idx))
 
     def step_rows(self, idx, obs):
         if np.shape(obs) != (len(idx), self.obs_dim):
@@ -615,9 +582,7 @@ class _ModelState:
 
 
 class PolicyServer:
-    """One served model behind a ROUTER socket (continuous batching) or
-    a REP socket (``serial=True`` — the one-request-per-exchange
-    baseline ``serve_batch_x`` is measured against).
+    """One served model behind a ROUTER socket (continuous batching).
 
     Params
     ------
@@ -637,8 +602,6 @@ class PolicyServer:
         ``model`` key go to the first/default model, so a single-model
         workload against a multi-model server is byte-identical to a
         single-model server — test-locked).
-    serial: bool
-        REP socket, batch size 1, no queue — the serial baseline.
     tick_ms: float
         Admission window once the queue is non-empty: how long one tick
         waits for more arrivals before computing (latency it trades for
@@ -662,7 +625,7 @@ class PolicyServer:
         ``weight_version`` once a snapshot has been adopted.
     """
 
-    def __init__(self, address, model, *, serial=False, tick_ms=2.0,
+    def __init__(self, address, model, *, tick_ms=2.0,
                  max_batch=64, buckets=None, slot_ttl_s=None,
                  reply_cache_depth=REPLY_CACHE_DEPTH, counters=None,
                  timer=None, context=None, shm_base=None,
@@ -691,7 +654,6 @@ class PolicyServer:
             from blendjax.utils.device import device_info
 
             self._device = device_info()
-        self.serial = bool(serial)
         self.tick_ms = float(tick_ms)
         self.buckets = tuple(sorted(
             int(b) for b in (buckets or default_buckets(int(max_batch)))
@@ -726,8 +688,7 @@ class PolicyServer:
         # models can ever hand out the same lease id.
         self._episode_seq = 0
         self._ctx = context or zmq.Context.instance()
-        self._sock = self._ctx.socket(zmq.REP if self.serial
-                                      else zmq.ROUTER)
+        self._sock = self._ctx.socket(zmq.ROUTER)
         self._sock.setsockopt(zmq.LINGER, 0)
         if address.endswith(":*") or address.endswith(":0"):
             base = address.rsplit(":", 1)[0]
@@ -860,7 +821,6 @@ class PolicyServer:
             "obs_dim": st.model.obs_dim,
             "slots": st.model.slots,
             "free_slots": len(st.free),
-            "serial": self.serial,
             "int8": bool(getattr(st.model, "int8", False)),
             "max_batch": self.max_batch,
             "buckets": list(self.buckets),
@@ -984,7 +944,6 @@ class PolicyServer:
             ),
             "free_slots": len(st.free),
             "queued": len(self._queue),
-            "serial": self.serial,
             "models": list(self._models),
             "weight_version": self.weight_version,
             "per_model": {
@@ -1026,7 +985,6 @@ class PolicyServer:
                 "model": st.model.kind,
                 "obs_dim": st.model.obs_dim,
                 "slots": st.model.slots,
-                "serial": self.serial,
                 "int8": bool(getattr(st.model, "int8", False)),
                 "max_batch": self.max_batch,
                 "buckets": list(self.buckets),
@@ -1184,12 +1142,8 @@ class PolicyServer:
                 self.counters.incr("serve_replies")
             return
         try:
-            if self.serial:
-                sent = wire.send_message(self._sock, reply,
-                                         raw_buffers=True)
-            else:
-                sent = wire.send_message_router(self._sock, ident, reply,
-                                                raw_buffers=True)
+            sent = wire.send_message_router(self._sock, ident, reply,
+                                            raw_buffers=True)
             self.counters.incr("serve_wire_bytes", sent)
             self.counters.incr("serve_replies")
         except zmq.ZMQError:
@@ -1434,11 +1388,6 @@ class PolicyServer:
             self._shm.send(chan, reply)
             return
         self._admit(chan, msg)
-        if self.serial:
-            # serial semantics are per-REQUEST (the batching baseline):
-            # tick immediately so co-pumped shm requests never batch
-            while self._queue:
-                self._tick()
 
     def _drain_shm(self):
         """Admit every request pending on the shm channels (the channel
@@ -1459,17 +1408,13 @@ class PolicyServer:
         recovers.  ``serve_idle_us`` is the one record of it."""
         t0 = time.perf_counter()
         with span("serve.idle"):
-            events = self._poller.poll(poll_ms)
+            self._poller.poll(poll_ms)
         self.counters.incr("serve_idle_us",
                            int((time.perf_counter() - t0) * 1e6))
-        return events
 
     def serve_forever(self, stop_event=None, poll_ms=50):
         import zmq
 
-        if self.serial:
-            self._serve_serial(stop_event, poll_ms)
-            return
         while stop_event is None or not stop_event.is_set():
             try:
                 # between ticks: the hot-swap point (no batch in
@@ -1504,51 +1449,6 @@ class PolicyServer:
                 # parked behind another admission window
                 while self._tick():
                     pass
-
-    def _serve_serial(self, stop_event, poll_ms):
-        """The REP baseline: one request, one (batch-1) reply.  shm
-        channels are served from the same loop (their replies ride
-        their own rings, so the REP alternation only governs the ZMQ
-        socket)."""
-        import zmq
-
-        while stop_event is None or not stop_event.is_set():
-            try:
-                events = dict(self._idle_poll(poll_ms))
-                self._poll_weights()  # between (batch-1) ticks
-                self._drain_shm()  # ticks per message (serial handler)
-                if self._sock not in events:
-                    continue
-                try:
-                    msg, nbytes = wire.recv_message_sized(self._sock)
-                    self.counters.incr("serve_wire_bytes", nbytes)
-                except zmq.ZMQError:
-                    return
-                except Exception as exc:  # noqa: BLE001 - see _drain
-                    # REP alternation: the garbled request was consumed,
-                    # so a reply is owed before the next recv (_send
-                    # keeps the serve_replies count honest)
-                    self.counters.incr("serve_errors")
-                    logger.warning(
-                        "policy server: undecodable request (%s: %s)",
-                        type(exc).__name__, exc,
-                    )
-                    self._send(None, {
-                        "error": "undecodable request (corrupt frames)"
-                    })
-                    continue
-            except zmq.ZMQError:
-                return
-            reply = shm_rpc.control_reply(self._shm, msg)
-            if reply is not None:
-                try:
-                    wire.send_message(self._sock, reply)
-                except zmq.ZMQError:
-                    return
-                continue
-            self._admit(None, msg)
-            while self._queue:
-                self._tick()
 
     def close(self):
         try:
@@ -1596,13 +1496,11 @@ class _LocalServerHandle:
 
 
 def start_server_thread(model, *, address="tcp://127.0.0.1:*",
-                        serial=False, counters=None, timer=None,
-                        **kwargs):
+                        counters=None, timer=None, **kwargs):
     """Serve a :class:`PolicyServer` from a daemon thread; returns a
     handle with ``.address``, ``.server`` and ``.close()``."""
     server = PolicyServer(
-        address, model, serial=serial, counters=counters, timer=timer,
-        **kwargs,
+        address, model, counters=counters, timer=timer, **kwargs,
     )
     stop = threading.Event()
     thread = threading.Thread(
@@ -1634,7 +1532,7 @@ class ServerProcess:
 
     def __init__(self, *, model="linear", address=None, seed=0,
                  obs_dim=8, slots=16, length=64, window=None,
-                 num_actions=4, int8=False, serial=False, tick_ms=2.0,
+                 num_actions=4, int8=False, tick_ms=2.0,
                  max_batch=64, work_us=0, subscribe=None, python=None,
                  ready_timeout=60.0, extra_args=()):
         from blendjax.replay.shard_client import free_port
@@ -1669,8 +1567,6 @@ class ServerProcess:
             self._cmd += ["--window", str(window)]
         if int8:
             self._cmd.append("--int8")
-        if serial:
-            self._cmd.append("--serial")
         self._cmd += list(extra_args)
         self.launch_info = None
 
@@ -1940,7 +1836,6 @@ def main(argv=None):
     ap.add_argument("--n-heads", type=int, default=4)
     ap.add_argument("--n-layers", type=int, default=2)
     ap.add_argument("--int8", action="store_true")
-    ap.add_argument("--serial", action="store_true")
     ap.add_argument("--tick-ms", type=float, default=2.0)
     ap.add_argument("--max-batch", type=int, default=64)
     ap.add_argument("--work-us", type=float, default=0,
@@ -1982,8 +1877,7 @@ def main(argv=None):
 
         subscriber = WeightSubscriber(args.subscribe)
     server = PolicyServer(
-        args.address, model, serial=args.serial,
-        tick_ms=args.tick_ms, max_batch=args.max_batch,
+        args.address, model, tick_ms=args.tick_ms, max_batch=args.max_batch,
         shm_base=args.shm_base, subscriber=subscriber,
     )
     stop = threading.Event()

@@ -57,7 +57,6 @@ DEFAULT_FLOORS = {
     "rl_sharded_x": 0.80,
     "telemetry_overhead_x": 0.95,   # itself a ratio; must stay ~free
     "serve_qps": 0.80,              # serving tier headline (docs/serving.md)
-    "serve_batch_x": 0.80,
     "serve_int8_x": 0.80,
     "serve_prefill_x": 0.80,        # batched prefill admission vs serial
     "gateway_qps": 0.80,            # serve-fleet aggregate through the gateway
@@ -163,8 +162,8 @@ def _flatten(doc, metrics):
                     metrics[k] = float(shard[k])
     sb = doc.get("serve_bench")
     if isinstance(sb, dict):
-        for k in ("serve_qps", "serve_p99_ms", "serve_batch_x",
-                  "serve_int8_x", "serve_prefill_x"):
+        for k in ("serve_qps", "serve_p99_ms", "serve_int8_x",
+                  "serve_prefill_x"):
             if isinstance(sb.get(k), (int, float)) \
                     and not isinstance(sb.get(k), bool):
                 metrics[k] = float(sb[k])

@@ -79,7 +79,11 @@ class TestDpEquivalence:
         b2 = put_batch(_env_major(batch_tm), data_sharding(mesh))
         s1, l1 = al_single._step(al_single.state, b1)
         s2, l2 = al_shard._step(al_shard.state, b2)
-        np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+        # the mean over the batch is summed in another order across the
+        # 8 shards: in float32 that moves the loss by a few 1e-5 relative
+        # (2.6e-5 seen, ROADMAP D2), so it gets the parameters' tolerance
+        np.testing.assert_allclose(float(l1), float(l2), rtol=1e-4,
+                                   atol=1e-6)
         for p1, p2 in zip(jax.tree.leaves(s1.params),
                           jax.tree.leaves(s2.params)):
             np.testing.assert_allclose(

@@ -143,8 +143,7 @@ def test_telemetry_overhead_floor_is_tight(tmp_path):
 
 SERVE_HEADLINE = {
     "headline": True, "metric": "x_images_per_sec", "value": 100.0,
-    "serve_qps": 2650.0, "serve_p99_ms": 6.4, "serve_batch_x": 3.1,
-    "serve_int8_x": 0.98,
+    "serve_qps": 2650.0, "serve_p99_ms": 6.4, "serve_int8_x": 0.98,
 }
 
 
@@ -156,12 +155,12 @@ def test_serve_metrics_extract_from_headline_and_nest(tmp_path):
     full = {
         "metric": "m", "value": 80.0,
         "serve_bench": {"serve_qps": 2600.0, "serve_p99_ms": 7.0,
-                        "serve_batch_x": 3.0, "serve_int8_x": 1.0},
+                        "serve_int8_x": 1.0},
     }
     m = bench_compare.extract_metrics(
         _write(tmp_path, "f.json", json.dumps(full))
     )
-    assert m["serve_batch_x"] == 3.0 and m["serve_p99_ms"] == 7.0
+    assert m["serve_int8_x"] == 1.0 and m["serve_p99_ms"] == 7.0
 
 
 def test_lower_is_better_ceiling_for_p99(tmp_path):

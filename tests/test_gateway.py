@@ -898,7 +898,7 @@ def test_bench_headline_carries_gateway_metrics():
     sb = {
         "phase": "serve_bench", "model": "seqformer", "clients": 8,
         "serve_qps": 2650.0, "serve_p50_ms": 2.4, "serve_p99_ms": 6.4,
-        "serve_batch_x": 3.1, "serve_int8_x": 0.98,
+        "serve_int8_x": 0.98,
         "serve_prefill_x": 14.9,
         "serve_qps_modes": {}, "stages": {},
     }

@@ -419,6 +419,18 @@ def _await_stats(lp, cond, timeout, what):
         time.sleep(0.1)
 
 
+def _committed(ckpt_dir):
+    """The newest complete manifest's update (0: none yet).  The stats
+    file's ``last_ckpt_update`` is the cadence cursor: it moves at the
+    cut, BEFORE the background serialization commits the manifest, so
+    a kill between the two resumes from the manifest before it."""
+    import glob
+
+    paths = sorted(glob.glob(os.path.join(ckpt_dir, "manifest_*.json")))
+    return int(os.path.basename(paths[-1])[len("manifest_"):-5]) \
+        if paths else 0
+
+
 @pytest.mark.chaos
 def test_supervised_learner_kill_respawn_resume(fake_blender, tmp_path):
     """The tier-1 failover drill: SIGKILL the supervised learner
@@ -448,9 +460,10 @@ def test_supervised_learner_kill_respawn_resume(fake_blender, tmp_path):
                 pre = _await_stats(
                     lp,
                     lambda s: s.get("updates", 0) >= 3
-                    and s.get("last_ckpt_update", 0) >= 2,
+                    and _committed(str(tmp_path / "ck")) >= 2,
                     90, "warmup + first checkpoint",
                 )
+                committed = _committed(str(tmp_path / "ck"))
                 os.kill(lp.launch_info.processes[0].pid,
                         signal.SIGKILL)
                 assert sup.await_deaths(1, 30)
@@ -461,10 +474,10 @@ def test_supervised_learner_kill_respawn_resume(fake_blender, tmp_path):
                     and s.get("updates", 0) > pre["updates"],
                     120, "post-respawn progress",
                 )
-    # resumed from a real cut (>= the one we read before the kill —
-    # the learner may have committed another between the read and the
-    # SIGKILL), never from zero
-    assert post["resumed_from"] >= pre["last_ckpt_update"] >= 2
+    # resumed from a real cut (>= the manifest that was complete before
+    # the kill — the learner may have committed another between the read
+    # and the SIGKILL), never from zero
+    assert post["resumed_from"] >= committed >= 2
     assert post["updates"] > pre["updates"]
     assert counters.get("ha_learner_deaths") == 1
     assert counters.get("ha_learner_respawns") == 1

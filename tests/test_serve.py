@@ -236,6 +236,94 @@ def test_prefill_admission_matches_serial_decode(kwargs, window):
         np.testing.assert_allclose(got, want[t], atol=1e-5, rtol=1e-5)
 
 
+def _prefill_case(kind):
+    """(params, prefixes (2, T0, ...), ring length, window)."""
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+
+    rng = np.random.default_rng(4)
+    if kind == "latent":
+        config = dict(
+            hidden_size=32, num_attention_heads=4, kv_lora_rank=16,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+            rope_theta=10000, rope_scaling=None, num_hidden_layers=2,
+            first_k_dense_replace=1, intermediate_size=64,
+            moe_intermediate_size=16, num_experts=8, num_experts_per_tok=2,
+            routed_scaling_factor=2.5, num_shared_experts=1, vocab_size=64)
+        params = seqformer.init_token_model(
+            jax.random.PRNGKey(0), config, dtype=jnp.float32)
+        return params, rng.integers(0, 64, (2, 7)).astype(np.int32), 16, None
+    params = seqformer.init(
+        jax.random.PRNGKey(0), obs_dim=5, d_model=32, n_heads=4,
+        n_layers=2, max_len=32)
+    prefix = rng.standard_normal((2, 7, 5)).astype(np.float32)
+    # windowed-wrap: 7 positions through a ring of 4 keep 3..6 at slots
+    # 3, 0, 1, 2
+    return (params, prefix) + ((4, 4) if kind == "windowed-wrap"
+                               else (16, None))
+
+
+@pytest.mark.parametrize("kind", ["plain", "windowed-wrap", "latent"])
+def test_prefill_writes_a_scalar_cache_and_pool_rows_alike(kind):
+    """``seqformer.prefill`` is the one writer of a prefix: into a
+    scalar-position cache (what ``rollout`` does) and into chosen rows
+    of a per-row pool (what the server does) it leaves the same bytes
+    and the same last prediction, what T0 serial ``decode_step``s leave
+    (to rounding), and every row not named is bit-identical."""
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+
+    params, prefix, length, window = _prefill_case(kind)
+    t0 = prefix.shape[1]
+    names = ["kv"] if kind == "latent" else ["k", "v"]
+
+    def marked(cache):  # so that an unwritten position shows
+        return {n: (v if n == "pos" else [jnp.full_like(a, 7.0) for a in v])
+                for n, v in cache.items()}
+
+    fill = functools.partial(seqformer.prefill, compute_dtype=jnp.float32,
+                             window=window)
+    scalar = marked(seqformer.init_cache(
+        params, 2, dtype=jnp.float32, length=length))
+    preds_a, a = fill(params, scalar, prefix)
+    pool = marked(seqformer.init_cache(
+        params, 5, dtype=jnp.float32, length=length, per_row=True))
+    pool["pos"] = jnp.arange(5, dtype=jnp.int32) + 20
+    rows = np.asarray([3, 1])
+    preds_b, b = fill(params, pool, prefix, jnp.asarray(rows))
+    others = np.asarray([0, 2, 4])
+
+    np.testing.assert_array_equal(preds_a[:, -1], preds_b[:, -1])
+    assert a["pos"].shape == () and int(a["pos"]) == t0
+    np.testing.assert_array_equal(b["pos"], [20, t0, 22, t0, 24])
+    kept = (np.arange(min(t0, length)) + t0 - min(t0, length)) % length
+    for name in names:
+        for layer_a, layer_b, before in zip(a[name], b[name], pool[name]):
+            np.testing.assert_array_equal(np.asarray(layer_b)[rows], layer_a)
+            np.testing.assert_array_equal(np.asarray(layer_b)[others],
+                                          np.asarray(before)[others])
+            assert not np.any(np.asarray(layer_a)[:, kept] == 7.0)
+            unwritten = np.setdiff1d(np.arange(length), kept)
+            assert np.all(np.asarray(layer_a)[:, unwritten] == 7.0)
+
+    # T0 serial steps through a scalar cache of the same ring
+    serial = seqformer.init_cache(params, 2, dtype=jnp.float32, length=length)
+    step = jax.jit(functools.partial(
+        seqformer.decode_step, compute_dtype=jnp.float32, window=window))
+    for t in range(t0):
+        pred, serial = step(params, serial, jnp.asarray(prefix[:, t]))
+    np.testing.assert_allclose(preds_a[:, -1], pred, atol=2e-5, rtol=1e-5)
+    for name in names:
+        for layer_a, layer_s in zip(a[name], serial[name]):
+            np.testing.assert_allclose(np.asarray(layer_a)[:, kept],
+                                       np.asarray(layer_s)[:, kept],
+                                       atol=2e-5, rtol=1e-5)
+
+
 def test_prefill_reset_end_to_end_and_validation():
     """The wire path: ``reset(prefix=...)`` admits mid-sequence (pred/
     pos in the reply, ``serve_prefills`` counted), and malformed or
@@ -904,6 +992,17 @@ def test_trace_spans_ride_the_correlation_id():
     assert span_trace(srv[0]) == span_trace(cli[0]) is not None
 
 
+def test_the_cli_refuses_the_serial_flag(capsys):
+    """The REP one-request-per-exchange mode is gone: its flag is an
+    error, not a silently batched server."""
+    from blendjax.serve import server
+
+    with pytest.raises(SystemExit) as exc:
+        server.main(["--address", "tcp://127.0.0.1:*", "--serial"])
+    assert exc.value.code == 2
+    assert "--serial" in capsys.readouterr().err
+
+
 # ---------------------------------------------------------------------------
 # bench schema lock (satellite)
 # ---------------------------------------------------------------------------
@@ -915,9 +1014,8 @@ def test_bench_headline_carries_serve_metrics():
     sb = {
         "phase": "serve_bench", "model": "seqformer", "clients": 8,
         "serve_qps": 2650.0, "serve_p50_ms": 2.4, "serve_p99_ms": 6.4,
-        "serve_batch_x": 3.1, "serve_int8_x": 0.98,
-        "serve_qps_modes": {"batched": 2650.0, "serial": 850.0,
-                            "int8": 2600.0},
+        "serve_int8_x": 0.98,
+        "serve_qps_modes": {"batched": 2650.0, "int8": 2600.0},
         "stages": {},
     }
     out = bench.assemble({"host_stream": {"items_per_sec": 1.0}},
@@ -926,7 +1024,7 @@ def test_bench_headline_carries_serve_metrics():
     line = bench.headline(out)
     assert line["serve_qps"] == 2650.0
     assert line["serve_p99_ms"] == 6.4
-    assert line["serve_batch_x"] == 3.1
+    assert line["serve_int8_x"] == 0.98
     assert len(json.dumps(line)) + 1 <= bench.HEADLINE_BYTE_BUDGET
 
 
@@ -940,6 +1038,5 @@ def test_serve_bench_emits_locked_schema():
     ]
     assert rec["serve_qps"] > 0
     assert rec["serve_p99_ms"] >= rec["serve_p50_ms"]
-    assert rec["serve_batch_x"] is not None
     for stage in SERVE_STAGES:
         assert stage in rec["stages"], stage

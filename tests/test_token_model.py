@@ -110,6 +110,15 @@ def test_prefill_then_steps_through_the_pool_equal_the_full_forward(bucket):
     assert m.drain_events() == {}
 
 
+def test_rollout_refuses_a_token_model():
+    """``rollout`` feeds predictions back as observations; logits are
+    not ids, and it says so up front (it used to die on ``cache['k']``)."""
+    _, _, served = make()
+    with pytest.raises(ValueError, match="token model"):
+        seqformer.rollout(served, ids_for(3, 6)[None, :, None], 2,
+                          compute_dtype=jnp.float32)
+
+
 def test_the_pool_is_one_latent_row_a_position():
     _, _, served = make()
     cache = seqformer.init_cache(served, 3, jnp.float32, length=8,
