@@ -11,7 +11,10 @@ of concurrent episodes from it:
   ``step`` requests are padded to a **bucketed** batch size (XLA
   compiles once per bucket, not once per occupancy), ONE jitted model
   call serves the tick, and replies scatter back per client over the
-  ROUTER socket;
+  ROUTER socket.  A tick is launched (assembled, dispatched) and
+  retired (fetched, answered) as two halves, and one launched tick is
+  kept in flight: admission, the next launch and the older tick's
+  replies run beside the device, not between its ticks;
 - **KV-cache slot pool** for stateful world-model serving: every live
   episode holds a row in batched ``(S, ...)`` cache arrays, a slot
   allocator handles admission/eviction on episode end, and
@@ -63,7 +66,7 @@ import subprocess
 import sys
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import OrderedDict, deque, namedtuple
 
 import numpy as np
 
@@ -305,6 +308,47 @@ class SlotPoolLost(RuntimeError):
     server drops them, so their clients ``reset()`` and resume)."""
 
 
+class _Reply:
+    """What :meth:`SeqFormerModel.step_rows` returns: the reply rows of
+    a call that has been dispatched and not waited for.  ``np.asarray``
+    / ``np.array`` of it (or an index into it) is the fetch: it waits
+    for the device, banks a routed model's counts and hands out the
+    rows; :meth:`is_ready` says whether that would wait.  Fetched once,
+    it keeps the rows."""
+
+    __slots__ = ("_model", "_what", "_out", "_rebuilds", "_rows")
+
+    def __init__(self, model, what, out):
+        self._model, self._what, self._out = model, what, out
+        self._rebuilds = model.pool_rebuilds
+        self._rows = None
+
+    def is_ready(self):
+        import jax
+
+        return self._rows is not None or all(
+            leaf.is_ready() for leaf in jax.tree.leaves(self._out))
+
+    def __array__(self, dtype=None, copy=None):
+        if self._rows is None:
+            self._rows = self._model._fetch(self._what, self._out,
+                                            self._rebuilds)
+            self._out = None
+        rows = self._rows if dtype is None else self._rows.astype(
+            dtype, copy=False)
+        return rows.copy() if copy else rows
+
+    def __getitem__(self, key):
+        return np.asarray(self)[key]
+
+
+def _reply_ready(reply):
+    """Whether a model's ``step_rows`` reply can be fetched without
+    waiting: rows a model computed on the host (an array) always can."""
+    ready = getattr(reply, "is_ready", None)
+    return ready is None or ready()
+
+
 class SeqFormerModel:
     """Stateful world-model serving: a slot pool of batched KV caches
     (``init_cache(per_row=True)``) over ``slots + 1`` rows — the extra
@@ -316,10 +360,21 @@ class SeqFormerModel:
     prefix length.  Nothing else may hold the pool's arrays: a donated
     call deletes them.
 
+    **What is in flight when.**  ``step_rows`` dispatches and returns a
+    :class:`_Reply` without waiting: the pool is rebound from the
+    call's result at once, so a second ``step_rows``, a ``reset_rows``
+    or a ``prefill_rows`` made before the reply is fetched runs behind
+    it on the device, in the order the calls were made, and the caller
+    decides when to wait (``np.asarray(reply)``; ``reply.is_ready()``).
+    ``prefill_rows`` waits for its own reply (and so for every step
+    dispatched before it).
+
     Everything that can refuse a call (shapes, lengths, a first
     compilation) fails BEFORE the pool is donated and leaves it as it
-    was.  A call that fails after that raises :class:`SlotPoolLost`
-    over a rebuilt, empty pool (``pool_rebuilds`` counts them).
+    was.  A call that fails after that, at its dispatch or at its
+    fetch, raises :class:`SlotPoolLost` over a rebuilt, empty pool
+    (``pool_rebuilds`` counts them; one lost pool is one rebuild,
+    however many replies were still to be fetched from it).
 
     ``int8=True`` serves :func:`~blendjax.ops.quant.quantize_seqformer`
     output — ``decode_step`` already dispatches per weight dict, so the
@@ -430,11 +485,20 @@ class SeqFormerModel:
         )
 
     def _in_place(self, fn, what, idx, arr):
-        """Run ``fn`` (``self._step`` / ``self._prefill``) over the
-        donated pool and rebind it from the result.  A failure that
-        finds the buffers it was given deleted (the call took them:
-        one that surfaces at the fence always does) costs the pool: it
-        is rebuilt empty and :class:`SlotPoolLost` raised."""
+        """Dispatch ``fn`` (``self._step`` / ``self._prefill``) over the
+        donated pool, rebind the pool from the result and start the
+        reply's copy to the host.  Nothing here waits for the device:
+        the returned :class:`_Reply` fetches when it is asked to.  The
+        pool chains through the calls' own data dependencies, so
+        whatever is dispatched behind this call (a second step, a
+        rewind, a prefill) runs after it on the device.
+
+        A dispatch that fails and finds the buffers it was given
+        deleted (the call took them) costs the pool: it is rebuilt
+        empty and :class:`SlotPoolLost` raised; so does a fetch that
+        fails (:meth:`_fetch`)."""
+        import jax
+
         pool = self._cache
         try:
             with span(f"serve.{what}.dispatch"):
@@ -442,29 +506,44 @@ class SeqFormerModel:
                     self.params, pool, self._jnp.asarray(idx),
                     self._jnp.asarray(arr),
                 )
-            with span(f"serve.{what}.fence"):
-                # fence: compute timing stays honest
-                if not isinstance(out, tuple):
-                    return np.asarray(out)
-                # a routed model's step: the counts come over with the
-                # reply, one fence for both
-                import jax
-
-                pred, counts = jax.device_get(out)
-                for name, n in zip(MOE_EVENTS, counts):
-                    self._events[name] = self._events.get(name, 0) + int(n)
-                return pred
+                for leaf in jax.tree.leaves(out):
+                    leaf.copy_to_host_async()
         except Exception as exc:
-            import jax
-
             if not any(leaf.is_deleted() for leaf in jax.tree.leaves(pool)):
                 raise  # refused before donation: the pool is intact
-            self._cache = self._new_pool()
-            self.pool_rebuilds += 1
-            raise SlotPoolLost(
-                f"the {what} failed after the slot pool was donated "
-                f"({type(exc).__name__}: {exc}); pool rebuilt empty"
-            ) from exc
+            self._write_off(what, exc)
+        return _Reply(self, what, out)
+
+    def _fetch(self, what, out, rebuilds):
+        """Wait for a dispatched call's reply and bring it to the host
+        (a routed model's counts come over with it and are banked for
+        :meth:`drain_events`).  A failure that surfaces here finds the
+        pool already handed on to whatever was dispatched behind the
+        call, so the pool is lost whatever holds it now: once per pool
+        (``rebuilds`` is ``pool_rebuilds`` as the call was dispatched),
+        a later reply off the same lost pool re-raises as it failed."""
+        import jax
+
+        try:
+            with span(f"serve.{what}.fence"):
+                if not isinstance(out, tuple):
+                    return np.asarray(out)
+                pred, counts = jax.device_get(out)
+        except Exception as exc:
+            if self.pool_rebuilds != rebuilds:
+                raise  # that pool was written off already
+            self._write_off(what, exc)
+        for name, n in zip(MOE_EVENTS, counts):
+            self._events[name] = self._events.get(name, 0) + int(n)
+        return pred
+
+    def _write_off(self, what, exc):
+        self._cache = self._new_pool()
+        self.pool_rebuilds += 1
+        raise SlotPoolLost(
+            f"the {what} failed after the slot pool was donated "
+            f"({type(exc).__name__}: {exc}); pool rebuilt empty"
+        ) from exc
 
     def prefill_rows(self, idx, prefix):
         """Admit a T-step observation prefix into slot ``idx`` with one
@@ -494,7 +573,9 @@ class SeqFormerModel:
                 f"table ({self.params['pos'].shape[0]}); use "
                 "pos_encoding='rope' for longer prefixes"
             )
-        return self._in_place(self._prefill, "prefill", idx, prefix)
+        # fenced where it is made: the reply is the ``reset``'s answer
+        return np.asarray(self._in_place(self._prefill, "prefill", idx,
+                                         prefix))
 
     def apply_weights(self, tree):
         """WeightBus hot-swap: adopt a published seqformer pytree (the
@@ -532,6 +613,8 @@ class SeqFormerModel:
                 self._cache, self._jnp.asarray(idx))
 
     def step_rows(self, idx, obs):
+        """Dispatch one decode step of rows ``idx``; the :class:`_Reply`
+        returned is fetched by whoever needs the rows."""
         if np.shape(obs) != (len(idx), self.obs_dim):
             raise ValueError(
                 f"obs shape {np.shape(obs)} != ({len(idx)}, {self.obs_dim})"
@@ -556,6 +639,15 @@ class _Pending:
         self.span_trace = span_trace
         self.t0_us = t0_us
         self.mstate = mstate
+
+
+#: One tick between its two halves: assembled and dispatched by
+#: ``PolicyServer._launch``, not yet fetched and answered by ``_retire``.
+#: ``batch`` holds ``(entry, slot, obs)`` per real row, ``reply`` what
+#: the model's ``step_rows`` returned, ``compute_s`` the host's time
+#: inside the model call so far (its dispatch).
+_Launched = namedtuple(
+    "_Launched", "state batch reply bucket pos_before compute_s")
 
 
 class _ModelState:
@@ -584,6 +676,20 @@ class _ModelState:
 class PolicyServer:
     """One served model behind a ROUTER socket (continuous batching).
 
+    **What is in flight when.**  One thread runs one loop
+    (:meth:`serve_forever`).  A tick's launch dispatches the model call
+    without waiting for it; between two turns of the loop at most ONE
+    launched tick is outstanding (two inside a turn: the follower is
+    dispatched, then the older one retired).  While it runs on the
+    device the loop admits requests, and by what it can observe
+    (is anything queued, can anybody still send, is the launched tick
+    ready) it retires the tick, or launches the next one behind it
+    first.  A lone client, an empty queue, or a model that computes on
+    the host sees launch-then-retire: the tick as it always was.
+    Nothing is in flight when weights are swapped or a prefill runs
+    (both retire what is launched first), and a step stays pending
+    (deduplicated) until its reply has been sent.
+
     Params
     ------
     address: str
@@ -603,9 +709,10 @@ class PolicyServer:
         workload against a multi-model server is byte-identical to a
         single-model server — test-locked).
     tick_ms: float
-        Admission window once the queue is non-empty: how long one tick
-        waits for more arrivals before computing (latency it trades for
-        batch occupancy).
+        Admission window once the queue is non-empty and nothing is
+        launched: how long one tick waits for more arrivals before
+        computing (latency it trades for batch occupancy).  With a
+        tick launched, the device's own tick is the window.
     max_batch: int
         Largest bucket (and the most requests one tick serves).
     buckets: tuple | None
@@ -669,7 +776,12 @@ class PolicyServer:
         self._reply_cache = OrderedDict()
         self._reply_cache_depth = int(reply_cache_depth)
         self._queue = deque()
-        self._pending = {}  # mid -> _Pending still queued (dedupe)
+        # mid -> _Pending not answered yet, queued or launched (dedupe):
+        # an entry leaves when its reply has entered the reply cache
+        self._pending = {}
+        # ticks dispatched and not yet answered, oldest first: at most
+        # one between two turns of the serve loop, two inside a turn
+        self._launched = deque()
         # Slot pools live per hosted model (:class:`_ModelState`):
         # ``live`` maps slot -> [episode lease id, monotonic last-use].
         # The lease id disambiguates slot REUSE: an evicted episode's
@@ -889,6 +1001,11 @@ class PolicyServer:
                 f"prefix shape {prefix.shape} != (T >= 1, "
                 f"{st.model.obs_dim})"
             )
+        # the prefill waits for its own reply, and so for every tick
+        # launched before it: those are answered first, so that their
+        # clients turn round while the prefill runs and not after it
+        while self._launched:
+            self._retire()
         t0 = time.perf_counter()
         try:
             with span("serve.prefill", len=int(prefix.shape[0])):
@@ -1012,16 +1129,21 @@ class PolicyServer:
 
     def _poll_weights(self):
         """Drain the WeightBus subscription and hot-swap a staged
-        snapshot — called from the serve loop BETWEEN ticks, the one
-        point where no batch is in flight, so slots/leases/reply-cache
-        state cannot be half-stepped under a swap.  A snapshot the
-        model refuses (structure/shape drift) is discarded and counted;
-        the last good version keeps serving either way."""
+        snapshot — called from the serve loop between turns.  A tick
+        may be launched then: a staged snapshot RETIRES it first, so
+        the swap happens at a point where no batch is in flight —
+        slots/leases/reply-cache state cannot be half-stepped under it,
+        and every reply is stamped (``_finish``) with the version that
+        executed it.  A snapshot the model refuses (structure/shape
+        drift) is discarded and counted; the last good version keeps
+        serving either way."""
         if self.subscriber is None:
             return
         snap = self.subscriber.poll()
         if snap is None:
             return
+        while self._launched:
+            self._retire()
         # routing: the snapshot's own model id wins; a publisher that
         # does not stamp one (a learner publishing its only model)
         # targets the model the SUBSCRIBER was attached for, default
@@ -1169,8 +1291,9 @@ class PolicyServer:
                          t0_us=t0_us)
             return
         if mid is not None and mid in self._pending:
-            # retry of a request still QUEUED: the original's reply
-            # will answer it — re-point the route and drop the dup
+            # retry of a request still QUEUED, or launched and not yet
+            # answered: the original's reply will answer it — re-point
+            # the route and drop the dup (its step runs once)
             self.counters.incr("serve_dup_inflight")
             self._pending[mid].ident = ident
             return
@@ -1188,24 +1311,43 @@ class PolicyServer:
         if mid is not None:
             self._pending[mid] = ent
 
+    def _answer_step(self, ent, reply, ding=True):
+        """Answer one step entry, wherever it got to (refused at
+        assembly, failed, or served): only now does it stop being
+        pending, so a retry that arrives while its step is in flight
+        finds it in ``_pending`` and one that arrives later finds the
+        reply cache."""
+        if ent.mid is not None:
+            self._pending.pop(ent.mid, None)
+        self._finish(ent.ident, ent.msg, reply, span_name="serve:step",
+                     t0_us=ent.t0_us, ding=ding)
+
     def _step_entry_error(self, ent, text, lease=None):
-        """Error-reply one queued step.  ``lease`` ("unknown"/"stale")
+        """Error-reply one step entry.  ``lease`` ("unknown"/"stale")
         rides as a structured field so a gateway can drop its own lease
         entry without parsing error prose."""
         self.counters.incr("serve_errors")
         reply = {"error": text}
         if lease is not None:
             reply["lease"] = lease
-        self._finish(ent.ident, ent.msg, reply,
-                     span_name="serve:step", t0_us=ent.t0_us)
+        self._answer_step(ent, reply)
 
-    def _tick(self):
-        """Drain up to ``max_batch`` queued steps into one padded,
-        bucketed model call and scatter the replies.  A tick serves ONE
-        hosted model (the queue head's); entries for other models are
-        left in order and the return value says so, so the serve loop
-        ticks again immediately instead of making them wait out another
-        admission window."""
+    def _step_failed(self, batch, exc):
+        for ent, _, _ in batch:
+            self._step_entry_error(
+                ent, f"batched step failed: {type(exc).__name__}: {exc}")
+
+    def _launch(self):
+        """A tick's first half: drain up to ``max_batch`` queued steps
+        into one padded, bucketed model call and DISPATCH it.  The
+        call's reply is not waited for: the tick joins ``_launched``
+        and :meth:`_retire` fetches it and answers, after the serve
+        loop has had the device's time for admission, the next launch
+        or an older tick's replies.  A tick serves ONE hosted model
+        (the queue head's); entries for other models are left in order
+        and the return value says so, so the serve loop launches again
+        immediately instead of making them wait out another admission
+        window."""
         with span("serve.tick") as tick:
             with span("serve.tick.assemble"):
                 t_assemble = time.perf_counter()
@@ -1219,8 +1361,6 @@ class PolicyServer:
                     elif ent.mstate is not head:
                         skipped.append(ent)
                         continue
-                    if ent.mid is not None:
-                        self._pending.pop(ent.mid, None)
                     st = ent.mstate
                     stateful = st.model.slots > 0
                     slot = int(ent.msg.get("slot", -1)) if stateful else -1
@@ -1267,7 +1407,7 @@ class PolicyServer:
                     batch.append((ent, slot, obs))
                 # skipped other-model entries return to the FRONT in order:
                 # they are older than anything still queued behind them —
-                # ``more`` asks the serve loop to tick again NOW for them
+                # ``more`` asks the serve loop to launch again NOW for them
                 # (same-model overflow keeps the admission-window pacing)
                 more = bool(skipped)
                 while skipped:
@@ -1299,51 +1439,89 @@ class PolicyServer:
             tick.set_metadata(rows=n, bucket=bucket)
             with span("serve.tick.compute"):
                 try:
-                    preds = model.step_rows(idx, obs_arr)
+                    reply = model.step_rows(idx, obs_arr)
                 except Exception as exc:  # noqa: BLE001 - must survive
                     logger.exception("policy server: batched step failed")
                     if isinstance(exc, SlotPoolLost):
                         self._pool_lost(head)
-                    for ent, _, _ in batch:
-                        self._step_entry_error(
-                            ent, "batched step failed: "
-                                 f"{type(exc).__name__}: {exc}"
-                        )
+                    self._step_failed(batch, exc)
                     return more
-                t_reply = time.perf_counter()
-                self.timer.add("compute", t_reply - t_compute)
+            self.counters.incr("serve_ticks_overlapped",
+                               int(bool(self._launched)))
+            self._launched.append(_Launched(
+                head, batch, reply, bucket, pos_before,
+                time.perf_counter() - t_compute))
+            return more
+
+    def _retire(self):
+        """A tick's second half, for the oldest launched tick: fetch
+        its reply (the one place the server's thread waits for the
+        device), count it and scatter the answers.  A fetch that fails
+        for a lost slot pool (:class:`SlotPoolLost`: raised once per
+        pool) also fails whatever was launched behind it on that pool;
+        the leases are dropped once."""
+        tick = self._launched.popleft()
+        model = tick.state.model
+        n = len(tick.batch)
+        with span("serve.retire", rows=n):
+            t_fetch = time.perf_counter()
+            try:
+                preds = np.asarray(tick.reply)
+            except Exception as exc:  # noqa: BLE001 - must survive
+                logger.exception("policy server: batched step failed")
+                behind = []
+                if isinstance(exc, SlotPoolLost):
+                    self._pool_lost(tick.state)
+                    behind = [t for t in self._launched
+                              if t.state is tick.state]
+                for t in behind:
+                    self._launched.remove(t)
+                for t in [tick] + behind:
+                    self._step_failed(t.batch, exc)
+                return
+            t_reply = time.perf_counter()
+            self.counters.incr("serve_fetch_wait_us",
+                               int((t_reply - t_fetch) * 1e6))
+            # the host's time inside the model call for this tick: its
+            # dispatch and its fetch, not what ran between the two
+            self.timer.add("compute", tick.compute_s + t_reply - t_fetch)
             with span("serve.tick.reply"):
                 self.counters.incr("serve_batches")
                 if hasattr(model, "drain_events"):
                     for name, count in model.drain_events().items():
                         self.counters.incr(name, count)
-                if bucket > n:
-                    self.counters.incr("serve_batch_pad", bucket - n)
-                for j, (ent, slot, _) in enumerate(batch):
+                if tick.bucket > n:
+                    self.counters.incr("serve_batch_pad", tick.bucket - n)
+                for j, (ent, _, _) in enumerate(tick.batch):
                     reply = {"pred": np.ascontiguousarray(preds[j])}
-                    if pos_before[j] is not None:
-                        reply["pos"] = pos_before[j]
+                    if tick.pos_before[j] is not None:
+                        reply["pos"] = tick.pos_before[j]
                     # deferred doorbells: the whole batch's shm replies
                     # ride ONE wake per channel (flushed below), not one
                     # ding per record
-                    self._finish(ent.ident, ent.msg, reply,
-                                 span_name="serve:step", t0_us=ent.t0_us,
-                                 ding=False)
+                    self._answer_step(ent, reply, ding=False)
                 if self._shm is not None:
                     self._shm.flush_bells()
                 self.timer.add("reply", time.perf_counter() - t_reply)
-            return more
 
     # -- serving -------------------------------------------------------------
 
-    def _window_target(self):
-        """Queue occupancy at which an admission window stops waiting:
-        every live episode (a blocking client keeps at most one step in
-        flight, so a fuller window cannot form), capped at the largest
-        bucket.  Stateless episodes are tracked by last use and pruned
-        after :data:`STATELESS_TTL_S` idle; the ``max(1, ...)`` keeps a
-        client that never reset servable instead of deadlocking the
-        window."""
+    def _window_target(self, in_flight=0):
+        """Queue occupancy at which an admission window stops waiting.
+
+        With nothing launched: every live episode (a blocking client
+        keeps at most one step in flight, so a fuller window cannot
+        form), capped at the largest bucket.  Stateless episodes are
+        tracked by last use and pruned after :data:`STATELESS_TTL_S`
+        idle; the ``max(1, ...)`` keeps a client that never reset
+        servable instead of deadlocking the window.
+
+        With a tick launched over ``in_flight`` rows: the episodes in it
+        cannot send, so at most the others; and no more than half the
+        live episodes, because with one tick on the device and one being
+        gathered an even split keeps the device fed by either (a
+        follower that waited for more would be launched after the
+        device had gone idle).  Zero or less when nobody can send."""
         live = 0
         for st in self._models.values():
             if st.model.slots > 0:
@@ -1355,7 +1533,9 @@ class PolicyServer:
                         if ts < cutoff:
                             del st.stateless_eps[ep]
                 live += len(st.stateless_eps)
-        return min(self.max_batch, max(1, live))
+        if not in_flight:
+            return min(self.max_batch, max(1, live))
+        return min(self.max_batch, live - in_flight, -(-live // 2))
 
     def _drain(self):
         """Admit every request currently sitting on the socket."""
@@ -1403,52 +1583,100 @@ class PolicyServer:
             self._drain_shm()
 
     def _idle_poll(self, poll_ms):
-        """Wait, with nothing queued, for the next request to arrive:
-        the clients' turnaround, which no change to the server
-        recovers.  ``serve_idle_us`` is the one record of it."""
+        """Wait, with nothing queued and nothing launched, for the next
+        request to arrive: the clients' turnaround, which no change to
+        the server recovers.  ``serve_idle_us`` is the one record of
+        it."""
         t0 = time.perf_counter()
         with span("serve.idle"):
             self._poller.poll(poll_ms)
         self.counters.incr("serve_idle_us",
                            int((time.perf_counter() - t0) * 1e6))
 
+    def _window(self):
+        """The admission window: wait for co-arriving requests (the
+        latency the scheduler trades for occupancy) until every episode
+        that can send has a step queued (episodes step one request at a
+        time, so nobody else can arrive — waiting longer would be pure
+        latency) or a full bucket is.
+
+        With nothing launched the window is ``tick_ms`` long and ends
+        early on the first empty poll slice.  With a tick launched the
+        target is smaller (:meth:`_window_target`) and the device's own
+        tick is the window: it ends when that tick's reply
+        is ready (its answers should not wait for a follower), however
+        long or short ``tick_ms`` is.  Meanwhile the wires are read in
+        slices of a millisecond, never slept on: a pump of the shm
+        channels costs the same for one request as for a burst, and the
+        follower cannot start before the launched tick ends anyway."""
+        t_end = time.perf_counter() + self.tick_ms / 1000.0
+        with span("serve.window"):
+            while True:
+                # read each time round: admission retires the launched
+                # tick itself before it runs a prefill
+                flying = self._launched[-1] if self._launched else None
+                in_flight = len(flying.batch) if flying is not None else 0
+                if len(self._queue) >= self._window_target(in_flight):
+                    break
+                if flying is not None:
+                    if _reply_ready(flying.reply):
+                        break
+                    t_slice = time.perf_counter() + 1e-3
+                    if not self._poller.poll(1):
+                        continue
+                    time.sleep(max(0.0, t_slice - time.perf_counter()))
+                else:
+                    rem_ms = (t_end - time.perf_counter()) * 1e3
+                    if rem_ms <= 0:
+                        break
+                    if not self._poller.poll(max(1, int(rem_ms))):
+                        break  # window elapsed with nothing new
+                self._admit_ready()
+
+    def _turn(self):
+        """One move of the serve loop after the window, by what it can
+        observe.  A launched tick that is ready, or that nothing queued
+        could follow, is retired (and the loop comes round again for
+        what is queued: a finished tick's answers never wait for a
+        follower's launch).  Otherwise what is queued is launched, BEHIND
+        a tick still running if there is one, and then the older tick is
+        retired: the launch, the older tick's fetch and replies, and the
+        admission that follows all run beside the device.  A lone
+        client, or an empty queue, sees launch then retire: a tick as it
+        always was."""
+        if self._launched and (
+                not self._queue or _reply_ready(self._launched[0].reply)):
+            self._retire()
+            return
+        # a launch serves one model; entries it skipped for model
+        # mismatch are launched at once behind it, not parked behind
+        # another admission window
+        more = True
+        while more and self._queue:
+            more = self._launch()
+            while len(self._launched) > 1:
+                self._retire()
+
     def serve_forever(self, stop_event=None, poll_ms=50):
+        """The one serve loop.  Between two turns at most ONE tick is
+        launched (dispatched, its reply not yet fetched); a hot-swap
+        retires it first, so weights change with nothing in flight."""
         import zmq
 
         while stop_event is None or not stop_event.is_set():
             try:
-                # between ticks: the hot-swap point (no batch in
-                # flight, every queued entry still un-executed)
                 self._poll_weights()
-                if not self._queue:
+                if not self._queue and not self._launched:
                     self._idle_poll(poll_ms)
                     self._admit_ready()
                     if not self._queue:
                         continue
-                # admission window: work is queued — wait up to tick_ms
-                # for co-arriving requests (the latency the scheduler
-                # trades for occupancy).  Leave early on the first
-                # empty poll slice, a full bucket, or once every LIVE
-                # episode has a step queued (episodes step one request
-                # at a time, so nobody else can arrive — waiting out
-                # the window would be pure latency)
-                t_end = time.perf_counter() + self.tick_ms / 1000.0
-                with span("serve.window"):
-                    while len(self._queue) < self._window_target():
-                        rem_ms = (t_end - time.perf_counter()) * 1e3
-                        if rem_ms <= 0:
-                            break
-                        if not self._poller.poll(max(1, int(rem_ms))):
-                            break  # window elapsed with nothing new
-                        self._admit_ready()
+                self._window()
             except zmq.ZMQError:
                 return  # socket closed under us: clean shutdown
-            if self._queue:
-                # a tick serves one model; entries it skipped for model
-                # mismatch are served by immediate follow-up ticks, not
-                # parked behind another admission window
-                while self._tick():
-                    pass
+            self._turn()
+        while self._launched:
+            self._retire()  # stopped: what was launched is still answered
 
     def close(self):
         try:

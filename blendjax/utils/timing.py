@@ -173,7 +173,15 @@ REPLAY_EVENTS = (
 #: ticks, real rows only, summed over its expert layers: (token, expert)
 #: assignments the router made, those whose expert this rank holds, and
 #: distinct held experts that got a token (whose weights a tick must
-#: read); they come over with the reply's fence.
+#: read); they come over with the reply's fetch.
+#: ``serve_ticks_overlapped`` — ticks launched (dispatched) while an
+#: older tick's reply was still to be fetched: over ``serve_batches``,
+#: how often the server's admission and replies ran beside the device
+#: and not between its ticks (0 for a lone client or a model that
+#: computes on the host);
+#: ``serve_fetch_wait_us`` — microseconds the server's thread was
+#: blocked fetching a launched tick's reply (waiting for the device):
+#: the one place it waits for it.
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
@@ -183,6 +191,7 @@ SERVE_EVENTS = (
     "serve_prefill_us", "serve_idle_us", "serve_pool_rebuilds",
     "serve_moe_assignments", "serve_moe_assignments_held",
     "serve_moe_experts_hit",
+    "serve_ticks_overlapped", "serve_fetch_wait_us",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md
@@ -462,8 +471,10 @@ GATEWAY_STAGES = (
 #: ``PolicyServer`` report under: ``queue_wait`` (request admission to
 #: batch dequeue — the continuous-batching latency price), and the tick
 #: processing: ``batch_assemble`` (drain + pad-to-bucket + host-side
-#: array build), ``compute`` (the jitted model call, fenced),
-#: ``reply`` (per-client scatter of the batch's replies).
+#: array build), ``compute`` (the host's time inside the model call
+#: for one tick: its dispatch plus the fetch of its reply, not what ran
+#: between the two), ``reply`` (per-client scatter of the batch's
+#: replies).
 SERVE_STAGES = (
     "queue_wait", "batch_assemble", "compute", "reply",
 )

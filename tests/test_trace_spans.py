@@ -41,7 +41,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SERVER_SPANS = (
     "serve.idle", "serve.admit", "serve.prefill", "serve.window",
     "serve.tick", "serve.tick.assemble", "serve.tick.compute",
-    "serve.tick.reply", "serve.weights",
+    "serve.retire", "serve.tick.reply", "serve.weights",
 )
 MODEL_SPANS = (
     "serve.step.dispatch", "serve.step.fence",
@@ -138,18 +138,29 @@ def test_server_span_is_in_the_profilers_trace(served, name):
         n for n in served.events if n.startswith("serve"))
 
 
+def _inside(served, inner, outer):
+    """Every ``inner`` span lies within some ``outer`` span."""
+    outers = [(lo, hi) for lo, hi, _ in served.events[outer]]
+    return all(any(o_lo <= lo and hi <= o_hi for o_lo, o_hi in outers)
+               for lo, hi, _ in served.events[inner])
+
+
 def test_tick_phases_lie_inside_a_tick(served):
-    ticks = [(lo, hi) for lo, hi, _ in served.events["serve.tick"]]
-    for phase in ("assemble", "compute", "reply"):
-        for lo, hi, _ in served.events[f"serve.tick.{phase}"]:
-            assert any(t_lo <= lo and hi <= t_hi for t_lo, t_hi in ticks), \
-                phase
-    # and the model's own two halves inside a tick's compute
-    computes = [(lo, hi) for lo, hi, _ in
-                served.events["serve.tick.compute"]]
-    for half in ("dispatch", "fence"):
-        for lo, hi, _ in served.events[f"serve.step.{half}"]:
-            assert any(c_lo <= lo and hi <= c_hi for c_lo, c_hi in computes)
+    # a tick has two halves: the launch (``serve.tick``: assemble and
+    # the model call, which for a model on the device is the dispatch
+    # alone) and the retire (the fetch, then the replies)
+    for phase in ("assemble", "compute"):
+        assert _inside(served, f"serve.tick.{phase}", "serve.tick"), phase
+    assert _inside(served, "serve.tick.reply", "serve.retire")
+    # and the model's own two halves: the dispatch inside the launch's
+    # compute, the fence where the fetch happens
+    assert _inside(served, "serve.step.dispatch", "serve.tick.compute")
+    assert _inside(served, "serve.step.fence", "serve.retire")
+    # every launched tick was retired, in order
+    launches = sorted(lo for lo, _, _ in served.events["serve.tick.compute"])
+    retires = sorted(lo for lo, _, _ in served.events["serve.retire"])
+    assert len(launches) == len(retires)
+    assert all(a < b for a, b in zip(launches, retires))
 
 
 def test_span_arguments_become_event_stats(served):
@@ -160,10 +171,14 @@ def test_span_arguments_become_event_stats(served):
 
 
 def test_prefill_and_idle_counters(served):
-    for name in ("serve_prefill_us", "serve_idle_us"):
+    for name in ("serve_prefill_us", "serve_idle_us",
+                 "serve_ticks_overlapped", "serve_fetch_wait_us"):
         assert name in SERVE_EVENTS
         # the hub zero-fills them before any server has reported
         assert TelemetryHub().scrape()["counters"][name] == 0
+    # one client: no tick was ever launched behind another, and the
+    # program says so (the key is there, at 0)
+    assert served.after["serve_ticks_overlapped"] == 0
     assert served.before.get("serve_prefill_us", 0) == 0
     assert served.after["serve_prefills"] == 1
     assert 0 < served.after["serve_prefill_us"] <= served.wall_us
