@@ -38,17 +38,30 @@ a `gate` is the gated SiLU MLP; a `moe` with a `route` is the held share
 of a sigmoid-routed layer (:func:`blendjax.models.moe.moe_apply_held`);
 `embed` with a `table` takes int32 ids, and a `head` without a bias
 answers with float32 logits over its vocabulary slice
-(:func:`init_token_model`).
+(:func:`init_token_model`); no `head` at all is the embedding tied.
+
+**Layers of different kinds in one model** (:func:`init_hybrid_model`;
+the kinds are :func:`hybrid_layer_kinds`' rule) are read off each block
+the same way: an `ssm` entry is a state-space mixer
+(:mod:`blendjax.models.mamba`: a recurrent state and a convolution tail
+per sequence, no position indexes them), a `gmu` entry a gated memory
+unit over the last state-space layer's scan output, a `diff` entry
+differential attention (:mod:`blendjax.models.diffattn`) -- over its own
+keys and values, a ring of `spec.window` positions or the full length,
+or, in a block without `wk`/`wv`, over the keys and values of the last
+block that made any (a *cross* layer: eight layers then read one
+buffer).  Such a model has no positional encoding.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
 import jax.numpy as jnp
 
-from blendjax.models import mla
+from blendjax.models import diffattn, mamba, mla
 from blendjax.models.layers import (
     apply_rope,
     apply_rope_rows,
@@ -98,14 +111,24 @@ def _ln_init(d):
     return {"scale": jnp.ones((d,)), "bias": jnp.zeros((d,))}
 
 
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class NormSpec:
+    """A norm's epsilon where it is not this module's 1e-6 (an entry
+    ``"spec"`` beside ``scale``)."""
+
+    eps: float
+
+
 @jax.named_scope("ln")
 def _ln_apply(p, x):
+    eps = p["spec"].eps if "spec" in p else 1e-6
     if "bias" not in p:
-        return rms_norm(p["scale"], x)
+        return rms_norm(p["scale"], x, eps)
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
     var = x32.var(-1, keepdims=True)
-    out = (x32 - mu) * jax.lax.rsqrt(var + 1e-6)
+    out = (x32 - mu) * jax.lax.rsqrt(var + eps)
     return (out * p["scale"] + p["bias"]).astype(x.dtype)
 
 
@@ -137,6 +160,23 @@ def _latent(params):
     return "mla" in params["blocks"][0]
 
 
+def _hybrid(params):
+    """Whether the model mixes layer kinds (state-space blocks among
+    them): its cache then holds recurrent state beside keys and values."""
+    return any("ssm" in blk for blk in params["blocks"])
+
+
+def _windows_are_described(hybrid, window):
+    if hybrid and window is not None:
+        raise ValueError("a model of mixed layer kinds takes its windows "
+                         "from its description, layer by layer")
+
+
+#: the cache entries that no position indexes: written whole, and zeroed
+#: (not masked) when a row is rewound
+_RECURRENT = ("ssm_h", "ssm_tail")
+
+
 def _embed(params, obs, dtype):
     """Observations through the dense projection, or int ids through the
     table (an id outside it is clipped)."""
@@ -151,12 +191,23 @@ def _head(params, x, dtype):
     """float32 predictions: the observation head, or logits over the
     vocabulary slice (a bias-free head; products in ``dtype``,
     accumulated in float32, the slice's weights never upcast)."""
+    if "head" not in params:  # tied: the embedding table, transposed
+        return jnp.einsum("...d,vd->...v", x.astype(dtype),
+                          params["embed"]["table"].astype(dtype),
+                          preferred_element_type=jnp.float32)
     head = params["head"]
     if "b" in head:
         return _dense_mq(head, x, jnp.float32)
     return jnp.einsum("...d,dv->...v", x.astype(dtype),
                       head["w"].astype(dtype),
                       preferred_element_type=jnp.float32)
+
+
+def _diff_kind(blk):
+    """The scope a differential block's attention runs under."""
+    if "wk" not in blk:
+        return "cross"
+    return "full" if blk["diff"]["spec"].window is None else "window"
 
 
 def _held_moe(blk, h, dtype, auxs, valid=None):
@@ -234,9 +285,98 @@ def token_model_specs(config, first=0):
     )
 
 
+def hybrid_layer_kinds(config):
+    """The kind of every layer of a decoder-hybrid-decoder model
+    (arXiv:2507.06607), from ``num_hidden_layers`` and ``mb_per_layer``:
+    in the first half every ``mb_per_layer``-th layer is ``"ssm"`` and
+    the others ``"window"`` attention; the second half opens with one
+    ``"ssm"`` (whose scan output is the memory of what follows) and one
+    ``"full"`` attention (whose keys and values are the only full-length
+    cache), then every ``mb_per_layer``-th layer is a ``"gmu"`` over that
+    memory and the others ``"cross"`` attention over those keys and
+    values."""
+    n, every = config["num_hidden_layers"], config["mb_per_layer"]
+    half = n // 2
+    kinds = []
+    for layer in range(n):
+        if layer == half + 1:
+            kinds.append("full")
+        elif layer <= half:
+            kinds.append("ssm" if layer % every == 0 or layer == half
+                         else "window")
+        else:
+            kinds.append("gmu" if layer % every == 0 else "cross")
+    return kinds
+
+
+def _describe_hybrid(arrays, config):
+    """A hybrid model's static entries: each norm's epsilon, and each
+    differential block's ``lam_init`` and window."""
+    eps = NormSpec(float(config["layer_norm_eps"]))
+    kinds = hybrid_layer_kinds(config)
+    if len(kinds) != len(arrays["blocks"]):
+        raise ValueError(f"{len(arrays['blocks'])} blocks for {len(kinds)} "
+                         "layers")
+    arrays["ln_f"]["spec"] = eps
+    for layer, (kind, blk) in enumerate(zip(kinds, arrays["blocks"])):
+        blk["ln1"]["spec"] = blk["ln2"]["spec"] = eps
+        holds = ("ssm" if "ssm" in blk else "gmu" if "gmu" in blk
+                 else "cross" if "wk" not in blk else "self")
+        if holds != ("self" if kind in ("window", "full") else kind):
+            raise ValueError(f"layer {layer} is {kind!r} by the "
+                             f"configuration and holds {sorted(blk)}")
+        if "diff" in blk:
+            blk["diff"]["spec"] = diffattn.DiffSpec(
+                diffattn.lam_init_of(layer),
+                config["sliding_window"] if kind == "window" else None)
+    return arrays
+
+
+def init_hybrid_model(key, config, dtype=jnp.float32):
+    """A decoder-hybrid-decoder token model from the published keys
+    ``hidden_size, num_attention_heads, num_key_value_heads,
+    intermediate_size, num_hidden_layers, mb_per_layer, sliding_window,
+    layer_norm_eps, vocab_size`` (tied embedding, LayerNorm, gated SiLU
+    feed-forwards, no positional encoding) and the state-space sizes
+    ``mamba_d_state, mamba_d_conv, mamba_expand, mamba_dt_rank``."""
+    c = config
+    d, heads = c["hidden_size"], c["num_attention_heads"]
+    d_inner = c["mamba_expand"] * d
+    kinds = hybrid_layer_kinds(c)
+    ke, *kb = jax.random.split(key, 1 + len(kinds))
+
+    def norm():
+        return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+
+    blocks = []
+    for kind, k in zip(kinds, kb):
+        km, kf = jax.random.split(k)
+        blk = {"ln1": norm(), "ln2": norm(),
+               "mlp": gated_mlp_init(kf, d, c["intermediate_size"], dtype)}
+        if kind == "ssm":
+            blk["ssm"] = mamba.init(km, d, d_inner, c["mamba_d_state"],
+                                    c["mamba_d_conv"], c["mamba_dt_rank"],
+                                    dtype)
+        elif kind == "gmu":
+            blk["gmu"] = mamba.gmu_init(km, d, d_inner, dtype)
+        else:
+            blk.update(diffattn.init(
+                km, d, heads, c["num_key_value_heads"], d // heads, None,
+                cross=kind == "cross", dtype=dtype))
+        blocks.append(blk)
+    return _describe_hybrid({
+        "embed": {"table": scaled_normal(ke, (c["vocab_size"], d), 1.0,
+                                         dtype)},
+        "blocks": blocks, "ln_f": norm()}, c)
+
+
 def describe_token_model(arrays, config, first=0):
     """Weights made elsewhere in this layout (arrays only) become a model:
-    every block is given its static entries.  Returns ``arrays``."""
+    every block is given its static entries.  Returns ``arrays``.  A
+    configuration with ``mb_per_layer`` describes a model of mixed layer
+    kinds (:func:`hybrid_layer_kinds`)."""
+    if "mb_per_layer" in config:
+        return _describe_hybrid(arrays, config)
     mla_spec, route = token_model_specs(config, first)
     for blk in arrays["blocks"]:
         blk["mla"]["spec"] = mla_spec
@@ -382,13 +522,21 @@ def init(
 
 def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
              moe_impl="dense", moe_k=2, moe_capacity_factor=1.25,
-             moe_dispatch="sort", kv_sink=None, last_only=False):
+             moe_dispatch="sort", kv_sink=None, last_only=False,
+             state_in=None, diff_attn_fn=None):
     """Shared forward: returns (prediction, list of per-layer MoE aux).
 
-    ``kv_sink`` (a list) collects each layer's cache entries, a ``(k,
-    v)`` pair or a latent block's ``(rows,)`` — :func:`rollout`'s
-    vectorized prefill fills its caches from one teacher-forced pass
-    instead of t0 serial decode steps.  ``last_only`` answers for the
+    ``kv_sink`` (a list) collects, block by block, what the block gives
+    the cache under the cache's own names (``k`` and ``v``, a latent
+    block's ``kv`` rows, a state-space block's ``ssm_h`` and ``ssm_tail``
+    after the last position; nothing for a block that keeps nothing) —
+    :func:`rollout`'s vectorized prefill fills its caches from one
+    teacher-forced pass instead of t0 serial decode steps.
+    ``state_in`` (``{"ssm_h": [...], "ssm_tail": [...]}`` by block) is
+    the recurrent state the state-space blocks start from, zeros by
+    default.  ``diff_attn_fn(q, k, v, scale, window)`` is the causal
+    attention under the differential blocks (plain by default).
+    ``last_only`` answers for the
     last position alone (a prefill over a vocabulary wants no other
     logits).  The ``moe_*`` arguments choose the evaluation of the
     legacy expert entry only; a held-share layer carries its own."""
@@ -396,26 +544,67 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
         def attn_fn(q, k, v):
             return full_attention(q, k, v, causal=True)
 
+    if diff_attn_fn is None:
+        def diff_attn_fn(q, k, v, scale, window):
+            return full_attention(q, k, v, causal=True, scale=scale,
+                                  window=window)
+
     t = obs.shape[1]
     auxs = []
-    use_rope = "pos" not in params and not _latent(params)
+    sink = [] if kv_sink is None else kv_sink
+    use_rope = ("pos" not in params and not _latent(params)
+                and not _hybrid(params))
     x = _embed(params, obs, compute_dtype)
     if use_rope:
         dh = _wq_head_dim(params)
         cos, sin = rope_table(jnp.arange(t), dh)
     elif "pos" in params:
         x = x + params["pos"][:t].astype(compute_dtype)[None]
-    for blk in params["blocks"]:
+    memory = shared_kv = None
+    for i, blk in enumerate(params["blocks"]):
         if "mla" in blk:
             with jax.named_scope("mla"):
                 h = _ln_apply(blk["ln1"], x)
                 q_nope, q_pe, rows = mla.project(
                     blk["mla"], h, *mla.rope(blk["mla"], jnp.arange(t)),
                     compute_dtype)
-                if kv_sink is not None:
-                    kv_sink.append((rows,))
+                sink.append({"kv": rows})
                 x = x + mla.attend_expanded(blk["mla"], q_nope, q_pe, rows,
                                             compute_dtype)
+        elif "ssm" in blk:
+            with jax.named_scope("ssm"):
+                h_shape, tail_shape = mamba.state_shapes(blk["ssm"])
+                if state_in is None:
+                    state = (jnp.zeros((x.shape[0], *h_shape), jnp.float32),
+                             jnp.zeros((x.shape[0], *tail_shape),
+                                       compute_dtype))
+                else:
+                    state = (state_in["ssm_h"][i],
+                             state_in["ssm_tail"][i].reshape(-1, *tail_shape))
+                out, memory, h_last, tail = mamba.mix_sequence(
+                    blk["ssm"], _ln_apply(blk["ln1"], x), *state,
+                    compute_dtype)
+                sink.append({"ssm_h": h_last,
+                             "ssm_tail": tail.reshape(tail.shape[0], -1)})
+                x = x + out
+        elif "gmu" in blk:
+            with jax.named_scope("gmu"):
+                sink.append({})
+                x = x + mamba.gmu(blk["gmu"], _ln_apply(blk["ln1"], x),
+                                  memory, compute_dtype)
+        elif "diff" in blk:
+            with jax.named_scope("attn"):
+                h = _ln_apply(blk["ln1"], x)
+                q = diffattn.project_q(blk, h, compute_dtype)
+                kept = {}
+                if "wk" in blk:
+                    shared_kv = diffattn.project_kv(blk, h, compute_dtype)
+                    kept = {name: kv.reshape(*kv.shape[:2], -1)
+                            for name, kv in zip(("k", "v"), shared_kv)}
+                sink.append(kept)
+                with jax.named_scope(_diff_kind(blk)):
+                    x = x + diffattn.attend(blk, q, *shared_kv,
+                                            compute_dtype, diff_attn_fn)
         else:
             with jax.named_scope("attn"):
                 h = _ln_apply(blk["ln1"], x)
@@ -430,8 +619,7 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
                     # scores relative)
                     q = apply_rope(q, cos, sin)
                     k = apply_rope(k, cos, sin)
-                if kv_sink is not None:
-                    kv_sink.append((k, v))
+                sink.append({"k": k, "v": v})
                 a = attn_fn(q, k, v)
                 x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd",
                                  compute_dtype)
@@ -594,7 +782,22 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
     """Per-layer KV caches: ``{'k': [(B, L, Hkv, Dh)], 'v': [...],
     'pos': 0}``, or for a latent-attention model ``{'kv': [(B, L,
     kv_rank + rope)], 'pos': 0}``, one row a position
-    (:mod:`blendjax.models.mla`).  ``length`` defaults to the model's ``max_len`` (the
+    (:mod:`blendjax.models.mla`).  A model of mixed layer kinds holds
+    **three kinds of state side by side**, each list indexed by block
+    with ``None`` where the block keeps nothing of that kind: a ring of
+    ``window`` positions of keys and of values ``(B, window, Hkv * Dh)``
+    per window layer, one full-length ``(B, L, Hkv * Dh)`` pair for the
+    full layer (which the cross layers read), and per state-space layer
+    ``ssm_h`` ``(B, d_state, d_inner)`` float32 and ``ssm_tail`` ``(B,
+    (d_conv - 1) * d_inner)``.  Every leaf is flat behind its row or
+    position, which is the layout the TPU compiler keeps as it is handed
+    it (compiled for a described v5e; ``tests/test_tpu_compile.py``):
+    a minor pair of axes like ``(Hkv / 2, 2 Dh)`` or ``(d_conv - 1,
+    d_inner)``, whose second-minor axis is no whole tile, was re-laid
+    on the way in and out, and pair-major ``(B, Hkv / 2, L, 2 Dh)`` keys
+    were re-laid positions-major for the one-position write: either way
+    the whole pool copied twice a layer.
+    ``length`` defaults to the model's ``max_len`` (the
     ``pos`` table); pass the actual decode horizon to size the cache —
     and every step's attention — to the sequence you will run.  Rope
     models have no table and no inherent bound: ``length`` is required.
@@ -629,6 +832,24 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
         jnp.zeros((batch_size,), jnp.int32)
         if per_row else jnp.asarray(0, jnp.int32)
     )
+    if _hybrid(params):
+        caches = {"pos": pos0, "k": [], "v": [], "ssm_h": [], "ssm_tail": []}
+        for blk in params["blocks"]:
+            own = dict.fromkeys(("k", "v") + _RECURRENT)
+            if "ssm" in blk:
+                h_shape, tail_shape = mamba.state_shapes(blk["ssm"])
+                own["ssm_h"] = jnp.zeros((batch_size, *h_shape), jnp.float32)
+                own["ssm_tail"] = jnp.zeros(
+                    (batch_size, math.prod(tail_shape)), dtype)
+            elif "wk" in blk:
+                _, h_kv, dh = blk["wk"].shape
+                ring = min(blk["diff"]["spec"].window or length, length)
+                for name in ("k", "v"):
+                    own[name] = jnp.zeros((batch_size, ring, h_kv * dh),
+                                          dtype)
+            for name, leaf in own.items():
+                caches[name].append(leaf)
+        return caches
     if _latent(params):
         # one latent row a position and layer: [RMSNorm(c) | rot(k_pe)]
         return {"pos": pos0, "kv": [
@@ -786,18 +1007,19 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     if slots is not None:
         with jax.named_scope("gather"):
             pos = pool_pos[slots]
-    latent = _latent(params)
+    latent, hybrid = _latent(params), _hybrid(params)
     if latent and window is not None:
         raise ValueError("latent attention has no windowed path")
-    use_rope = "pos" not in params and not latent
+    _windows_are_described(hybrid, window)
+    use_rope = "pos" not in params and not latent and not hybrid
     moe_capacity_factor = _drop_free(params, moe_k, moe_capacity_factor)
     auxs = []
     x = _embed(params, obs_t, compute_dtype)
     if use_rope:
         cos, sin = rope_table(pos if per_row else pos[None],
                               _wq_head_dim(params))
-    elif latent:
-        pass  # each latent block rotates by its own table
+    elif latent or hybrid:
+        pass  # a latent block rotates by its own table; no encoding at all
     elif per_row:
         # per-row table lookup; clip mirrors dynamic_index_in_dim's
         # out-of-bounds clamp on the scalar path (init_cache rejects
@@ -819,11 +1041,17 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     for name in cache:
         if name != "pos":
             new_cache[name] = []
+    if hybrid:
+        step = _HybridStep(cache, new_cache, obs_t.shape[0], pos, rows,
+                           slots, compute_dtype)
+        auxs.append(step.counts(params, valid))
     for i, blk in enumerate(params["blocks"]):
         if "mla" in blk:
             with jax.named_scope("mla"):
                 x = x + _mla_step(blk, cache["kv"][i], new_cache["kv"], x,
                                   pos, rows, slots, compute_dtype)
+        elif hybrid:
+            x = x + step.mix(i, blk, _ln_apply(blk["ln1"], x))
         else:
             with jax.named_scope("attn"):
                 h = _ln_apply(blk["ln1"], x)
@@ -875,6 +1103,89 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     return _head(params, x, compute_dtype), new_cache, auxs
 
 
+class _HybridStep:
+    """One decode step's walk over a model of mixed layer kinds: what the
+    layers hand each other (the last state-space layer's scan output,
+    the stepped rows of the last written keys and values) and where each
+    block's state goes.  ``pos`` is a scalar or one position a row."""
+
+    def __init__(self, cache, new_cache, b, pos, rows, slots, dtype):
+        self.cache, self.new, self.dtype = cache, new_cache, dtype
+        self.pos = jnp.broadcast_to(pos, (b,))
+        self.rows = jnp.arange(b) if rows is None else rows
+        self.slots = slots
+        self.memory = self.kv_rows = None
+
+    def counts(self, params, valid):
+        """``HYBRID_EVENTS``' device half over the ``valid`` rows (all by
+        default): the live positions of the rows stepped (the one being
+        written included), the rows, and the positions live in one
+        window ring."""
+        live = self.pos + 1
+        valid = jnp.ones_like(live, bool) if valid is None else valid
+        window = next((blk["diff"]["spec"].window for blk in params["blocks"]
+                       if "wk" in blk and blk["diff"]["spec"].window), 0)
+        return {"counts": jnp.stack([
+            jnp.sum(jnp.where(valid, live, 0)), jnp.sum(valid),
+            jnp.sum(jnp.where(valid, jnp.minimum(live, window), 0))])}
+
+    def _keep(self, i, names, leaves):
+        """This block's entries of the new cache: ``leaves`` under
+        ``names``, nothing under the cache's other names."""
+        for name in self.new:
+            if name != "pos":
+                self.new[name].append(None)
+        for name, leaf in zip(names, leaves):
+            self.new[name][i] = leaf
+
+    def mix(self, i, blk, h):
+        """Block ``i``'s mixer over its normed input ``h`` (B, d)."""
+        dtype = self.dtype
+        if "ssm" in blk:
+            with jax.named_scope("ssm"):
+                pools = [self.cache[name][i] for name in _RECURRENT]
+                _, tail_shape = mamba.state_shapes(blk["ssm"])
+                with jax.named_scope("gather"):
+                    state = [pool[self.rows] for pool in pools]
+                out, self.memory, *state = mamba.mix_step(
+                    blk["ssm"], h, state[0],
+                    state[1].reshape(-1, *tail_shape), dtype)
+                with jax.named_scope("scatter"):
+                    # the whole state of the stepped rows, and of no other
+                    self._keep(i, _RECURRENT, [
+                        pool.at[self.rows].set(new.reshape(
+                            -1, *pool.shape[1:]).astype(pool.dtype))
+                        for pool, new in zip(pools, state)])
+            return out
+        if "gmu" in blk:
+            self._keep(i, (), ())
+            with jax.named_scope("gmu"):
+                return mamba.gmu(blk["gmu"], h, self.memory, dtype)
+        with jax.named_scope("attn"):
+            q = diffattn.project_q(blk, h, dtype)
+            if "wk" not in blk:
+                self._keep(i, (), ())
+            else:
+                pools = self.cache["k"][i], self.cache["v"][i]
+                slot = self.pos % pools[0].shape[1]
+                with jax.named_scope("scatter"):
+                    pools = [pool.at[self.rows, slot].set(new.reshape(
+                        -1, pool.shape[-1]).astype(pool.dtype))
+                        for pool, new in zip(
+                            pools, diffattn.project_kv(blk, h, dtype))]
+                self._keep(i, ("k", "v"), pools)
+                if self.slots is not None:
+                    # write first, then read (see _decode); the cross
+                    # layers after this one read the same gathered rows
+                    with jax.named_scope("gather"):
+                        pools = [_pool_rows(pool, self.slots)
+                                 for pool in pools]
+                self.kv_rows = pools
+            with jax.named_scope(_diff_kind(blk)):
+                return diffattn.attend_one(blk, q, *self.kv_rows, self.pos,
+                                           dtype)
+
+
 def _mla_step(blk, pool, sink, x, pos, rows, slots, dtype):
     """A latent block's attention at one position a row: project, write
     the position's row into ``pool`` at its ring slot (the written pool
@@ -906,7 +1217,10 @@ def prefill(params, cache, prefix, rows=None, *,
     predictions ``(B, T0, ...)`` float32 (``last_only``: position T0's
     alone, ``(B, 1, ...)``), the cache holding the bytes serial decode
     would have written (k/v are rotated before the sink; a latent model
-    attends expanded and sinks its latent rows) with ``pos`` at T0.
+    attends expanded and sinks its latent rows; a state-space layer
+    scans on from the row's own recurrent state, which
+    :func:`rewind_rows` has zeroed, and writes the state after T0 whole)
+    with ``pos`` at T0.
 
     ``rows=None`` fills every row of the cache (which then has ``B`` of
     them: what :func:`rollout` does); ``rows`` ``(B,)`` names the rows of
@@ -918,46 +1232,88 @@ def prefill(params, cache, prefix, rows=None, *,
     slot (distinct, since at most ``C`` consecutive ones are kept).
     ``moe`` are the legacy expert layer's ``moe_*`` arguments, as
     :func:`apply` takes them."""
-    kvs = []
+    hybrid = _hybrid(params)
+    _windows_are_described(hybrid, window)
+    kvs, more = [], {}
+    t0 = prefix.shape[1]
+    if hybrid:
+        # the state-space layers go on from the rows' own state, which a
+        # rewind has zeroed: the prefill is T0 decode steps of a rewound row
+        more["state_in"] = {
+            name: [leaf if leaf is None or rows is None else leaf[rows]
+                   for leaf in cache[name]] for name in _RECURRENT}
+        if t0 % 32 == 0:
+            more["diff_attn_fn"] = _flash_diff_attn
     with jax.named_scope("forward"):
         preds, _ = _forward(
             params, prefix,
             lambda q, k, v: full_attention(q, k, v, causal=True,
                                            window=window),
-            compute_dtype, kv_sink=kvs, last_only=last_only, **moe)
-    names = ("kv",) if _latent(params) else ("k", "v")
-    t0 = prefix.shape[1]
-    ring = cache[names[0]][0].shape[1]
-    keep_n = min(t0, ring)
-    slots_ax = (jnp.arange(keep_n) + (t0 - keep_n)) % ring
+            compute_dtype, kv_sink=kvs, last_only=last_only, **more, **moe)
+    # each ring keeps the last positions that fit (one length per model,
+    # or a window layer's ring beside the full layer's)
+    rings = {}
+    for i, kept in enumerate(kvs):
+        for name in kept:
+            if name in _RECURRENT:
+                continue
+            ring = cache[name][i].shape[1]
+            if ring not in rings:
+                keep_n = min(t0, ring)
+                rings[ring] = keep_n, (
+                    jnp.arange(keep_n) + (t0 - keep_n)) % ring
     with jax.named_scope("scatter"):
         if rows is None:
             new = {"pos": jnp.full_like(cache["pos"], t0)}
         else:
             new = {"pos": cache["pos"].at[rows].set(t0)}
-        for name in names:
-            new[name] = []
+        for name in cache:
+            if name != "pos":
+                new[name] = list(cache[name])
         for i, kept in enumerate(kvs):
-            for name, t in zip(names, kept):
+            for name, t in kept.items():
                 pool = cache[name][i]
-                if rows is None:
-                    pool = pool.at[:, slots_ax].set(
-                        t[:, t0 - keep_n:].astype(pool.dtype))
+                if name in _RECURRENT:  # no positions: written whole
+                    pool = (t.astype(pool.dtype) if rows is None
+                            else pool.at[rows].set(t.astype(pool.dtype)))
                 else:
-                    for j in range(t.shape[0]):
-                        pool = pool.at[rows[j], slots_ax].set(
-                            t[j, t0 - keep_n:].astype(pool.dtype))
-                new[name].append(pool)
+                    keep_n, slots_ax = rings[pool.shape[1]]
+                    if rows is None:
+                        pool = pool.at[:, slots_ax].set(
+                            t[:, t0 - keep_n:].astype(pool.dtype))
+                    else:
+                        for j in range(t.shape[0]):
+                            pool = pool.at[rows[j], slots_ax].set(
+                                t[j, t0 - keep_n:].astype(pool.dtype))
+                new[name][i] = pool
     return preds, new
+
+
+def _flash_diff_attn(q, k, v, scale, window):
+    """The flash kernel under a differential block's two maps, at the
+    tile policy's choice for the sequence (a multiple of 32 long)."""
+    from blendjax.ops.flash_attention import flash_attention, flash_block_size
+
+    t = q.shape[1]
+    window = None if window is None or window >= t else window
+    block = flash_block_size(t, q.shape[-1], q.dtype, window)
+    return flash_attention(q, k, v, causal=True, scale=scale, block_q=block,
+                           block_kv=block, window=window)
 
 
 def rewind_rows(cache, rows):
     """``cache`` (per-row) with ``rows`` rewound to position 0, ready for
-    their next tenants.  Rewinding ``pos`` is sufficient: :func:`_attn_one`
-    masks by each slot's absolute position, so the stale k/v (or latent)
-    rows of the previous tenant sit at negative positions and never
-    attend."""
-    return {**cache, "pos": cache["pos"].at[rows].set(0)}
+    their next tenants.  For what positions index, rewinding ``pos`` is
+    sufficient: :func:`_attn_one` masks by each slot's absolute position,
+    so the stale k/v (or latent) rows of the previous tenant sit at
+    negative positions and never attend.  A recurrent state cannot be
+    masked: the rows' ``ssm_h`` and ``ssm_tail`` are zeroed."""
+    new = {**cache, "pos": cache["pos"].at[rows].set(0)}
+    for name in _RECURRENT:
+        if name in cache:
+            new[name] = [leaf if leaf is None else leaf.at[rows].set(0)
+                         for leaf in cache[name]]
+    return new
 
 
 def rollout(params, prefix, n_steps, compute_dtype=jnp.bfloat16,
@@ -981,7 +1337,7 @@ def rollout(params, prefix, n_steps, compute_dtype=jnp.bfloat16,
     (SURVEY.md §5); this completes the world-model workload the
     framework adds.
     """
-    if _latent(params):
+    if "table" in params["embed"]:
         raise ValueError(
             "rollout() feeds a model its own predictions; a token model "
             "answers with logits and nothing here samples an id from them "

@@ -302,6 +302,13 @@ MOE_EVENTS = ("serve_moe_assignments", "serve_moe_assignments_held",
               "serve_moe_experts_hit")
 
 
+#: the counts a step of a model of mixed layer kinds returns, in
+#: ``seqformer._HybridStep.counts``' order, and the one its resets make
+#: (rows whose recurrent state was zeroed); all in ``SERVE_EVENTS``
+HYBRID_EVENTS = ("serve_ctx_positions", "serve_rows_stepped",
+                 "serve_window_positions", "serve_state_resets")
+
+
 class SlotPoolLost(RuntimeError):
     """A donated call failed after it had taken the slot pool: the
     model holds a fresh, EMPTY pool and every lease on it is void (the
@@ -412,8 +419,17 @@ class SeqFormerModel:
             self.obs_dim = (emb["w"] if "w" in emb else emb["w_q"]).shape[0]
             self.obs_dtype = np.float32
         routed = any("route" in blk.get("moe", ()) for blk in params["blocks"])
+        # recurrent state in the pool: a reset zeroes it (and counts it)
+        self._recurrent = seqformer._hybrid(params)
         if window is not None and seqformer._latent(params):
             raise ValueError("latent attention has no windowed path")
+        if window is not None and self._recurrent:
+            raise ValueError("a model of mixed layer kinds takes its "
+                             "windows from its description")
+        # the names of the counts a step returns beside its reply
+        self._step_events = (MOE_EVENTS if routed else
+                             HYBRID_EVENTS[:3] if self._recurrent else ())
+        counted = bool(self._step_events)
         self._events = {}
         cdt = compute_dtype or jnp.float32
         self._cache_dtype = cache_dtype or cdt
@@ -445,11 +461,12 @@ class SeqFormerModel:
                     obs = obs[:, 0]
                 pred, cache, auxs = seqformer._decode(
                     params, cache, obs, compute_dtype=cdt, window=window,
-                    slots=idx, valid=(idx != pad) if routed else None,
+                    slots=idx, valid=(idx != pad) if counted else None,
                 )
-            if routed:
-                # the held-share layers' counts over the real rows, summed
-                # over layers: they ride the reply's fence
+            if counted:
+                # the model's counts over the real rows (the held-share
+                # layers', summed over layers; a hybrid step's): they ride
+                # the reply's fence
                 return (reply_row(pred),
                         sum(a["counts"] for a in auxs)), cache
             return reply_row(pred), cache
@@ -475,6 +492,9 @@ class SeqFormerModel:
         # observations — padding them would write fabricated positions
         # into the cache, so lengths are not bucketed)
         self._prefill = jax.jit(serve_prefill, donate_argnums=(1,))
+        # a rewind moves `pos` and zeroes what recurrent state there is,
+        # in place on the donated pool like every other call on it
+        self._rewind = jax.jit(seqformer.rewind_rows, donate_argnums=(0,))
 
     def _new_pool(self):
         from blendjax.models import seqformer
@@ -533,7 +553,7 @@ class SeqFormerModel:
             if self.pool_rebuilds != rebuilds:
                 raise  # that pool was written off already
             self._write_off(what, exc)
-        for name, n in zip(MOE_EVENTS, counts):
+        for name, n in zip(self._step_events, counts):
             self._events[name] = self._events.get(name, 0) + int(n)
         return pred
 
@@ -600,17 +620,18 @@ class SeqFormerModel:
         self.params = jax.tree.map(jnp.asarray, tree)
 
     def drain_events(self):
-        """Counts the model's steps made since the last call (the
-        routed layers' ``MOE_EVENTS``), for the server's counters."""
+        """Counts the model's steps and resets made since the last call
+        (the routed layers' ``MOE_EVENTS``, a hybrid model's
+        ``HYBRID_EVENTS``), for the server's counters."""
         events, self._events = self._events, {}
         return events
 
     def reset_rows(self, idx):
-        from blendjax.models import seqformer
-
         with span("serve.reset_rows"):
-            self._cache = seqformer.rewind_rows(
-                self._cache, self._jnp.asarray(idx))
+            self._cache = self._rewind(self._cache, self._jnp.asarray(idx))
+        if self._recurrent:
+            name = HYBRID_EVENTS[3]
+            self._events[name] = self._events.get(name, 0) + len(idx)
 
     def step_rows(self, idx, obs):
         """Dispatch one decode step of rows ``idx``; the :class:`_Reply`

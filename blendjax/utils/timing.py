@@ -182,6 +182,13 @@ REPLAY_EVENTS = (
 #: ``serve_fetch_wait_us`` — microseconds the server's thread was
 #: blocked fetching a launched tick's reply (waiting for the device):
 #: the one place it waits for it.
+#: ``serve_ctx_positions`` / ``serve_rows_stepped`` /
+#: ``serve_window_positions`` / ``serve_state_resets`` — a served model
+#: of mixed layer kinds (``HYBRID_EVENTS`` in blendjax/serve/server.py):
+#: over a tick's real rows, the positions live in each row's full-length
+#: K/V (the one being written included), the rows, and the positions
+#: live in one window ring (they come over with the reply's fetch); and
+#: the rows whose recurrent state a reset zeroed.
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
@@ -192,6 +199,8 @@ SERVE_EVENTS = (
     "serve_moe_assignments", "serve_moe_assignments_held",
     "serve_moe_experts_hit",
     "serve_ticks_overlapped", "serve_fetch_wait_us",
+    "serve_ctx_positions", "serve_rows_stepped", "serve_window_positions",
+    "serve_state_resets",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md

@@ -162,6 +162,102 @@ def test_latent_pool_is_served_in_place_on_the_chip(one_chip, what, n):
         assert rows_bytes * 20 < n * 2048 * 64 * 320 * 2
 
 
+# the hybrid cell's widths (chipbench/configs/phi4miniflash_serve_bf16.json)
+# over the 32-layer rule at 8 layers: two periods of state-space and
+# window, then state-space, full, gated memory, cross
+HYBRID_WIDTHS = dict(
+    hidden_size=2560, num_attention_heads=40, num_key_value_heads=20,
+    intermediate_size=10240, num_hidden_layers=8, mb_per_layer=2,
+    sliding_window=512, layer_norm_eps=1e-5, vocab_size=200064,
+    mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160)
+
+
+@pytest.mark.parametrize("what,n", [("step", 64), ("prefill", 512)])
+def test_hybrid_pool_is_served_in_place_on_the_chip(one_chip, what, n,
+                                                    monkeypatch):
+    """A model of mixed layer kinds at its published widths: the donated
+    pool (window rings, the one full-length K/V, float32 recurrent state
+    beside bfloat16 tails) is aliased to the output, no leaf of it is
+    copied or re-laid whole (a positions-major `(S, C, 10, 128)` K/V or
+    a `(S, 3, 5120)` tail was, and a pair-major `(S, 10, C, 128)` K/V:
+    `init_cache` keeps every leaf flat behind its row or position), nor
+    are the stepped rows once gathered (seen as `(B, C, 10, 128)` they
+    were, twice a read: `diffattn.attend_one` reads each pair as whole
+    lanes of the flat rows), the rows are read by pieces, and the
+    prefill's attention is the flash kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    import importlib
+
+    from blendjax.models import seqformer
+    from blendjax.serve.server import SeqFormerModel
+
+    flash_attention = importlib.import_module("blendjax.ops.flash_attention")
+    # this process's backend is the CPU, where the kernels interpret: the
+    # chip compiles them (the guide's "steer such code in the test")
+    monkeypatch.setattr(flash_attention, "resolve_interpret",
+                        lambda interpret=None: False)
+    assert seqformer.hybrid_layer_kinds(HYBRID_WIDTHS) == [
+        "ssm", "window", "ssm", "window", "ssm", "full", "gmu", "cross"]
+    tiny_widths = dict(
+        HYBRID_WIDTHS, hidden_size=64, num_attention_heads=8,
+        num_key_value_heads=4, intermediate_size=128, sliding_window=8,
+        vocab_size=96, mamba_d_state=4, mamba_dt_rank=4)
+    tiny = SeqFormerModel(
+        seqformer.init_hybrid_model(jax.random.PRNGKey(0), tiny_widths,
+                                    dtype=jnp.bfloat16),
+        slots=2, length=16, compute_dtype=jnp.bfloat16,
+        cache_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: seqformer.init_hybrid_model(
+        jax.random.PRNGKey(0), HYBRID_WIDTHS, dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: seqformer.init_cache(
+        params, TOKEN_POOL["slots"] + 1, dtype=jnp.bfloat16,
+        length=TOKEN_POOL["length"], per_row=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    fn = tiny._step if what == "step" else tiny._prefill
+    compiled = fn.lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((n if what == "step" else 1,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32, sharding=one_chip),
+    ).compile()
+    leaves = [leaf for leaf in jax.tree.leaves(cache) if leaf.ndim > 1]
+    shapes = {(leaf.shape, leaf.dtype.name) for leaf in leaves}
+    assert shapes == {
+        ((129, 512, 1280), "bfloat16"), ((129, 2048, 1280), "bfloat16"),
+        ((129, 16, 5120), "float32"), ((129, 15360), "bfloat16")}
+    pool_bytes = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                     for leaf in leaves)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    text = compiled.as_text()
+    assert f"jit_serve_{what}" in text
+    assert not [line for line in text.splitlines()
+                if re.search(r" copy\(", line)
+                and re.search(r"= \w+\[129,", line)]
+    assert "mini-gather-slice" not in text
+    rows_shape = r"\[%d,(512|2048),(1280|10,128)\]|\[%d,10,(512|2048),128\]" % (
+        n, n)
+    if what == "step":
+        # the gathered rows go to the products as they come: no `copy` or
+        # `reshape` of them
+        assert not [line for line in text.splitlines()
+                    if re.search(r" (copy|reshape)\(", line)
+                    and re.search(rows_shape, line.split(" = ")[1][:60])]
+        # about the gathered rows of the full-length K/V (gathered once:
+        # the cross layer reads them again) and of one ring, twice over;
+        # eight readers each with rows of their own would be 5.4 GB
+        rows = 2 * n * (2048 + 512) * 1280 * 2
+        assert mem.temp_size_in_bytes < 2 * rows
+    else:
+        assert "flash_fwd" in text and "tpu_custom_call" in text
+
+
 # the train cell (chipbench/configs/seqformer_wm100m_train_bf16.json: batch
 # 64 x 512, 8 heads of 128, bfloat16 compute, block 'auto'), the long
 # sequence of chip_smoke.py's kernel leg, and the widest float32 head the

@@ -339,6 +339,51 @@ def _token_prefill():
         jnp.ones((3, 1), jnp.int32))
 
 
+TINY_HYBRID = dict(
+    hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+    intermediate_size=64, num_hidden_layers=8, mb_per_layer=2,
+    sliding_window=4, layer_norm_eps=1e-5, vocab_size=64, mamba_d_state=4,
+    mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=2)
+
+
+def _tiny_hybrid_model():
+    return SeqFormerModel(
+        seqformer.init_hybrid_model(jax.random.PRNGKey(0), TINY_HYBRID),
+        slots=2, length=16)
+
+
+def _hybrid_step():
+    model = _tiny_hybrid_model()
+    return model._step.lower(
+        model.params, model._cache, jnp.zeros(2, jnp.int32),
+        jnp.ones((2, 1), jnp.int32))
+
+
+def _hybrid_prefill():
+    model = _tiny_hybrid_model()
+    return model._prefill.lower(
+        model.params, model._cache, jnp.zeros(1, jnp.int32),
+        jnp.ones((3, 1), jnp.int32))
+
+
+def test_hybrid_model_counters_move_in_a_served_episode():
+    from blendjax.serve.server import HYBRID_EVENTS
+
+    for name in HYBRID_EVENTS:
+        assert name in SERVE_EVENTS
+        assert TelemetryHub().scrape()["counters"][name] == 0
+    model = _tiny_hybrid_model()
+    model.reset_rows(np.asarray([1]))
+    model.prefill_rows(np.asarray([1]), np.ones((5, 1), np.int32))
+    for _ in range(3):  # positions 5, 6, 7; the pad row beside them
+        np.asarray(model.step_rows(np.asarray([1, model.pad_slot]),
+                                   np.ones((2, 1), np.int32)))
+    assert model.drain_events() == {
+        "serve_ctx_positions": 6 + 7 + 8, "serve_rows_stepped": 3,
+        "serve_window_positions": 3 * 4, "serve_state_resets": 1}
+    assert model.drain_events() == {}
+
+
 @pytest.mark.parametrize("name", [
     "serve_moe_assignments", "serve_moe_assignments_held",
     "serve_moe_experts_hit"])
@@ -354,6 +399,12 @@ def test_routed_model_counters_are_in_the_vocabulary(name):
     (_token_prefill, "serve_prefill",
      ("forward", "mla", "expand", "scatter", "moe", "route", "experts",
       "shared", "mlp", "ln", "head")),
+    (_hybrid_step, "serve_step",
+     ("decode", "ssm", "conv", "update", "gmu", "attn", "diff", "window",
+      "full", "cross", "scatter", "gather", "mlp", "ln", "head")),
+    (_hybrid_prefill, "serve_prefill",
+     ("forward", "ssm", "conv", "scan", "gmu", "attn", "diff", "window",
+      "full", "cross", "scatter", "mlp", "ln", "head")),
     (_train_step, "train_step",
      ("loss", "optimizer", "attn", "mlp", "ln")),
     (_serve_step, "serve_step",
