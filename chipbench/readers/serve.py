@@ -41,8 +41,11 @@ def queue_wait_ms(obs, ctx):
 
 
 def compute_ms(obs, ctx):
-    """Server ``StageTimer`` ``compute``, mean per tick (host clock around a
-    tick fenced by ``np.asarray``)."""
+    """Server ``StageTimer`` ``compute``, mean per tick: the host's time
+    inside the model call, a tick's dispatch plus the fetch of its reply
+    (``np.asarray``), added once where the tick is retired.  Since one tick
+    is kept in flight, what ran between the two is not in it, so this is
+    not the device's time for a tick."""
     return _stage_mean_ms(obs, "compute")
 
 
@@ -53,6 +56,19 @@ def batch_rows_mean(obs, ctx):
     if not batches or not len(obs.get("step_s", ())):
         return None
     return len(obs["step_s"]) / batches
+
+
+def batch_pad_pct(obs, ctx):
+    """Pad rows over rows computed in the window: a tick of ``n`` real rows
+    runs the least bucket that holds them, and ``serve_batch_pad`` counts
+    the ``bucket - n`` rows that answer nobody (a server that padded
+    nothing counted nothing: that reads 0, which is a reading)."""
+    events = obs.get("events") or {}
+    real = len(obs.get("step_s", ()))
+    if not events.get("serve_batches") or not real:
+        return None
+    pad = events.get("serve_batch_pad", 0)
+    return 100.0 * pad / (real + pad)
 
 
 def decode_mfu_pct(obs, ctx):

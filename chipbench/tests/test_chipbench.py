@@ -117,8 +117,15 @@ def test_driver_end_to_end_prints_the_contracts_keys(kind, trace):
         assert not any(n.startswith("device.idle_pct") for n in names)
     else:
         assert "setup_s" in names and len(names) >= 2
-    for m in line["metrics"].values():
-        assert m["value"] > 0 and m["unit"]
+    # counts of what need not happen in 1.5 s at three clients (the queue
+    # found empty, a launch behind a tick in flight, a bucket missed): 0 is
+    # a reading there, and on the chip all three are above it
+    may_read_zero = {"serve.idle_wait_ms", "serve.tick_overlap_pct",
+                     "serve.batch_pad_pct"}
+    for name, m in line["metrics"].items():
+        assert m["unit"]
+        assert m["value"] > 0 or (name in may_read_zero
+                                  and m["value"] == 0), name
 
 
 @pytest.mark.parametrize("kind,fault,fails", [
