@@ -50,7 +50,18 @@ differential attention (:mod:`blendjax.models.diffattn`) -- over its own
 keys and values, a ring of `spec.window` positions or the full length,
 or, in a block without `wk`/`wv`, over the keys and values of the last
 block that made any (a *cross* layer: eight layers then read one
-buffer).  Such a model has no positional encoding.
+buffer).  Such a model has no positional encoding.  A `gdn` entry is
+gated delta-rule linear attention (:mod:`blendjax.models.deltanet`: a
+float32 matrix state a head and three convolution tails per sequence);
+`wq`/`wk`/`wv`/`wo` without a `diff` entry, inside such a model, are plain
+softmax attention over a full-length K/V, with a `q_norm` / `k_norm`
+RMSNorm over the whole projection where the block holds them.  The kinds
+are then the configuration's own ``layer_types``
+(:func:`init_linear_hybrid_model`).
+
+**The norm's place is read off the block too**: `ln1` / `ln2` norm a
+sublayer's input (``x + f(ln(x))``), `post_ln1` / `post_ln2` its output
+(``x + ln(f(x))``, the Olmo 2 convention).
 """
 
 from __future__ import annotations
@@ -61,7 +72,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from blendjax.models import diffattn, mamba, mla
+from blendjax.models import deltanet, diffattn, mamba, mla
 from blendjax.models.layers import (
     apply_rope,
     apply_rope_rows,
@@ -132,6 +143,18 @@ def _ln_apply(p, x):
     return (out * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def _pre(blk, name, x):
+    """A sublayer's input: normed where the block norms before it."""
+    return _ln_apply(blk[name], x) if name in blk else x
+
+
+def _post(blk, name, y):
+    """A sublayer's output: normed where the block norms after it
+    (``post_ln1`` / ``post_ln2`` in place of ``ln1`` / ``ln2``)."""
+    name = "post_" + name
+    return _ln_apply(blk[name], y) if name in blk else y
+
+
 def _moe_init(key, n_experts, d, d_ff):
     kg, k1, k2 = jax.random.split(key, 3)
     s1 = jnp.sqrt(2.0 / d)
@@ -160,21 +183,37 @@ def _latent(params):
     return "mla" in params["blocks"][0]
 
 
+#: the recurrent mixers, by the block entry that holds one: the module
+#: (``state_shapes``, ``mix_sequence``, ``mix_step``) and the cache entries
+#: it keeps, in ``state_shapes``' order: a float32 state, then what the
+#: cache's dtype keeps (convolution tails).  No position indexes them:
+#: they are written whole, and zeroed (not masked) when a row is rewound.
+_MIXERS = {
+    "ssm": (mamba, ("ssm_h", "ssm_tail")),
+    "gdn": (deltanet, ("gdn_s", "gdn_tail_q", "gdn_tail_k", "gdn_tail_v")),
+}
+_RECURRENT = tuple(name for _, names in _MIXERS.values() for name in names)
+
+
+def _mixer(blk):
+    """``(entry, module, cache names)`` of the block's recurrent mixer,
+    or None."""
+    for kind, (module, names) in _MIXERS.items():
+        if kind in blk:
+            return kind, module, names
+    return None
+
+
 def _hybrid(params):
-    """Whether the model mixes layer kinds (state-space blocks among
-    them): its cache then holds recurrent state beside keys and values."""
-    return any("ssm" in blk for blk in params["blocks"])
+    """Whether the model mixes layer kinds (recurrent blocks among them):
+    its cache then holds recurrent state beside keys and values."""
+    return any(_mixer(blk) for blk in params["blocks"])
 
 
 def _windows_are_described(hybrid, window):
     if hybrid and window is not None:
         raise ValueError("a model of mixed layer kinds takes its windows "
                          "from its description, layer by layer")
-
-
-#: the cache entries that no position indexes: written whole, and zeroed
-#: (not masked) when a row is rewound
-_RECURRENT = ("ssm_h", "ssm_tail")
 
 
 def _embed(params, obs, dtype):
@@ -210,6 +249,54 @@ def _diff_kind(blk):
     return "full" if blk["diff"]["spec"].window is None else "window"
 
 
+def _plain_qkv(blk, h, dtype):
+    """The bias-free projections of a plain attention block inside a
+    model of mixed kinds: normed input ``h`` (..., d) -> ``(q (..., H,
+    Dh), k, v (..., Hkv, Dh))``, ``q`` and ``k`` through the block's
+    RMSNorm over the whole projection where it holds one."""
+    q, k, v = (jnp.einsum("...d,dhk->...hk", h.astype(dtype),
+                          blk[n].astype(dtype)) for n in ("wq", "wk", "wv"))
+
+    def normed(name, t):
+        if name not in blk:
+            return t
+        return _ln_apply(blk[name],
+                         t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
+
+    return normed("q_norm", q), normed("k_norm", k), v
+
+
+def _plain_out(blk, a, dtype):
+    return jnp.einsum("...hk,hkd->...d", a.astype(dtype),
+                      blk["wo"].astype(dtype))
+
+
+def _attend_rows(q, kc, vc, pos, dtype):
+    """One query a row, ``q`` (B, H, Dh), over that row's cached keys and
+    values, flat ``(B, C, Hkv * Dh)`` rows of a ring written at ``p % C``
+    and masked by the absolute position a slot holds (as
+    :func:`_attn_one`), at position ``pos`` (B,) -> (B, H, Dh) float32.
+    Each K/V head is read where it lies, as the whole-lane columns of the
+    rows (``diffattn.attend_one`` says why)."""
+    b, c, _ = kc.shape
+    h, dh = q.shape[1:]
+    n_kv = kc.shape[-1] // dh
+    qs = q.reshape(b, n_kv, h // n_kv, dh).astype(dtype)
+    p_col = pos[:, None]
+    keep = p_col - ((p_col - jnp.arange(c)[None]) % c) >= 0
+    outs = []
+    for i in range(n_kv):
+        cols = slice(i * dh, (i + 1) * dh)
+        s = jnp.einsum("bme,bce->bmc", qs[:, i], kc[..., cols].astype(dtype),
+                       preferred_element_type=jnp.float32)
+        w = jax.nn.softmax(jnp.where(keep[:, None], s * dh ** -0.5, -1e30),
+                           axis=-1)
+        outs.append(jnp.einsum(
+            "bmc,bce->bme", w.astype(dtype), vc[..., cols].astype(dtype),
+            preferred_element_type=jnp.float32))
+    return jnp.stack(outs, axis=1).reshape(b, h, dh)
+
+
 def _held_moe(blk, h, dtype, auxs, valid=None):
     """The held-share expert layer over ``h`` of any leading shape; its
     counts go to ``auxs``."""
@@ -229,15 +316,16 @@ def _ffn(blk, x, dtype, auxs, valid, moe_impl, moe_k, moe_capacity_factor,
     routed evaluation's aux goes to ``auxs`` too), the gated MLP or the
     GELU MLP.  The one place the choice is made."""
     if "moe" in blk and "route" in blk["moe"]:
-        return x + _held_moe(blk, _ln_apply(blk["ln2"], x), dtype, auxs,
-                             valid)
+        return x + _post(blk, "ln2", _held_moe(
+            blk, _pre(blk, "ln2", x), dtype, auxs, valid))
     with jax.named_scope("mlp"):
-        h = _ln_apply(blk["ln2"], x)
+        h = _pre(blk, "ln2", x)
         if "moe" not in blk:
             if "gate" in blk["mlp"]:
-                return x + gated_mlp(blk["mlp"], h, dtype)
+                return x + _post(blk, "ln2", gated_mlp(blk["mlp"], h, dtype))
             h = gelu(_dense_mq(blk["mlp"]["fc"], h, dtype))
-            return x + _dense_mq(blk["mlp"]["proj"], h, dtype)
+            return x + _post(blk, "ln2",
+                             _dense_mq(blk["mlp"]["proj"], h, dtype))
         rows = h.ndim == 2
         if rows:
             h = h[:, None]  # the legacy layers take (B, T, d)
@@ -250,7 +338,7 @@ def _ffn(blk, x, dtype, auxs, valid, moe_impl, moe_k, moe_capacity_factor,
             y = _moe_apply(blk["moe"], h, dtype)
         else:
             raise ValueError(f"unknown moe_impl {moe_impl!r}")
-        return x + (y[:, 0] if rows else y)
+        return x + _post(blk, "ln2", y[:, 0] if rows else y)
 
 
 def _drop_free(params, moe_k, capacity_factor):
@@ -285,8 +373,16 @@ def token_model_specs(config, first=0):
     )
 
 
+#: a published ``layer_types`` entry -> the kind it is here
+_LAYER_TYPES = {"linear_attention": "gdn", "full_attention": "full"}
+
+
 def hybrid_layer_kinds(config):
-    """The kind of every layer of a decoder-hybrid-decoder model
+    """The kind of every layer of a model of mixed layer kinds.  Where
+    the configuration publishes ``layer_types`` they are its first
+    ``num_hidden_layers`` entries (``"gdn"`` for ``linear_attention``,
+    ``"full"`` for ``full_attention``).  Otherwise the rule of a
+    decoder-hybrid-decoder model
     (arXiv:2507.06607), from ``num_hidden_layers`` and ``mb_per_layer``:
     in the first half every ``mb_per_layer``-th layer is ``"ssm"`` and
     the others ``"window"`` attention; the second half opens with one
@@ -295,6 +391,12 @@ def hybrid_layer_kinds(config):
     cache), then every ``mb_per_layer``-th layer is a ``"gmu"`` over that
     memory and the others ``"cross"`` attention over those keys and
     values."""
+    if "layer_types" in config:
+        types = config["layer_types"]
+        if len(types) < config["num_hidden_layers"]:
+            raise ValueError(f"{len(types)} layer_types for "
+                             f"{config['num_hidden_layers']} layers")
+        return [_LAYER_TYPES[t] for t in types[:config["num_hidden_layers"]]]
     n, every = config["num_hidden_layers"], config["mb_per_layer"]
     half = n // 2
     kinds = []
@@ -370,11 +472,85 @@ def init_hybrid_model(key, config, dtype=jnp.float32):
         "blocks": blocks, "ln_f": norm()}, c)
 
 
+def _describe_linear_hybrid(arrays, config):
+    """The static entries of a model whose ``layer_types`` mix linear and
+    full attention: each norm's epsilon and each linear block's
+    :class:`deltanet.GdnSpec`."""
+    eps = float(config["rms_norm_eps"])
+    kinds = hybrid_layer_kinds(config)
+    if len(kinds) != len(arrays["blocks"]):
+        raise ValueError(f"{len(arrays['blocks'])} blocks for {len(kinds)} "
+                         "layers")
+    arrays["ln_f"]["spec"] = NormSpec(eps)
+    for layer, (kind, blk) in enumerate(zip(kinds, arrays["blocks"])):
+        if ("gdn" if "gdn" in blk else "full") != kind:
+            raise ValueError(f"layer {layer} is {kind!r} by the "
+                             f"configuration and holds {sorted(blk)}")
+        for name in ("post_ln1", "post_ln2", "q_norm", "k_norm"):
+            if name in blk:
+                blk[name]["spec"] = NormSpec(eps)
+        if kind == "gdn":
+            blk["gdn"]["spec"] = deltanet.GdnSpec(
+                bool(config["linear_allow_neg_eigval"]), eps)
+    return arrays
+
+
+def init_linear_hybrid_model(key, config, dtype=jnp.float32):
+    """A token model of gated delta-rule linear attention beside full
+    attention, from the published keys ``hidden_size, intermediate_size,
+    num_hidden_layers, num_attention_heads, num_key_value_heads,
+    layer_types, linear_num_key_heads, linear_num_value_heads,
+    linear_key_head_dim, linear_value_head_dim, linear_conv_kernel_dim,
+    linear_allow_neg_eigval, rms_norm_eps, vocab_size,
+    tie_word_embeddings``: RMSNorm after every sublayer and before the
+    head, gated SiLU feed-forwards, a q/k norm over the whole projection
+    in the full layers, no positional encoding, no biases."""
+    c = config
+    d, heads = c["hidden_size"], c["num_attention_heads"]
+    if c["linear_num_key_heads"] != c["linear_num_value_heads"]:
+        raise ValueError("linear key and value heads differ in number")
+    kinds = hybrid_layer_kinds(c)
+    ke, kh, *kb = jax.random.split(key, 2 + len(kinds))
+
+    def norm(width):
+        return {"scale": jnp.ones((width,), dtype)}
+
+    blocks = []
+    for kind, k in zip(kinds, kb):
+        km, kf = jax.random.split(k)
+        blk = {"post_ln1": norm(d), "post_ln2": norm(d),
+               "mlp": gated_mlp_init(kf, d, c["intermediate_size"], dtype)}
+        if kind == "gdn":
+            blk["gdn"] = deltanet.init(
+                km, d, c["linear_num_key_heads"], c["linear_key_head_dim"],
+                c["linear_value_head_dim"], c["linear_conv_kernel_dim"],
+                dtype=dtype)
+        else:
+            h_kv, dh = c["num_key_value_heads"], d // heads
+            kq, kk, kv, ko = jax.random.split(km, 4)
+            blk.update(
+                wq=scaled_normal(kq, (d, heads, dh), d, dtype),
+                wk=scaled_normal(kk, (d, h_kv, dh), d, dtype),
+                wv=scaled_normal(kv, (d, h_kv, dh), d, dtype),
+                wo=scaled_normal(ko, (heads, dh, d), heads * dh, dtype),
+                q_norm=norm(heads * dh), k_norm=norm(h_kv * dh))
+        blocks.append(blk)
+    model = {"embed": {"table": scaled_normal(ke, (c["vocab_size"], d), 1.0,
+                                              dtype)},
+             "blocks": blocks, "ln_f": norm(d)}
+    if not c.get("tie_word_embeddings"):
+        model["head"] = {"w": scaled_normal(kh, (d, c["vocab_size"]), d,
+                                            dtype)}
+    return _describe_linear_hybrid(model, c)
+
+
 def describe_token_model(arrays, config, first=0):
     """Weights made elsewhere in this layout (arrays only) become a model:
     every block is given its static entries.  Returns ``arrays``.  A
-    configuration with ``mb_per_layer`` describes a model of mixed layer
-    kinds (:func:`hybrid_layer_kinds`)."""
+    configuration with ``layer_types`` or ``mb_per_layer`` describes a
+    model of mixed layer kinds (:func:`hybrid_layer_kinds`)."""
+    if "layer_types" in config:
+        return _describe_linear_hybrid(arrays, config)
     if "mb_per_layer" in config:
         return _describe_hybrid(arrays, config)
     mla_spec, route = token_model_specs(config, first)
@@ -528,14 +704,16 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
 
     ``kv_sink`` (a list) collects, block by block, what the block gives
     the cache under the cache's own names (``k`` and ``v``, a latent
-    block's ``kv`` rows, a state-space block's ``ssm_h`` and ``ssm_tail``
-    after the last position; nothing for a block that keeps nothing) —
+    block's ``kv`` rows, a recurrent block's state after the last position
+    under :data:`_MIXERS`' names, the tails flat; nothing for a block
+    that keeps nothing) —
     :func:`rollout`'s vectorized prefill fills its caches from one
     teacher-forced pass instead of t0 serial decode steps.
-    ``state_in`` (``{"ssm_h": [...], "ssm_tail": [...]}`` by block) is
-    the recurrent state the state-space blocks start from, zeros by
+    ``state_in`` (``{name: [...]}`` by block, under the same names) is
+    the recurrent state the recurrent blocks start from, zeros by
     default.  ``diff_attn_fn(q, k, v, scale, window)`` is the causal
-    attention under the differential blocks (plain by default).
+    attention under the attention blocks of a model of mixed kinds
+    (plain by default).
     ``last_only`` answers for the
     last position alone (a prefill over a vocabulary wants no other
     logits).  The ``moe_*`` arguments choose the evaluation of the
@@ -552,8 +730,8 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
     t = obs.shape[1]
     auxs = []
     sink = [] if kv_sink is None else kv_sink
-    use_rope = ("pos" not in params and not _latent(params)
-                and not _hybrid(params))
+    hybrid = _hybrid(params)
+    use_rope = "pos" not in params and not _latent(params) and not hybrid
     x = _embed(params, obs, compute_dtype)
     if use_rope:
         dh = _wq_head_dim(params)
@@ -562,39 +740,46 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
         x = x + params["pos"][:t].astype(compute_dtype)[None]
     memory = shared_kv = None
     for i, blk in enumerate(params["blocks"]):
+        mixer = _mixer(blk)
         if "mla" in blk:
             with jax.named_scope("mla"):
-                h = _ln_apply(blk["ln1"], x)
+                h = _pre(blk, "ln1", x)
                 q_nope, q_pe, rows = mla.project(
                     blk["mla"], h, *mla.rope(blk["mla"], jnp.arange(t)),
                     compute_dtype)
                 sink.append({"kv": rows})
-                x = x + mla.attend_expanded(blk["mla"], q_nope, q_pe, rows,
-                                            compute_dtype)
-        elif "ssm" in blk:
-            with jax.named_scope("ssm"):
-                h_shape, tail_shape = mamba.state_shapes(blk["ssm"])
+                x = x + _post(blk, "ln1", mla.attend_expanded(
+                    blk["mla"], q_nope, q_pe, rows, compute_dtype))
+        elif mixer:
+            kind, module, names = mixer
+            with jax.named_scope(kind):
+                shapes = module.state_shapes(blk[kind])
                 if state_in is None:
-                    state = (jnp.zeros((x.shape[0], *h_shape), jnp.float32),
-                             jnp.zeros((x.shape[0], *tail_shape),
-                                       compute_dtype))
+                    state = [jnp.zeros((x.shape[0], *shape),
+                                       compute_dtype if j else jnp.float32)
+                             for j, shape in enumerate(shapes)]
                 else:
-                    state = (state_in["ssm_h"][i],
-                             state_in["ssm_tail"][i].reshape(-1, *tail_shape))
-                out, memory, h_last, tail = mamba.mix_sequence(
-                    blk["ssm"], _ln_apply(blk["ln1"], x), *state,
-                    compute_dtype)
-                sink.append({"ssm_h": h_last,
-                             "ssm_tail": tail.reshape(tail.shape[0], -1)})
-                x = x + out
+                    state = [state_in[name][i].reshape(-1, *shape)
+                             for name, shape in zip(names, shapes)]
+                out, *state = module.mix_sequence(
+                    blk[kind], _pre(blk, "ln1", x), *state, compute_dtype)
+                if kind == "ssm":  # its scan output, for the memory units
+                    memory, *state = state
+                # the float32 state as its module shapes it, the tails
+                # flat, as the cache keeps them
+                sink.append({name: part if j == 0
+                             else part.reshape(part.shape[0], -1)
+                             for j, (name, part) in enumerate(
+                                 zip(names, state))})
+                x = x + _post(blk, "ln1", out)
         elif "gmu" in blk:
             with jax.named_scope("gmu"):
                 sink.append({})
-                x = x + mamba.gmu(blk["gmu"], _ln_apply(blk["ln1"], x),
-                                  memory, compute_dtype)
+                x = x + _post(blk, "ln1", mamba.gmu(
+                    blk["gmu"], _pre(blk, "ln1", x), memory, compute_dtype))
         elif "diff" in blk:
             with jax.named_scope("attn"):
-                h = _ln_apply(blk["ln1"], x)
+                h = _pre(blk, "ln1", x)
                 q = diffattn.project_q(blk, h, compute_dtype)
                 kept = {}
                 if "wk" in blk:
@@ -603,11 +788,19 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
                             for name, kv in zip(("k", "v"), shared_kv)}
                 sink.append(kept)
                 with jax.named_scope(_diff_kind(blk)):
-                    x = x + diffattn.attend(blk, q, *shared_kv,
-                                            compute_dtype, diff_attn_fn)
+                    x = x + _post(blk, "ln1", diffattn.attend(
+                        blk, q, *shared_kv, compute_dtype, diff_attn_fn))
+        elif hybrid:
+            with jax.named_scope("attn"):
+                q, k, v = _plain_qkv(blk, _pre(blk, "ln1", x), compute_dtype)
+                sink.append({"k": k.reshape(*k.shape[:2], -1),
+                             "v": v.reshape(*v.shape[:2], -1)})
+                with jax.named_scope("full"):
+                    a = diff_attn_fn(q, k, v, q.shape[-1] ** -0.5, None)
+                x = x + _post(blk, "ln1", _plain_out(blk, a, compute_dtype))
         else:
             with jax.named_scope("attn"):
-                h = _ln_apply(blk["ln1"], x)
+                h = _pre(blk, "ln1", x)
                 q, k, v = (
                     _proj_mq(blk[n], h, "btd,dhk->bthk", compute_dtype)
                     for n in ("wq", "wk", "wv")
@@ -621,8 +814,8 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
                     k = apply_rope(k, cos, sin)
                 sink.append({"k": k, "v": v})
                 a = attn_fn(q, k, v)
-                x = x + _proj_mq(blk["wo"], a, "bthk,hkd->btd",
-                                 compute_dtype)
+                x = x + _post(blk, "ln1", _proj_mq(
+                    blk["wo"], a, "bthk,hkd->btd", compute_dtype))
         x = _ffn(blk, x, compute_dtype, auxs, None, moe_impl, moe_k,
                  moe_capacity_factor, moe_dispatch)
     if last_only:
@@ -787,10 +980,15 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
     with ``None`` where the block keeps nothing of that kind: a ring of
     ``window`` positions of keys and of values ``(B, window, Hkv * Dh)``
     per window layer, one full-length ``(B, L, Hkv * Dh)`` pair for the
-    full layer (which the cross layers read), and per state-space layer
+    full layer (which the cross layers read), and per recurrent layer
+    its module's state under :data:`_MIXERS`' names: a state-space layer's
     ``ssm_h`` ``(B, d_state, d_inner)`` float32 and ``ssm_tail`` ``(B,
-    (d_conv - 1) * d_inner)``.  Every leaf is flat behind its row or
-    position, which is the layout the TPU compiler keeps as it is handed
+    (d_conv - 1) * d_inner)``; a linear-attention layer's ``gdn_s`` ``(B,
+    pieces, H / pieces, dv, dk)`` float32 (the heads cut in the fewest
+    pieces that :func:`_pool_rows` gathers without the compiler slicing
+    the whole pool) and three flat tails.  A plain attention layer of
+    such a model keeps a full-length pair.  Every leaf is flat behind its
+    row or position, which is the layout the TPU compiler keeps as it is handed
     it (compiled for a described v5e; ``tests/test_tpu_compile.py``):
     a minor pair of axes like ``(Hkv / 2, 2 Dh)`` or ``(d_conv - 1,
     d_inner)``, whose second-minor axis is no whole tile, was re-laid
@@ -833,17 +1031,24 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
         if per_row else jnp.asarray(0, jnp.int32)
     )
     if _hybrid(params):
-        caches = {"pos": pos0, "k": [], "v": [], "ssm_h": [], "ssm_tail": []}
+        held = ("k", "v") + tuple(
+            name for kind, (_, names) in _MIXERS.items()
+            if any(kind in blk for blk in params["blocks"]) for name in names)
+        caches = {"pos": pos0, **{name: [] for name in held}}
         for blk in params["blocks"]:
-            own = dict.fromkeys(("k", "v") + _RECURRENT)
-            if "ssm" in blk:
-                h_shape, tail_shape = mamba.state_shapes(blk["ssm"])
-                own["ssm_h"] = jnp.zeros((batch_size, *h_shape), jnp.float32)
-                own["ssm_tail"] = jnp.zeros(
-                    (batch_size, math.prod(tail_shape)), dtype)
+            own = dict.fromkeys(held)
+            mixer = _mixer(blk)
+            if mixer:
+                kind, module, names = mixer
+                for j, (name, shape) in enumerate(zip(
+                        names, module.state_shapes(blk[kind]))):
+                    own[name] = jnp.zeros(
+                        (batch_size, *_state_leaf(j, shape)),
+                        dtype if j else jnp.float32)
             elif "wk" in blk:
                 _, h_kv, dh = blk["wk"].shape
-                ring = min(blk["diff"]["spec"].window or length, length)
+                window = blk["diff"]["spec"].window if "diff" in blk else None
+                ring = min(window or length, length)
                 for name in ("k", "v"):
                     own[name] = jnp.zeros((batch_size, ring, h_kv * dh),
                                           dtype)
@@ -926,6 +1131,13 @@ def _attn_one(q, kc, vc, pos, scale, window=None):
 _GATHER_SLICE_ELEMS = 1 << 18
 
 
+def _gather_pieces(c, per_pos):
+    """The fewest equal pieces of ``c`` positions of ``per_pos`` elements
+    each that stay under :data:`_GATHER_SLICE_ELEMS` a piece."""
+    return next((n for n in range(1, c + 1)
+                 if c % n == 0 and c // n * per_pos <= _GATHER_SLICE_ELEMS), c)
+
+
 def _pool_rows(pool, slots):
     """``pool[slots]`` for one ``(S, C, Hkv, Dh)`` cache tensor (or a
     latent ``(S, C, W)`` one), gathered as pieces of ``C / n`` positions
@@ -933,14 +1145,33 @@ def _pool_rows(pool, slots):
     pieces stay under :data:`_GATHER_SLICE_ELEMS`, so that only the
     stepped rows move.  The same values either way."""
     s, c, *rest = pool.shape
-    per_pos = math.prod(rest)
-    n = next((n for n in range(1, c + 1)
-              if c % n == 0 and c // n * per_pos <= _GATHER_SLICE_ELEMS), c)
+    n = _gather_pieces(c, math.prod(rest))
     if n == 1:
         return pool[slots]
     at = (slots[:, None] * n + jnp.arange(n, dtype=slots.dtype)).reshape(-1)
     return pool.reshape(s * n, c // n, *rest)[at].reshape(
         slots.shape[0], c, *rest)
+
+
+def _state_leaf(j, shape):
+    """The shape the ``j``-th part of a recurrent state takes behind its
+    row in the pool: a tail (``j > 0``) flat; the float32 state as its
+    module shapes it, its leading axis cut in the fewest pieces that keep
+    a gathered slice under :data:`_GATHER_SLICE_ELEMS` (:func:`_pool_rows`
+    reads ``(S, pieces, ...)`` by pieces)."""
+    if j:
+        return (math.prod(shape),)
+    n = _gather_pieces(shape[0], math.prod(shape[1:]))
+    return shape if n == 1 else (n, shape[0] // n, *shape[1:])
+
+
+def state_row_bytes(cache):
+    """Bytes of recurrent state (tails included) behind one row of
+    ``cache``: what a decode step reads, and writes again, of each row it
+    steps."""
+    return sum(math.prod(leaf.shape[1:]) * leaf.dtype.itemsize
+               for name in _RECURRENT for leaf in cache.get(name, ())
+               if leaf is not None)
 
 
 def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
@@ -1048,13 +1279,15 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
     for i, blk in enumerate(params["blocks"]):
         if "mla" in blk:
             with jax.named_scope("mla"):
-                x = x + _mla_step(blk, cache["kv"][i], new_cache["kv"], x,
-                                  pos, rows, slots, compute_dtype)
+                x = x + _post(blk, "ln1", _mla_step(
+                    blk, cache["kv"][i], new_cache["kv"], x, pos, rows,
+                    slots, compute_dtype))
         elif hybrid:
-            x = x + step.mix(i, blk, _ln_apply(blk["ln1"], x))
+            x = x + _post(blk, "ln1",
+                          step.mix(i, blk, _pre(blk, "ln1", x)))
         else:
             with jax.named_scope("attn"):
-                h = _ln_apply(blk["ln1"], x)
+                h = _pre(blk, "ln1", x)
                 q = _proj_mq(blk["wq"], h, "bd,dhk->bhk", compute_dtype)
                 k_new = _proj_mq(blk["wk"], h, "bd,dhk->bhk", compute_dtype)
                 v_new = _proj_mq(blk["wv"], h, "bd,dhk->bhk", compute_dtype)
@@ -1096,7 +1329,8 @@ def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
                 dh = q.shape[-1]
                 a = _attn_one(q, kc, vc, pos, 1.0 / jnp.sqrt(dh),
                               window=window).astype(compute_dtype)
-                x = x + _proj_mq(blk["wo"], a, "bhk,hkd->bd", compute_dtype)
+                x = x + _post(blk, "ln1", _proj_mq(
+                    blk["wo"], a, "bhk,hkd->bd", compute_dtype))
         x = _ffn(blk, x, compute_dtype, auxs, valid, moe_impl, moe_k,
                  moe_capacity_factor, moe_dispatch)
     x = _ln_apply(params["ln_f"], x)
@@ -1124,7 +1358,8 @@ class _HybridStep:
         live = self.pos + 1
         valid = jnp.ones_like(live, bool) if valid is None else valid
         window = next((blk["diff"]["spec"].window for blk in params["blocks"]
-                       if "wk" in blk and blk["diff"]["spec"].window), 0)
+                       if "wk" in blk and "diff" in blk
+                       and blk["diff"]["spec"].window), 0)
         return {"counts": jnp.stack([
             jnp.sum(jnp.where(valid, live, 0)), jnp.sum(valid),
             jnp.sum(jnp.where(valid, jnp.minimum(live, window), 0))])}
@@ -1141,18 +1376,21 @@ class _HybridStep:
     def mix(self, i, blk, h):
         """Block ``i``'s mixer over its normed input ``h`` (B, d)."""
         dtype = self.dtype
-        if "ssm" in blk:
-            with jax.named_scope("ssm"):
-                pools = [self.cache[name][i] for name in _RECURRENT]
-                _, tail_shape = mamba.state_shapes(blk["ssm"])
+        mixer = _mixer(blk)
+        if mixer:
+            kind, module, names = mixer
+            with jax.named_scope(kind):
+                pools = [self.cache[name][i] for name in names]
                 with jax.named_scope("gather"):
-                    state = [pool[self.rows] for pool in pools]
-                out, self.memory, *state = mamba.mix_step(
-                    blk["ssm"], h, state[0],
-                    state[1].reshape(-1, *tail_shape), dtype)
+                    state = [_pool_rows(pool, self.rows).reshape(-1, *shape)
+                             for pool, shape in zip(
+                                 pools, module.state_shapes(blk[kind]))]
+                out, *state = module.mix_step(blk[kind], h, *state, dtype)
+                if kind == "ssm":  # its scan output, for the memory units
+                    self.memory, *state = state
                 with jax.named_scope("scatter"):
                     # the whole state of the stepped rows, and of no other
-                    self._keep(i, _RECURRENT, [
+                    self._keep(i, names, [
                         pool.at[self.rows].set(new.reshape(
                             -1, *pool.shape[1:]).astype(pool.dtype))
                         for pool, new in zip(pools, state)])
@@ -1162,17 +1400,21 @@ class _HybridStep:
             with jax.named_scope("gmu"):
                 return mamba.gmu(blk["gmu"], h, self.memory, dtype)
         with jax.named_scope("attn"):
-            q = diffattn.project_q(blk, h, dtype)
+            if "diff" in blk:
+                q = diffattn.project_q(blk, h, dtype)
+            else:  # plain attention: the three projections at once
+                q, *fresh = _plain_qkv(blk, h, dtype)
             if "wk" not in blk:
                 self._keep(i, (), ())
             else:
                 pools = self.cache["k"][i], self.cache["v"][i]
                 slot = self.pos % pools[0].shape[1]
                 with jax.named_scope("scatter"):
+                    if "diff" in blk:
+                        fresh = diffattn.project_kv(blk, h, dtype)
                     pools = [pool.at[self.rows, slot].set(new.reshape(
                         -1, pool.shape[-1]).astype(pool.dtype))
-                        for pool, new in zip(
-                            pools, diffattn.project_kv(blk, h, dtype))]
+                        for pool, new in zip(pools, fresh)]
                 self._keep(i, ("k", "v"), pools)
                 if self.slots is not None:
                     # write first, then read (see _decode); the cross
@@ -1181,6 +1423,10 @@ class _HybridStep:
                         pools = [_pool_rows(pool, self.slots)
                                  for pool in pools]
                 self.kv_rows = pools
+            if "diff" not in blk:
+                with jax.named_scope("full"):
+                    return _plain_out(blk, _attend_rows(
+                        q, *self.kv_rows, self.pos, dtype), dtype)
             with jax.named_scope(_diff_kind(blk)):
                 return diffattn.attend_one(blk, q, *self.kv_rows, self.pos,
                                            dtype)
@@ -1195,7 +1441,7 @@ def _mla_step(blk, pool, sink, x, pos, rows, slots, dtype):
     pos = jnp.broadcast_to(pos, (b,))
     if rows is None:
         rows = jnp.arange(b)
-    h = _ln_apply(blk["ln1"], x)
+    h = _pre(blk, "ln1", x)
     q_nope, q_pe, row = mla.project(
         blk["mla"], h, *mla.rope(blk["mla"], pos), dtype)
     with jax.named_scope("scatter"):
@@ -1217,8 +1463,8 @@ def prefill(params, cache, prefix, rows=None, *,
     predictions ``(B, T0, ...)`` float32 (``last_only``: position T0's
     alone, ``(B, 1, ...)``), the cache holding the bytes serial decode
     would have written (k/v are rotated before the sink; a latent model
-    attends expanded and sinks its latent rows; a state-space layer
-    scans on from the row's own recurrent state, which
+    attends expanded and sinks its latent rows; a recurrent layer
+    goes on from the row's own recurrent state, which
     :func:`rewind_rows` has zeroed, and writes the state after T0 whole)
     with ``pos`` at T0.
 
@@ -1237,11 +1483,12 @@ def prefill(params, cache, prefix, rows=None, *,
     kvs, more = [], {}
     t0 = prefix.shape[1]
     if hybrid:
-        # the state-space layers go on from the rows' own state, which a
+        # the recurrent layers go on from the rows' own state, which a
         # rewind has zeroed: the prefill is T0 decode steps of a rewound row
         more["state_in"] = {
-            name: [leaf if leaf is None or rows is None else leaf[rows]
-                   for leaf in cache[name]] for name in _RECURRENT}
+            name: [leaf if leaf is None or rows is None
+                   else _pool_rows(leaf, rows) for leaf in cache[name]]
+            for name in _RECURRENT if name in cache}
         if t0 % 32 == 0:
             more["diff_attn_fn"] = _flash_diff_attn
     with jax.named_scope("forward"):
@@ -1274,8 +1521,8 @@ def prefill(params, cache, prefix, rows=None, *,
             for name, t in kept.items():
                 pool = cache[name][i]
                 if name in _RECURRENT:  # no positions: written whole
-                    pool = (t.astype(pool.dtype) if rows is None
-                            else pool.at[rows].set(t.astype(pool.dtype)))
+                    t = t.reshape(-1, *pool.shape[1:]).astype(pool.dtype)
+                    pool = t if rows is None else pool.at[rows].set(t)
                 else:
                     keep_n, slots_ax = rings[pool.shape[1]]
                     if rows is None:
@@ -1307,7 +1554,7 @@ def rewind_rows(cache, rows):
     sufficient: :func:`_attn_one` masks by each slot's absolute position,
     so the stale k/v (or latent) rows of the previous tenant sit at
     negative positions and never attend.  A recurrent state cannot be
-    masked: the rows' ``ssm_h`` and ``ssm_tail`` are zeroed."""
+    masked: the rows' entries under :data:`_MIXERS`' names are zeroed."""
     new = {**cache, "pos": cache["pos"].at[rows].set(0)}
     for name in _RECURRENT:
         if name in cache:

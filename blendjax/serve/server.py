@@ -303,10 +303,15 @@ MOE_EVENTS = ("serve_moe_assignments", "serve_moe_assignments_held",
 
 
 #: the counts a step of a model of mixed layer kinds returns, in
-#: ``seqformer._HybridStep.counts``' order, and the one its resets make
-#: (rows whose recurrent state was zeroed); all in ``SERVE_EVENTS``
+#: ``seqformer._HybridStep.counts``' order (a model without window layers
+#: counts 0 window positions), the one its resets make (rows whose
+#: recurrent state was zeroed), and the bytes of recurrent state and
+#: convolution tails its steps' real rows read and wrote (the rows
+#: stepped times twice ``seqformer.state_row_bytes``, added where the
+#: step's counts are fetched); all in ``SERVE_EVENTS``
 HYBRID_EVENTS = ("serve_ctx_positions", "serve_rows_stepped",
-                 "serve_window_positions", "serve_state_resets")
+                 "serve_window_positions", "serve_state_resets",
+                 "serve_state_bytes")
 
 
 class SlotPoolLost(RuntimeError):
@@ -435,6 +440,8 @@ class SeqFormerModel:
         self._cache_dtype = cache_dtype or cdt
         self._jnp = jnp
         self._cache = self._new_pool()
+        # what a step reads and writes of each row's recurrent state
+        self._state_row_bytes = 2 * seqformer.state_row_bytes(self._cache)
         pad = self.pad_slot
 
         def reply_row(pred):
@@ -555,6 +562,9 @@ class SeqFormerModel:
             self._write_off(what, exc)
         for name, n in zip(self._step_events, counts):
             self._events[name] = self._events.get(name, 0) + int(n)
+            if name == "serve_rows_stepped":
+                self._events[HYBRID_EVENTS[4]] = self._events.get(
+                    HYBRID_EVENTS[4], 0) + int(n) * self._state_row_bytes
         return pred
 
     def _write_off(self, what, exc):

@@ -189,6 +189,8 @@ REPLAY_EVENTS = (
 #: K/V (the one being written included), the rows, and the positions
 #: live in one window ring (they come over with the reply's fetch); and
 #: the rows whose recurrent state a reset zeroed.
+#: ``serve_state_bytes`` — bytes of recurrent state and convolution tails
+#: that the real rows of such a model's decode ticks read and wrote.
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
@@ -200,7 +202,7 @@ SERVE_EVENTS = (
     "serve_moe_experts_hit",
     "serve_ticks_overlapped", "serve_fetch_wait_us",
     "serve_ctx_positions", "serve_rows_stepped", "serve_window_positions",
-    "serve_state_resets",
+    "serve_state_resets", "serve_state_bytes",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md

@@ -258,6 +258,103 @@ def test_hybrid_pool_is_served_in_place_on_the_chip(one_chip, what, n,
         assert "flash_fwd" in text and "tpu_custom_call" in text
 
 
+# the linear-attention cell's widths
+# (chipbench/configs/olmohybrid7b_l12_serve_bf16.json) over one period:
+# three linear-attention layers and one full-attention layer
+LINEAR_POOL = dict(slots=72, length=1536)
+LINEAR_WIDTHS = dict(
+    hidden_size=3840, intermediate_size=11008, num_hidden_layers=4,
+    num_attention_heads=30, num_key_value_heads=30,
+    layer_types=["linear_attention"] * 3 + ["full_attention"],
+    linear_num_key_heads=30, linear_num_value_heads=30,
+    linear_key_head_dim=96, linear_value_head_dim=192,
+    linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    rms_norm_eps=1e-6, vocab_size=100352, tie_word_embeddings=False)
+
+
+@pytest.mark.parametrize("what,n", [("step", 32), ("step", 64),
+                                    ("prefill", 1024)])
+def test_linear_attention_pool_is_served_in_place_on_the_chip(
+        one_chip, what, n, monkeypatch):
+    """Gated delta-rule linear attention at its published widths: the
+    donated pool (a float32 matrix state a head, 2.2 MB a row and layer,
+    three tails, the full layers' K/V) is aliased to the output; no leaf
+    of it is copied, re-laid or sliced whole (a flat ``(S, 552960)`` state
+    was cut by the compiler into whole-pool slices before its gather:
+    ``init_cache`` keeps the heads in three pieces of ten, each under the
+    gather's limit); the step's temporaries stay near the stepped rows'
+    gathered K/V and state; the prefill's full attention is the flash
+    kernel."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+    from blendjax.serve.server import SeqFormerModel
+
+    flash_attention = importlib.import_module("blendjax.ops.flash_attention")
+    monkeypatch.setattr(flash_attention, "resolve_interpret",
+                        lambda interpret=None: False)
+    tiny_widths = dict(
+        LINEAR_WIDTHS, hidden_size=64, intermediate_size=128,
+        num_attention_heads=2, num_key_value_heads=2, linear_num_key_heads=2,
+        linear_num_value_heads=2, linear_key_head_dim=8,
+        linear_value_head_dim=16, vocab_size=128)
+    tiny = SeqFormerModel(
+        seqformer.init_linear_hybrid_model(jax.random.PRNGKey(0), tiny_widths,
+                                           dtype=jnp.bfloat16),
+        slots=2, length=16, compute_dtype=jnp.bfloat16,
+        cache_dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: seqformer.init_linear_hybrid_model(
+        jax.random.PRNGKey(0), LINEAR_WIDTHS, dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: seqformer.init_cache(
+        params, LINEAR_POOL["slots"] + 1, dtype=jnp.bfloat16,
+        length=LINEAR_POOL["length"], per_row=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    fn = tiny._step if what == "step" else tiny._prefill
+    compiled = fn.lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((n if what == "step" else 1,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32, sharding=one_chip),
+    ).compile()
+    leaves = [leaf for leaf in jax.tree.leaves(cache) if leaf.ndim > 1]
+    shapes = {(leaf.shape, leaf.dtype.name) for leaf in leaves}
+    assert shapes == {
+        ((73, 3, 10, 192, 96), "float32"), ((73, 8640), "bfloat16"),
+        ((73, 17280), "bfloat16"), ((73, 1536, 3840), "bfloat16")}
+    assert seqformer.state_row_bytes(cache) == 3 * (552960 * 4
+                                                    + 3 * 11520 * 2)
+    pool_bytes = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                     for leaf in leaves)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    text = compiled.as_text()
+    assert f"jit_serve_{what}" in text
+    # no operation yields a whole leaf of the pool but the in-place
+    # writes (fusions over the aliased buffer): no copy, no slice
+    whole = [line for line in text.splitlines()
+             if re.search(r"= \w+\[(73|219),", line)
+             and re.search(r" (copy|slice|copy-start|slice-start|"
+                           r"transpose)\(", line)]
+    assert not whole, whole[:3]
+    assert "mini-gather-slice" not in text
+    if what == "step":
+        # the gathered K/V rows of one full layer (twice over: keys and
+        # values) and the stepped rows' state, its 96-wide minor axis
+        # padded to 128 lanes, a few times over
+        kv_rows = 2 * n * 1536 * 3840 * 2
+        state_rows = n * 30 * 192 * 128 * 4
+        assert mem.temp_size_in_bytes < 1.5 * kv_rows + 4 * state_rows
+    else:
+        assert "flash_fwd" in text and "tpu_custom_call" in text
+
+
 # the train cell (chipbench/configs/seqformer_wm100m_train_bf16.json: batch
 # 64 x 512, 8 heads of 128, bfloat16 compute, block 'auto'), the long
 # sequence of chip_smoke.py's kernel leg, and the widest float32 head the

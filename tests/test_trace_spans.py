@@ -366,6 +366,61 @@ def _hybrid_prefill():
         jnp.ones((3, 1), jnp.int32))
 
 
+TINY_LINEAR = dict(
+    hidden_size=32, intermediate_size=64, num_hidden_layers=4,
+    num_attention_heads=2, num_key_value_heads=2,
+    layer_types=["linear_attention"] * 3 + ["full_attention"],
+    linear_num_key_heads=2, linear_num_value_heads=2, linear_key_head_dim=8,
+    linear_value_head_dim=16, linear_conv_kernel_dim=4,
+    linear_allow_neg_eigval=True, rms_norm_eps=1e-6, vocab_size=64,
+    tie_word_embeddings=False)
+
+
+def _tiny_linear_model():
+    return SeqFormerModel(
+        seqformer.init_linear_hybrid_model(jax.random.PRNGKey(0),
+                                           TINY_LINEAR),
+        slots=2, length=16)
+
+
+def _linear_step():
+    model = _tiny_linear_model()
+    return model._step.lower(
+        model.params, model._cache, jnp.zeros(2, jnp.int32),
+        jnp.ones((2, 1), jnp.int32))
+
+
+def _linear_prefill():
+    model = _tiny_linear_model()
+    return model._prefill.lower(
+        model.params, model._cache, jnp.zeros(1, jnp.int32),
+        jnp.ones((3, 1), jnp.int32))
+
+
+def test_linear_attention_model_counters_move_in_a_served_episode():
+    """A model without window layers counts no window positions, and the
+    other counts stand; ``serve_state_bytes`` is twice the state and tails
+    behind a row, a real row stepped."""
+    from blendjax.serve.server import HYBRID_EVENTS
+
+    assert "serve_state_bytes" in HYBRID_EVENTS
+    assert "serve_state_bytes" in SERVE_EVENTS
+    assert TelemetryHub().scrape()["counters"]["serve_state_bytes"] == 0
+    model = _tiny_linear_model()
+    row = 3 * (2 * 16 * 8 * 4 + 3 * (16 + 16 + 32) * 4)  # float32 tails here
+    assert seqformer.state_row_bytes(model._cache) == row
+    model.reset_rows(np.asarray([1]))
+    model.prefill_rows(np.asarray([1]), np.ones((5, 1), np.int32))
+    for _ in range(3):  # positions 5, 6, 7; the pad row beside them
+        np.asarray(model.step_rows(np.asarray([1, model.pad_slot]),
+                                   np.ones((2, 1), np.int32)))
+    assert model.drain_events() == {
+        "serve_ctx_positions": 6 + 7 + 8, "serve_rows_stepped": 3,
+        "serve_window_positions": 0, "serve_state_resets": 1,
+        "serve_state_bytes": 3 * 2 * row}
+    assert model.drain_events() == {}
+
+
 def test_hybrid_model_counters_move_in_a_served_episode():
     from blendjax.serve.server import HYBRID_EVENTS
 
@@ -380,7 +435,8 @@ def test_hybrid_model_counters_move_in_a_served_episode():
                                    np.ones((2, 1), np.int32)))
     assert model.drain_events() == {
         "serve_ctx_positions": 6 + 7 + 8, "serve_rows_stepped": 3,
-        "serve_window_positions": 3 * 4, "serve_state_resets": 1}
+        "serve_window_positions": 3 * 4, "serve_state_resets": 1,
+        "serve_state_bytes": 3 * 2 * seqformer.state_row_bytes(model._cache)}
     assert model.drain_events() == {}
 
 
@@ -405,6 +461,12 @@ def test_routed_model_counters_are_in_the_vocabulary(name):
     (_hybrid_prefill, "serve_prefill",
      ("forward", "ssm", "conv", "scan", "gmu", "attn", "diff", "window",
       "full", "cross", "scatter", "mlp", "ln", "head")),
+    (_linear_step, "serve_step",
+     ("decode", "gdn", "conv", "gate", "update", "attn", "full", "scatter",
+      "gather", "mlp", "ln", "head")),
+    (_linear_prefill, "serve_prefill",
+     ("forward", "gdn", "conv", "gate", "chunk", "attn", "full", "scatter",
+      "mlp", "ln", "head")),
     (_train_step, "train_step",
      ("loss", "optimizer", "attn", "mlp", "ln")),
     (_serve_step, "serve_step",
