@@ -321,7 +321,8 @@ class SlotPoolLost(RuntimeError):
 
 
 class _Reply:
-    """What :meth:`SeqFormerModel.step_rows` returns: the reply rows of
+    """What :meth:`SeqFormerModel.step_rows` and
+    :meth:`~SeqFormerModel.prefill_reply` return: the reply rows of
     a call that has been dispatched and not waited for.  ``np.asarray``
     / ``np.array`` of it (or an index into it) is the fetch: it waits
     for the device, banks a routed model's counts and hands out the
@@ -355,8 +356,9 @@ class _Reply:
 
 
 def _reply_ready(reply):
-    """Whether a model's ``step_rows`` reply can be fetched without
-    waiting: rows a model computed on the host (an array) always can."""
+    """Whether a model's ``step_rows`` or prefill reply can be fetched
+    without waiting: rows a model computed on the host (an array) always
+    can."""
     ready = getattr(reply, "is_ready", None)
     return ready is None or ready()
 
@@ -378,8 +380,9 @@ class SeqFormerModel:
     or a ``prefill_rows`` made before the reply is fetched runs behind
     it on the device, in the order the calls were made, and the caller
     decides when to wait (``np.asarray(reply)``; ``reply.is_ready()``).
-    ``prefill_rows`` waits for its own reply (and so for every step
-    dispatched before it).
+    ``prefill_reply`` hands a prefill out the same way;
+    ``prefill_rows`` is the same call waiting for its own reply (and so
+    for every step dispatched before it).
 
     Everything that can refuse a call (shapes, lengths, a first
     compilation) fails BEFORE the pool is donated and leaves it as it
@@ -575,12 +578,14 @@ class SeqFormerModel:
             f"({type(exc).__name__}: {exc}); pool rebuilt empty"
         ) from exc
 
-    def prefill_rows(self, idx, prefix):
+    def prefill_reply(self, idx, prefix):
         """Admit a T-step observation prefix into slot ``idx`` with one
         teacher-forced batched pass (vs T serial ``decode_step``s —
-        parity within 1e-5, tests/test_serve.py).  Returns the
-        prediction for position T (what the T'th serial step would have
-        returned); the slot's next ``step`` decodes at position T."""
+        parity within 1e-5, tests/test_serve.py), dispatched and not
+        waited for.  The :class:`_Reply` returned fetches the prediction
+        for position T (what the T'th serial step would have returned);
+        the slot's next ``step`` decodes at position T, and one
+        dispatched before the fetch runs behind the prefill."""
         if np.ndim(prefix) != 2 or np.shape(prefix)[1] != self.obs_dim:
             raise ValueError(
                 f"prefix shape {np.shape(prefix)} != (T, {self.obs_dim})"
@@ -603,9 +608,12 @@ class SeqFormerModel:
                 f"table ({self.params['pos'].shape[0]}); use "
                 "pos_encoding='rope' for longer prefixes"
             )
-        # fenced where it is made: the reply is the ``reset``'s answer
-        return np.asarray(self._in_place(self._prefill, "prefill", idx,
-                                         prefix))
+        return self._in_place(self._prefill, "prefill", idx, prefix)
+
+    def prefill_rows(self, idx, prefix):
+        """:meth:`prefill_reply`, fenced where it is made: the
+        prediction for position T as an array."""
+        return np.asarray(self.prefill_reply(idx, prefix))
 
     def apply_weights(self, tree):
         """WeightBus hot-swap: adopt a published seqformer pytree (the
@@ -681,6 +689,26 @@ _Launched = namedtuple(
     "_Launched", "state batch reply bucket pos_before compute_s")
 
 
+class _Prefilling:
+    """One ``reset``'s prefill between its dispatch (``PolicyServer.
+    _prefill``) and the fetch that answers the reset (``_retire``): it
+    rides in ``_launched`` among the ticks, in dispatch order, and in
+    ``_pending`` under the reset's message id, so that a retry meeting
+    it in flight re-points ``ident`` and runs nothing.  ``behind`` says
+    whether anything launched was still unfetched at its dispatch,
+    ``prefill_s`` is the thread's time in this prefill so far."""
+
+    __slots__ = ("state", "reply", "slot", "episode", "length", "behind",
+                 "prefill_s", "ident", "msg", "t0_us")
+
+    def __init__(self, state, reply, slot, episode, length, behind,
+                 prefill_s):
+        self.state, self.reply = state, reply
+        self.slot, self.episode, self.length = slot, episode, length
+        self.behind, self.prefill_s = behind, prefill_s
+        self.ident = self.msg = self.t0_us = None
+
+
 class _ModelState:
     """One hosted model's serving state: its slot pool (or stateless
     episode registry) — multi-model servers keep one per model id, so
@@ -711,14 +739,19 @@ class PolicyServer:
     (:meth:`serve_forever`).  A tick's launch dispatches the model call
     without waiting for it; between two turns of the loop at most ONE
     launched tick is outstanding (two inside a turn: the follower is
-    dispatched, then the older one retired).  While it runs on the
-    device the loop admits requests, and by what it can observe
-    (is anything queued, can anybody still send, is the launched tick
-    ready) it retires the tick, or launches the next one behind it
-    first.  A lone client, an empty queue, or a model that computes on
-    the host sees launch-then-retire: the tick as it always was.
-    Nothing is in flight when weights are swapped or a prefill runs
-    (both retire what is launched first), and a step stays pending
+    dispatched, then the older one retired).  A ``reset``'s prefill is
+    launched the same way, where the reset is admitted: dispatched
+    behind whatever is in flight, it rides among the launched ticks in
+    dispatch order (any number of prefills beside the one tick) and the
+    reset is answered where the prefill is retired.  While they run on
+    the device the loop admits requests, and by what it can observe
+    (is anything queued, can anybody still send, is the oldest launched
+    entry ready) it retires that entry, or launches the next tick
+    behind it first.  A lone client, an empty queue, or a model that
+    computes on the host sees launch-then-retire: the tick, and the
+    reset answered at once, as they always were.  Nothing is in flight
+    when weights are swapped (a staged snapshot retires what is
+    launched first), and a step or a prefilling reset stays pending
     (deduplicated) until its reply has been sent.
 
     Params
@@ -807,11 +840,13 @@ class PolicyServer:
         self._reply_cache = OrderedDict()
         self._reply_cache_depth = int(reply_cache_depth)
         self._queue = deque()
-        # mid -> _Pending not answered yet, queued or launched (dedupe):
-        # an entry leaves when its reply has entered the reply cache
+        # mid -> _Pending (a step queued or launched) or _Prefilling (a
+        # reset whose prefill is launched) not answered yet (dedupe): an
+        # entry leaves when its reply has entered the reply cache
         self._pending = {}
-        # ticks dispatched and not yet answered, oldest first: at most
-        # one between two turns of the serve loop, two inside a turn
+        # ticks (_Launched) and prefills (_Prefilling) dispatched and not
+        # yet answered, in dispatch order: at most one TICK between two
+        # turns of the serve loop, two inside a turn
         self._launched = deque()
         # Slot pools live per hosted model (:class:`_ModelState`):
         # ``live`` maps slot -> [episode lease id, monotonic last-use].
@@ -983,6 +1018,9 @@ class PolicyServer:
         }
 
     def _cmd_reset(self, msg):
+        """Allocate a slot and answer with it, or (a reset with a prefix
+        on a model that hands its prefill out unfetched) return the
+        launched :class:`_Prefilling`, whose retire answers instead."""
         st, err = self._state_or_error(msg)
         if err is not None:
             return err
@@ -993,26 +1031,33 @@ class PolicyServer:
                 f"no free episode slot ({st.model.slots} live on model "
                 f"{st.mid!r}); close an episode or raise slots="
             )}
-        reply = {"slot": slot, "episode": episode}
         prefix = msg.get("prefix")
         if prefix is not None:
-            err = self._prefill(st, slot, episode, prefix, reply)
-            if err is not None:
-                return err
+            return self._prefill(st, slot, episode, prefix)
         self.counters.incr("serve_resets")
-        return reply
+        return {"slot": slot, "episode": episode}
 
-    def _prefill(self, st, slot, episode, prefix, reply):
+    def _release(self, st, slot, episode):
+        """Give back what a reset that failed had been allocated."""
+        if st.model.slots > 0:
+            self._free_slot(st, slot, episode)
+        else:
+            st.stateless_eps.pop(episode, None)
+
+    def _prefill(self, st, slot, episode, prefix):
         """Batched prefill admission: replay a T-step observation
         prefix into the freshly-allocated slot with ONE teacher-forced
-        pass (``model.prefill_rows``) instead of T serial decode steps.
-        Mutates ``reply`` in place on success; returns an error reply
-        (with the slot freed again) on failure."""
+        pass instead of T serial decode steps.  The pass is DISPATCHED
+        here, behind whatever is launched (the pool chains the device's
+        order: tick, rewind, prefill, next tick run as dispatched), and
+        not waited for: a model that hands the reply out unfetched
+        (``prefill_reply``) gets a :class:`_Prefilling` appended to
+        ``_launched`` and returned, and :meth:`_retire` answers the
+        reset; an array (a model that computes on the host) is the
+        reset's reply at once.  Returns an error reply, with the slot
+        freed again, where the prefix or the dispatch is refused."""
         def fail(text):
-            if st.model.slots > 0:
-                self._free_slot(st, slot, episode)
-            else:
-                st.stateless_eps.pop(episode, None)
+            self._release(st, slot, episode)
             return {"error": text}
 
         if not hasattr(st.model, "prefill_rows") or st.model.slots == 0:
@@ -1032,30 +1077,38 @@ class PolicyServer:
                 f"prefix shape {prefix.shape} != (T >= 1, "
                 f"{st.model.obs_dim})"
             )
-        # the prefill waits for its own reply, and so for every tick
-        # launched before it: those are answered first, so that their
-        # clients turn round while the prefill runs and not after it
-        while self._launched:
-            self._retire()
+        dispatch = getattr(st.model, "prefill_reply", st.model.prefill_rows)
+        behind = bool(self._launched)
         t0 = time.perf_counter()
         try:
             with span("serve.prefill", len=int(prefix.shape[0])):
-                pred = st.model.prefill_rows(np.asarray([slot]), prefix)
+                reply = dispatch(np.asarray([slot]), prefix)
         except Exception as exc:  # noqa: BLE001 - surfaced to client
             logger.exception("policy server: prefill failed")
             if isinstance(exc, SlotPoolLost):
                 self._pool_lost(st)
             return fail(f"prefill failed: {type(exc).__name__}: {exc}")
-        # the one record of the time the server's thread spent in
-        # prefill (no tick can start meanwhile)
-        self.counters.incr("serve_prefill_us",
-                           int((time.perf_counter() - t0) * 1e6))
+        ent = _Prefilling(st, reply, slot, episode, int(prefix.shape[0]),
+                          behind, time.perf_counter() - t0)
+        if not hasattr(reply, "is_ready"):
+            return self._prefilled(ent, reply, overlapped=False)
+        self._launched.append(ent)
+        return ent
+
+    def _prefilled(self, ent, pred, overlapped):
+        """Count a prefill that ran to its end (``overlapped``: it
+        shared the device's queue) and make its reset's reply: the
+        prediction for position T (what the T'th serial step would have
+        returned) and the position the next step consumes."""
+        # the one record of the server's thread's time in prefills: the
+        # dispatch, and what it waited at the fetch (nothing, where the
+        # device had finished behind admission and the ticks)
+        self.counters.incr("serve_prefill_us", int(ent.prefill_s * 1e6))
         self.counters.incr("serve_prefills")
-        # the prediction for position T (what the T'th serial step
-        # would have returned) and the position the next step consumes
-        reply["pred"] = np.ascontiguousarray(pred)
-        reply["pos"] = int(prefix.shape[0])
-        return None
+        self.counters.incr("serve_prefills_overlapped", int(overlapped))
+        self.counters.incr("serve_resets")
+        return {"slot": ent.slot, "episode": ent.episode,
+                "pred": np.ascontiguousarray(pred), "pos": ent.length}
 
     def _cmd_close(self, msg):
         st, err = self._state_or_error(msg)
@@ -1154,20 +1207,20 @@ class PolicyServer:
             except Exception as exc:  # noqa: BLE001 - surfaced to client
                 logger.exception("policy server: %r failed", cmd)
                 reply = {"error": f"{type(exc).__name__}: {exc}"}
-        if "error" in reply:
+        if isinstance(reply, dict) and "error" in reply:
             self.counters.incr("serve_errors")
         return reply
 
     def _poll_weights(self):
         """Drain the WeightBus subscription and hot-swap a staged
         snapshot — called from the serve loop between turns.  A tick
-        may be launched then: a staged snapshot RETIRES it first, so
-        the swap happens at a point where no batch is in flight —
-        slots/leases/reply-cache state cannot be half-stepped under it,
-        and every reply is stamped (``_finish``) with the version that
-        executed it.  A snapshot the model refuses (structure/shape
-        drift) is discarded and counted; the last good version keeps
-        serving either way."""
+        and prefills may be launched then: a staged snapshot RETIRES
+        them first, so the swap happens at a point where nothing is in
+        flight — slots/leases/reply-cache state cannot be half-stepped
+        under it, and every reply (a prefilling reset's too) is stamped
+        (``_finish``) with the version that executed it.  A snapshot
+        the model refuses (structure/shape drift) is discarded and
+        counted; the last good version keeps serving either way."""
         if self.subscriber is None:
             return
         snap = self.subscriber.poll()
@@ -1303,8 +1356,10 @@ class PolicyServer:
             pass  # client gone; its retry will re-dial
 
     def _admit(self, ident, msg):
-        """One decoded request: answer control commands immediately,
-        queue ``step``s for the next tick, dedupe retries."""
+        """One decoded request: answer control commands immediately (a
+        ``reset`` with a prefix dispatches its prefill here and is
+        answered where that is retired), queue ``step``s for the next
+        tick, dedupe retries."""
         self.counters.incr("serve_requests")
         mid = msg.get(wire.BTMID_KEY)
         cmd = msg.get("cmd")
@@ -1316,17 +1371,24 @@ class PolicyServer:
             self.counters.incr("serve_cache_hits")
             self._send(ident, self._reply_cache[mid])
             return
-        if cmd != "step":
-            reply = self._control_reply(msg)
-            self._finish(ident, msg, reply, span_name=f"serve:{cmd}",
-                         t0_us=t0_us)
-            return
         if mid is not None and mid in self._pending:
-            # retry of a request still QUEUED, or launched and not yet
-            # answered: the original's reply will answer it — re-point
-            # the route and drop the dup (its step runs once)
+            # retry of a step still QUEUED, or of a step or a reset's
+            # prefill launched and not yet answered: the original's
+            # reply will answer it — re-point the route and drop the
+            # dup (its step or prefill runs once)
             self.counters.incr("serve_dup_inflight")
             self._pending[mid].ident = ident
+            return
+        if cmd != "step":
+            reply = self._control_reply(msg)
+            if isinstance(reply, _Prefilling):
+                # a reset whose prefill was launched: its retire answers
+                reply.ident, reply.msg, reply.t0_us = ident, msg, t0_us
+                if mid is not None:
+                    self._pending[mid] = reply
+                return
+            self._finish(ident, msg, reply, span_name=f"serve:{cmd}",
+                         t0_us=t0_us)
             return
         st, err = self._state_or_error(msg)
         if err is not None:
@@ -1478,62 +1540,105 @@ class PolicyServer:
                     self._step_failed(batch, exc)
                     return more
             self.counters.incr("serve_ticks_overlapped",
-                               int(bool(self._launched)))
+                               int(self._ticks_launched() > 0))
             self._launched.append(_Launched(
                 head, batch, reply, bucket, pos_before,
                 time.perf_counter() - t_compute))
             return more
 
+    def _ticks_launched(self):
+        """How many of the launched entries are ticks (the others are
+        prefills)."""
+        return sum(isinstance(t, _Launched) for t in self._launched)
+
     def _retire(self):
-        """A tick's second half, for the oldest launched tick: fetch
-        its reply (the one place the server's thread waits for the
-        device), count it and scatter the answers.  A fetch that fails
-        for a lost slot pool (:class:`SlotPoolLost`: raised once per
-        pool) also fails whatever was launched behind it on that pool;
-        the leases are dropped once."""
-        tick = self._launched.popleft()
-        model = tick.state.model
-        n = len(tick.batch)
-        with span("serve.retire", rows=n):
+        """The second half of the oldest launched entry, a tick or a
+        prefill: fetch its reply (the one place the server's thread
+        waits for the device), count it and answer: a tick's rows, or
+        the ``reset`` whose prefill it was.  A fetch that fails for a
+        lost slot pool (:class:`SlotPoolLost`: raised once per pool)
+        also fails whatever was launched behind it on that pool, ticks
+        and prefills; the leases are dropped once."""
+        ent = self._launched.popleft()
+        tick = isinstance(ent, _Launched)
+        what = {"rows": len(ent.batch)} if tick else {"prefill": ent.length}
+        with span("serve.retire", **what):
             t_fetch = time.perf_counter()
             try:
-                preds = np.asarray(tick.reply)
+                rows = np.asarray(ent.reply)
             except Exception as exc:  # noqa: BLE001 - must survive
-                logger.exception("policy server: batched step failed")
+                logger.exception("policy server: a launched %s failed",
+                                 "step" if tick else "prefill")
                 behind = []
                 if isinstance(exc, SlotPoolLost):
-                    self._pool_lost(tick.state)
+                    self._pool_lost(ent.state)
                     behind = [t for t in self._launched
-                              if t.state is tick.state]
+                              if t.state is ent.state]
                 for t in behind:
                     self._launched.remove(t)
-                for t in [tick] + behind:
-                    self._step_failed(t.batch, exc)
+                for t in [ent] + behind:
+                    self._launched_failed(t, exc)
                 return
-            t_reply = time.perf_counter()
-            self.counters.incr("serve_fetch_wait_us",
-                               int((t_reply - t_fetch) * 1e6))
-            # the host's time inside the model call for this tick: its
-            # dispatch and its fetch, not what ran between the two
-            self.timer.add("compute", tick.compute_s + t_reply - t_fetch)
-            with span("serve.tick.reply"):
-                self.counters.incr("serve_batches")
-                if hasattr(model, "drain_events"):
-                    for name, count in model.drain_events().items():
-                        self.counters.incr(name, count)
-                if tick.bucket > n:
-                    self.counters.incr("serve_batch_pad", tick.bucket - n)
-                for j, (ent, _, _) in enumerate(tick.batch):
-                    reply = {"pred": np.ascontiguousarray(preds[j])}
-                    if tick.pos_before[j] is not None:
-                        reply["pos"] = tick.pos_before[j]
-                    # deferred doorbells: the whole batch's shm replies
-                    # ride ONE wake per channel (flushed below), not one
-                    # ding per record
-                    self._answer_step(ent, reply, ding=False)
-                if self._shm is not None:
-                    self._shm.flush_bells()
-                self.timer.add("reply", time.perf_counter() - t_reply)
+            waited = time.perf_counter() - t_fetch
+            if tick:
+                self._answer_tick(ent, rows, waited)
+            else:
+                self._answer_prefill(ent, rows, waited)
+
+    def _launched_failed(self, ent, exc):
+        """Error-reply everybody a launched entry was to answer."""
+        if isinstance(ent, _Launched):
+            self._step_failed(ent.batch, exc)
+            return
+        self._release(ent.state, ent.slot, ent.episode)
+        self.counters.incr("serve_errors")
+        self._answer_reset(ent, {
+            "error": f"prefill failed: {type(exc).__name__}: {exc}"})
+
+    def _answer_reset(self, ent, reply):
+        """Answer the ``reset`` whose prefill was launched: only now
+        does it stop being pending (as :meth:`_answer_step`)."""
+        mid = ent.msg.get(wire.BTMID_KEY)
+        if mid is not None:
+            self._pending.pop(mid, None)
+        self._finish(ent.ident, ent.msg, reply, span_name="serve:reset",
+                     t0_us=ent.t0_us)
+
+    def _answer_prefill(self, ent, pred, waited):
+        """A fetched prefill: count it, and whether it shared the
+        device's queue (something launched was unfetched at its
+        dispatch, or was dispatched behind it before this fetch)."""
+        ent.prefill_s += waited
+        self._answer_reset(ent, self._prefilled(
+            ent, pred, overlapped=ent.behind or bool(self._launched)))
+
+    def _answer_tick(self, tick, preds, waited):
+        """A fetched tick: count it and scatter the answers."""
+        model = tick.state.model
+        n = len(tick.batch)
+        t_reply = time.perf_counter()
+        self.counters.incr("serve_fetch_wait_us", int(waited * 1e6))
+        # the host's time inside the model call for this tick: its
+        # dispatch and its fetch, not what ran between the two
+        self.timer.add("compute", tick.compute_s + waited)
+        with span("serve.tick.reply"):
+            self.counters.incr("serve_batches")
+            if hasattr(model, "drain_events"):
+                for name, count in model.drain_events().items():
+                    self.counters.incr(name, count)
+            if tick.bucket > n:
+                self.counters.incr("serve_batch_pad", tick.bucket - n)
+            for j, (ent, _, _) in enumerate(tick.batch):
+                reply = {"pred": np.ascontiguousarray(preds[j])}
+                if tick.pos_before[j] is not None:
+                    reply["pos"] = tick.pos_before[j]
+                # deferred doorbells: the whole batch's shm replies
+                # ride ONE wake per channel (flushed below), not one
+                # ding per record
+                self._answer_step(ent, reply, ding=False)
+            if self._shm is not None:
+                self._shm.flush_bells()
+            self.timer.add("reply", time.perf_counter() - t_reply)
 
     # -- serving -------------------------------------------------------------
 
@@ -1547,12 +1652,13 @@ class PolicyServer:
         idle; the ``max(1, ...)`` keeps a client that never reset
         servable instead of deadlocking the window.
 
-        With a tick launched over ``in_flight`` rows: the episodes in it
-        cannot send, so at most the others; and no more than half the
-        live episodes, because with one tick on the device and one being
-        gathered an even split keeps the device fed by either (a
-        follower that waited for more would be launched after the
-        device had gone idle).  Zero or less when nobody can send."""
+        With ``in_flight`` episodes launched (a tick's rows, and every
+        episode whose prefill is): they cannot send, so at most the
+        others; and no more than half the live episodes, because with
+        one tick on the device and one being gathered an even split
+        keeps the device fed by either (a follower that waited for more
+        would be launched after the device had gone idle).  Zero or
+        less when nobody can send."""
         live = 0
         for st in self._models.values():
             if st.model.slots > 0:
@@ -1607,8 +1713,8 @@ class PolicyServer:
             self._shm.pump(self._handle_shm_msg)
 
     def _admit_ready(self):
-        """Admit what has arrived on either wire (resets, and so
-        prefills, run in here)."""
+        """Admit what has arrived on either wire (resets are handled in
+        here: a prefill is dispatched, and joins what is launched)."""
         with span("serve.admit"):
             self._drain()
             self._drain_shm()
@@ -1632,25 +1738,28 @@ class PolicyServer:
         latency) or a full bucket is.
 
         With nothing launched the window is ``tick_ms`` long and ends
-        early on the first empty poll slice.  With a tick launched the
-        target is smaller (:meth:`_window_target`) and the device's own
-        tick is the window: it ends when that tick's reply
-        is ready (its answers should not wait for a follower), however
-        long or short ``tick_ms`` is.  Meanwhile the wires are read in
-        slices of a millisecond, never slept on: a pump of the shm
-        channels costs the same for one request as for a burst, and the
-        follower cannot start before the launched tick ends anyway."""
+        early on the first empty poll slice.  With a tick or a prefill
+        launched the target is smaller (:meth:`_window_target`: no
+        launched episode can send) and the device's own work is the
+        window: it ends when the OLDEST launched entry's reply is ready
+        (its answers should not wait for a follower), however long or
+        short ``tick_ms`` is.  Meanwhile the wires are read in slices of
+        a millisecond, never slept on: a pump of the shm channels costs
+        the same for one request as for a burst, and the follower cannot
+        start before what is launched ends anyway.  A ``reset`` read in
+        here dispatches its prefill behind what is launched and joins
+        it."""
         t_end = time.perf_counter() + self.tick_ms / 1000.0
         with span("serve.window"):
             while True:
-                # read each time round: admission retires the launched
-                # tick itself before it runs a prefill
-                flying = self._launched[-1] if self._launched else None
-                in_flight = len(flying.batch) if flying is not None else 0
+                # read each time round: admission launches prefills
+                in_flight = sum(
+                    len(t.batch) if isinstance(t, _Launched) else 1
+                    for t in self._launched)
                 if len(self._queue) >= self._window_target(in_flight):
                     break
-                if flying is not None:
-                    if _reply_ready(flying.reply):
+                if self._launched:
+                    if _reply_ready(self._launched[0].reply):
                         break
                     t_slice = time.perf_counter() + 1e-3
                     if not self._poller.poll(1):
@@ -1666,15 +1775,17 @@ class PolicyServer:
 
     def _turn(self):
         """One move of the serve loop after the window, by what it can
-        observe.  A launched tick that is ready, or that nothing queued
-        could follow, is retired (and the loop comes round again for
-        what is queued: a finished tick's answers never wait for a
-        follower's launch).  Otherwise what is queued is launched, BEHIND
-        a tick still running if there is one, and then the older tick is
-        retired: the launch, the older tick's fetch and replies, and the
-        admission that follows all run beside the device.  A lone
-        client, or an empty queue, sees launch then retire: a tick as it
-        always was."""
+        observe.  The oldest launched entry (a tick or a prefill), if it
+        is ready or nothing queued could follow it, is retired (and the
+        loop comes round again for what is queued: finished work's
+        answers never wait for a follower's launch).  Otherwise what is
+        queued is launched, BEHIND whatever is still running, and then
+        the launched entries are retired oldest first until one tick is
+        left: the launch, the older entries' fetches and replies, and
+        the admission that follows all run beside the device, and the
+        thread waits in a prefill's fetch only when that prefill is the
+        oldest entry.  A lone client, or an empty queue, sees launch
+        then retire: a tick as it always was."""
         if self._launched and (
                 not self._queue or _reply_ready(self._launched[0].reply)):
             self._retire()
@@ -1685,13 +1796,15 @@ class PolicyServer:
         more = True
         while more and self._queue:
             more = self._launch()
-            while len(self._launched) > 1:
+            while self._ticks_launched() > 1:
                 self._retire()
 
     def serve_forever(self, stop_event=None, poll_ms=50):
         """The one serve loop.  Between two turns at most ONE tick is
-        launched (dispatched, its reply not yet fetched); a hot-swap
-        retires it first, so weights change with nothing in flight."""
+        launched (dispatched, its reply not yet fetched), with the
+        prefills admission dispatched before and behind it; a hot-swap
+        retires them all first, so weights change with nothing in
+        flight, and so does the loop's exit."""
         import zmq
 
         while stop_event is None or not stop_event.is_set():

@@ -159,9 +159,10 @@ REPLAY_EVENTS = (
 #: two running quantities in microseconds, the one record of two
 #: phases of the server's single thread (the same intervals are the
 #: ``serve.prefill`` and ``serve.idle`` spans of a profiler trace):
-#: ``serve_prefill_us`` — spent inside ``model.prefill_rows`` (over
-#: ``serve_prefills``: one prefill; over the wall: the share in which
-#: no tick could start);
+#: ``serve_prefill_us`` — the thread's time in prefills: a prefill's
+#: dispatch where its reset is admitted plus whatever the thread waited
+#: where its reply is fetched (over ``serve_prefills``: one prefill;
+#: over the wall: the share in which the thread could do nothing else);
 #: ``serve_idle_us`` — spent polling with nothing queued (over
 #: ``serve_batches``: the clients' turnaround per tick);
 #: ``serve_pool_rebuilds`` — times a donated step or prefill failed
@@ -181,7 +182,13 @@ REPLAY_EVENTS = (
 #: computes on the host);
 #: ``serve_fetch_wait_us`` — microseconds the server's thread was
 #: blocked fetching a launched tick's reply (waiting for the device):
-#: the one place it waits for it.
+#: the one place it waits for a tick (a launched prefill's wait is in
+#: ``serve_prefill_us``);
+#: ``serve_prefills_overlapped`` — prefills (of ``serve_prefills``)
+#: that did not have the device's queue to themselves: something
+#: launched was still unfetched when the prefill was dispatched, or
+#: something was dispatched behind it before its reply was fetched (0
+#: for a lone client or a model that computes on the host).
 #: ``serve_ctx_positions`` / ``serve_rows_stepped`` /
 #: ``serve_window_positions`` / ``serve_state_resets`` — a served model
 #: of mixed layer kinds (``HYBRID_EVENTS`` in blendjax/serve/server.py):
@@ -201,6 +208,7 @@ SERVE_EVENTS = (
     "serve_moe_assignments", "serve_moe_assignments_held",
     "serve_moe_experts_hit",
     "serve_ticks_overlapped", "serve_fetch_wait_us",
+    "serve_prefills_overlapped",
     "serve_ctx_positions", "serve_rows_stepped", "serve_window_positions",
     "serve_state_resets", "serve_state_bytes",
 )

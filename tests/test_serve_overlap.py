@@ -1,14 +1,18 @@
-"""One serve tick in flight (docs/serving.md "One tick in flight").
+"""One serve tick in flight, and the prefills beside it (docs/serving.md
+"One tick in flight", "Batched prefill admission").
 
 ``PolicyServer`` launches a tick (assemble + dispatch) and retires it
 (fetch + replies) as two halves, and keeps at most one launched tick
 between two turns of its loop, so admission, the next launch and the
-older tick's replies run beside the device.  Locked here, on the CPU,
-with a stub model whose replies become ready when the test says so: the
-overlap itself and its counter, the lone client's unchanged sequence,
+older tick's replies run beside the device.  A ``reset``'s prefill is
+launched the same way where the reset is admitted, behind whatever is in
+flight, and answered where it is retired.  Locked here, on the CPU, with
+a stub model whose replies become ready when the test says so: the
+overlap itself and its counters, the lone client's unchanged sequence,
 and the guarantees the overlap could break — exactly-once, the version
 stamps, the lost pool, the leases.  Then real models: concurrent
-closed-loop clients get exactly what serial decode gives.
+closed-loop clients get exactly what serial decode gives, and what a
+fenced twin gives when they reset with prefixes.
 
 Every wait in this file is bounded: a test that cannot finish fails.
 """
@@ -49,8 +53,11 @@ class _Gated:
 
 class StubModel:
     """``pred = w * sum(obs) + pos``, computed where the step is made;
-    the reply is handed out gated.  ``calls`` holds (real rows, reply)
-    in dispatch order, ``log`` every call that touched a row."""
+    the reply is handed out gated, and so is a prefill's (``w`` times
+    the sum of the prefix's last row).  ``calls`` holds the steps' (real
+    rows, reply) and ``prefills`` the prefills' (slot, reply), each in
+    dispatch order; ``log`` every call that touched a row, in the order
+    the device would run them."""
 
     kind = "stub"
     obs_dim = 2
@@ -60,8 +67,10 @@ class StubModel:
         self.pos = np.zeros(slots + 1, np.int64)
         self.w = 1.0
         self.calls = []
+        self.prefills = []
         self.log = []
         self.errors = {}  # call number -> what its fetch raises
+        self.prefill_errors = {}  # prefill number -> what its fetch raises
         self.pool_rebuilds = 0
 
     def reset_rows(self, idx):
@@ -71,11 +80,17 @@ class StubModel:
     def apply_weights(self, tree):
         self.w = float(tree["w"])
 
-    def prefill_rows(self, idx, prefix):
+    def prefill_reply(self, idx, prefix):
         self.pos[idx] = len(prefix)
-        self.log.append(("prefill", tuple(int(i) for i in idx),
-                         [reply.gate.is_set() for _, reply in self.calls]))
-        return prefix[-1:].sum(-1) * np.float32(self.w)
+        slot = int(idx[0])
+        reply = _Gated(prefix[-1:].sum(-1) * np.float32(self.w),
+                       self.prefill_errors.get(len(self.prefills)))
+        self.prefills.append((slot, reply))
+        self.log.append(("prefill", (slot,)))
+        return reply
+
+    def prefill_rows(self, idx, prefix):
+        raise AssertionError("the server fences no prefill where it is made")
 
     def step_rows(self, idx, obs):
         rows = (self.w * obs.sum(-1, keepdims=True)
@@ -135,13 +150,14 @@ def served():
         try:
             yield model, counters, h, a, b
         finally:
-            for _, reply in model.calls:
+            for _, reply in model.calls + model.prefills:
                 reply.gate.set()
             a.close()
             b.close()
 
 
 OBS = np.asarray([1.0, 2.0], np.float32)
+PREFIX = np.asarray([[9.0, 9.0], [0.5, 0.25], [1.0, 3.0]], np.float32)
 
 
 def test_second_tick_is_launched_before_the_first_is_fetched(served):
@@ -363,25 +379,292 @@ def test_a_slot_reused_in_flight_answers_the_old_step_and_no_stale_one(
         c.close()
 
 
-def test_a_prefill_waits_for_no_launched_ticks_answers(served):
-    """A prefill blocks the server's thread: whatever was launched is
-    answered before it starts, as when prefills ran between ticks."""
+# -- a reset's prefill is launched like a tick --------------------------------
+
+
+def _quiet(reply, for_s=0.1):
+    """Nobody went to this reply's fetch for a while."""
+    return not reply.fetching.wait(for_s)
+
+
+def test_a_prefill_behind_a_tick_neither_fetches_nor_delays_it(
+        served):
     model, counters, h, a, b = served
     call = _Call(a.step, OBS)
     _until(lambda: len(model.calls) == 1, "a's tick")
+    tick = model.calls[0][1]
     newcomer = _client(h)
-    admitted = _Call(newcomer.reset, np.ones((3, 2), np.float32))
-    # the reset is read with a's tick in flight and goes to its fetch
-    assert model.calls[0][1].fetching.wait(WAIT_S)
-    assert not [e for e in model.log if e[0] == "prefill"]
+    admitted = _Call(newcomer.reset, PREFIX)
+    # the prefill is dispatched with a's tick in flight: nobody fetched
+    # that tick for it, and the device runs step, rewind, prefill
+    _until(lambda: len(model.prefills) == 1, "the prefill's dispatch")
+    slot, prefill = model.prefills[0]
+    assert not tick.gate.is_set() and _quiet(tick)
+    assert model.log[-3:] == [("step", (a.slot,)), ("reset", (slot,)),
+                              ("prefill", (slot,))]
+    assert counters.get("serve_batches") == 0
+    # the tick's answer does not wait for the prefill behind it
+    tick.gate.set()
+    assert call.result()["pos"] == 0
+    assert not prefill.gate.is_set() and admitted.thread.is_alive()
+    assert counters.get("serve_resets") == 2  # a's and b's: not this one yet
+    prefill.gate.set()
+    reply = admitted.result()
+    assert reply["slot"] == slot and reply["pos"] == 3
+    assert reply["pred"] == 4.0
+    # no step of the episode could have run before its prefill: the
+    # client learnt the slot from that reply
+    nxt = _Call(newcomer.step, OBS)
+    _until(lambda: len(model.calls) == 2, "the newcomer's tick")
+    model.calls[1][1].gate.set()
+    assert nxt.result()["pos"] == 3
+    snap = counters.snapshot()
+    assert snap["serve_prefills"] == 1 and snap["serve_resets"] == 3
+    assert snap["serve_prefills_overlapped"] == 1
+    assert snap["serve_prefill_us"] > 0
+    newcomer.close()
+
+
+def test_a_tick_is_dispatched_behind_an_unfetched_prefill(
+        served):
+    model, counters, h, a, b = served
+    newcomer = _client(h)
+    admitted = _Call(newcomer.reset, PREFIX)
+    _until(lambda: len(model.prefills) == 1, "the prefill's dispatch")
+    prefill = model.prefills[0][1]
+    # a and b are everybody who can send: their tick is launched behind
+    # the prefill, whose reply nobody could have fetched
+    first, second = _Call(a.step, OBS), _Call(b.step, OBS)
+    _until(lambda: sum(len(rows) for rows, _ in model.calls) == 2,
+           "a's and b's steps behind the prefill")
+    assert not prefill.gate.is_set() and admitted.thread.is_alive()
+    # ... and then the server waits in the OLDEST entry's fetch: the
+    # prefill's, though the tick behind it is ready first
+    assert prefill.fetching.wait(WAIT_S)
+    for _, reply in model.calls:
+        reply.gate.set()
+    assert _quiet(model.calls[0][1])
+    assert first.thread.is_alive() and second.thread.is_alive()
+    assert counters.get("serve_batches") == 0
+    prefill.gate.set()
+    assert admitted.result()["pos"] == 3
+    assert first.result()["pos"] == 0 and second.result()["pos"] == 0
+    snap = counters.snapshot()
+    # overlapped: a tick was dispatched behind it before its fetch; and
+    # a tick behind a PREFILL is not a tick behind a tick
+    assert snap["serve_prefills_overlapped"] == 1
+    assert snap["serve_ticks_overlapped"] == 0
+    newcomer.close()
+
+
+def test_a_lone_prefill_is_retired_at_once_and_overlaps_nothing():
+    model, counters = StubModel(), EventCounters()
+    with start_server_thread(model, counters=counters,
+                             tick_ms=5000.0) as h:
+        c = _client(h)
+        t0 = time.monotonic()
+        admitted = _Call(c.reset, PREFIX)
+        _until(lambda: len(model.prefills) == 1, "the prefill")
+        prefill = model.prefills[0][1]
+        assert prefill.fetching.wait(2.0), "the server did not go to fetch"
+        prefill.gate.set()
+        assert admitted.result()["pos"] == 3
+        assert time.monotonic() - t0 < 2.5  # far inside tick_ms
+        snap = counters.snapshot()
+        assert snap["serve_prefills"] == 1
+        assert snap.get("serve_prefills_overlapped", 0) == 0
+        c.close()
+
+
+def test_a_retry_of_a_reset_in_flight_runs_one_prefill_in_one_slot(served):
+    model, counters, h, a, b = served
+    retrying = ServeClient(
+        h.address, timeoutms=150,
+        fault_policy=FaultPolicy(max_retries=30, backoff_base=0.01,
+                                 backoff_max=0.02, circuit_threshold=0,
+                                 seed=1))
+    admitted = _Call(retrying.reset, PREFIX)
+    _until(lambda: counters.get("serve_dup_inflight") >= 1,
+           "a retry to meet its prefill in flight")
+    assert len(model.prefills) == 1
+    stats = a.stats()
+    assert stats["live_slots"] == 3 and stats["free_slots"] == 1
+    model.prefills[0][1].gate.set()
+    reply = admitted.result()
+    assert reply["pos"] == 3 and reply["slot"] == model.prefills[0][0]
+    # a late duplicate is answered from the reply cache: nothing re-ran
+    time.sleep(0.2)
+    assert len(model.prefills) == 1
+    assert [e for e in model.log if e[0] == "prefill"] == [
+        ("prefill", (reply["slot"],))]
+    stats = a.stats()
+    assert stats["live_slots"] == 3 and stats["free_slots"] == 1
+    snap = counters.snapshot()
+    assert snap["serve_prefills"] == 1 and snap["serve_resets"] == 3
+    assert snap.get("serve_errors", 0) == 0
+    retrying.close()
+
+
+def test_a_prefill_whose_fetch_fails_frees_its_slot_and_answers_the_error(
+        served):
+    model, counters, h, a, b = served
+    model.prefill_errors = {0: RuntimeError("device fault (injected)")}
+    newcomer = _client(h)
+    admitted = _Call(newcomer.reset, PREFIX)
+    _until(lambda: len(model.prefills) == 1, "the prefill's dispatch")
+    assert a.stats()["live_slots"] == 3
+    model.prefills[0][1].gate.set()
+    with pytest.raises(RuntimeError, match="prefill failed.*device fault"):
+        admitted.result()
+    stats = a.stats()
+    assert stats["live_slots"] == 2 and stats["free_slots"] == 2
+    snap = counters.snapshot()
+    assert snap["serve_errors"] == 1
+    assert snap.get("serve_prefills", 0) == 0 and snap["serve_resets"] == 2
+    assert snap.get("serve_pool_rebuilds", 0) == 0
+    # the others' episodes stand, and the slot is given out again
+    call = _Call(a.step, OBS)
+    _until(lambda: len(model.calls) == 1, "a's tick")
     model.calls[0][1].gate.set()
     assert call.result()["pos"] == 0
-    reply = admitted.result()
-    assert reply["pos"] == 3
-    (prefill,) = [e for e in model.log if e[0] == "prefill"]
-    assert prefill[2] == [True]  # a's reply had been fetched by then
-    assert counters.get("serve_prefills") == 1
+    again = _Call(newcomer.reset, PREFIX)
+    _until(lambda: len(model.prefills) == 2, "the second prefill")
+    model.prefills[1][1].gate.set()
+    assert again.result()["slot"] == model.prefills[0][0]
     newcomer.close()
+
+
+@pytest.mark.parametrize("lost", ["prefill", "tick"])
+def test_a_pool_lost_at_a_fetch_fails_what_was_launched_behind_it_once(
+        served, lost):
+    """The first reply off a lost pool raises SlotPoolLost, a later one
+    re-raises plainly (as SeqFormerModel does it): whichever of a tick
+    and a prefill is the older takes the other with it, once."""
+    model, counters, h, a, b = served
+    newcomer = _client(h)
+    first, second = (SlotPoolLost("device fault (injected)"),
+                     RuntimeError("device fault (injected)"))
+    if lost == "prefill":
+        model.prefill_errors, model.errors = {0: first}, {0: second}
+        admitted = _Call(newcomer.reset, PREFIX)
+        _until(lambda: len(model.prefills) == 1, "the prefill")
+        calls = [_Call(a.step, OBS), _Call(b.step, OBS)]
+        _until(lambda: sum(len(rows) for rows, _ in model.calls) == 2,
+               "the tick behind the prefill")
+    else:
+        model.errors, model.prefill_errors = {0: first}, {0: second}
+        calls = [_Call(a.step, OBS)]
+        _until(lambda: len(model.calls) == 1, "a's tick")
+        admitted = _Call(newcomer.reset, PREFIX)
+        _until(lambda: len(model.prefills) == 1, "the prefill behind it")
+    older, younger = ((model.prefills[0][1], model.calls[0][1])
+                      if lost == "prefill" else
+                      (model.calls[0][1], model.prefills[0][1]))
+    for _, reply in model.calls + model.prefills:
+        reply.gate.set()
+    with pytest.raises(RuntimeError, match="prefill failed"):
+        admitted.result()
+    for call in calls:
+        with pytest.raises(RuntimeError, match="batched step failed"):
+            call.result()
+    # the younger entry's reply was never fetched: it went with the pool
+    assert older.fetching.is_set() and not younger.fetching.is_set()
+    snap = counters.snapshot()
+    assert snap["serve_pool_rebuilds"] == 1
+    assert snap["serve_errors"] == 1 + len(calls)
+    assert snap.get("serve_batches", 0) == 0
+    assert snap.get("serve_prefills", 0) == 0
+    stats = a.stats()
+    assert stats["live_slots"] == 0 and stats["free_slots"] == model.slots
+    with pytest.raises(RuntimeError, match="unknown episode slot"):
+        a.step(OBS)
+    # and it admits again on the new pool
+    again = _Call(newcomer.reset, PREFIX)
+    _until(lambda: len(model.prefills) == 2, "a prefill on the new pool")
+    model.prefills[1][1].gate.set()
+    assert again.result()["pos"] == 3
+    assert counters.get("serve_pool_rebuilds") == 1
+    newcomer.close()
+
+
+def test_a_snapshot_staged_with_a_prefill_in_flight_is_adopted_after_it():
+    model, counters = StubModel(), EventCounters()
+    with start_server_thread(model, counters=counters, tick_ms=2.0) as h:
+        a, b = _client(h), _client(h)
+        a.reset()
+        bus = h.server.subscriber = _StagedWhileFlying(h.server)
+        _until(lambda: h.server.weight_version == 1, "version 1")
+        admitted = _Call(b.reset, PREFIX)
+        _until(lambda: len(model.prefills) == 1, "the prefill")
+        # the snapshot is staged with the prefill in flight; it is not
+        # adopted until the reset has been answered
+        _until(lambda: bus.staged_with_flying, "version 2 to be staged")
+        time.sleep(0.05)
+        assert h.server.weight_version == 1 and model.w == 10.0
+        model.prefills[0][1].gate.set()
+        out = admitted.result()
+        assert out["weight_version"] == 1
+        assert out["pred"] == 40.0  # executed under version 1
+        _until(lambda: h.server.weight_version == 2, "version 2")
+        b.close_episode()
+        nxt = _Call(b.reset, PREFIX)
+        _until(lambda: len(model.prefills) == 2, "the next prefill")
+        model.prefills[1][1].gate.set()
+        out = nxt.result()
+        assert out["weight_version"] == 2 and out["pred"] == 400.0
+        assert counters.get("weight_adopted") == 2
+        a.close()
+        b.close()
+
+
+def test_a_slot_refilled_in_flight_is_rewound_behind_the_old_step(
+        served):
+    model, counters, h, a, b = served
+    slot, episode = a.slot, a.episode
+    bystander = _client(h)  # so that somebody can still send afterwards
+    bystander.reset()
+    call = _Call(a.step, OBS)
+    _until(lambda: len(model.calls) == 1, "a's tick")
+    janitor, tenant = _client(h), _client(h)
+    assert janitor.rpc("close", {"slot": slot, "episode": episode})["closed"]
+    admitted = _Call(tenant.reset, PREFIX)
+    _until(lambda: len(model.prefills) == 1, "the tenant's prefill")
+    assert model.prefills[0][0] == slot
+    # rewind and prefill are ordered behind the step that was in flight
+    assert model.log[-3:] == [("step", (slot,)), ("reset", (slot,)),
+                              ("prefill", (slot,))]
+    model.calls[0][1].gate.set()
+    out = call.result()
+    assert out["pred"][0] == 3.0 and out["pos"] == 0  # the old step's answer
+    model.prefills[0][1].gate.set()
+    reply = admitted.result()
+    assert reply["slot"] == slot and reply["episode"] != episode
+    with pytest.raises(RuntimeError, match="stale episode lease"):
+        a.step(OBS)  # a's lease is gone: it cannot step the new tenant
+    nxt = _Call(tenant.step, OBS)
+    _until(lambda: len(model.calls) == 2, "the tenant's tick")
+    model.calls[1][1].gate.set()
+    assert nxt.result()["pos"] == 3
+    for c in (janitor, tenant, bystander):
+        c.close()
+
+
+def test_a_model_whose_prefill_returns_an_array_is_answered_at_once():
+    """``LinearModel`` and stubs: an array is a reply that is always
+    ready, so nothing is launched for it and nothing deferred."""
+    from blendjax.serve.server import LinearModel
+
+    counters = EventCounters()
+    with start_server_thread(LinearModel(obs_dim=2, slots=2),
+                             counters=counters) as h:
+        c = _client(h)
+        reply = c.reset(prefix=PREFIX)
+        assert reply["pos"] == 3 and not h.server._launched
+        assert c.step(OBS)["pos"] == 3
+        snap = counters.snapshot()
+        assert snap["serve_prefills"] == 1 and snap["serve_resets"] == 1
+        assert snap.get("serve_prefills_overlapped", 0) == 0
+        c.close()
 
 
 # -- the model's half: a reply is fetched when asked, a lost pool once -------
@@ -456,7 +739,9 @@ def test_two_replies_off_one_lost_pool_cost_one_rebuild():
 
 def _drive(model, episodes, rounds, *, max_batch, prefix_of=None):
     """``len(episodes)`` closed-loop clients, each running its
-    ``rounds`` episodes one after another; returns the predictions per
+    ``rounds`` episodes one after another (the first ``prefix_of(i, r)``
+    positions of an episode admitted as the reset's prefix, the reply's
+    prediction first among its outputs); returns the predictions per
     client and episode, and the server's counters."""
     counters = EventCounters()
     outs = [[[] for _ in range(rounds)] for _ in episodes]
@@ -468,8 +753,14 @@ def _drive(model, episodes, rounds, *, max_batch, prefix_of=None):
             try:
                 for r in range(rounds):
                     ep = episodes[i][r]
-                    c.reset()
-                    for t in range(len(ep)):
+                    t0 = prefix_of(i, r) if prefix_of else 0
+                    if t0:
+                        reply = c.reset(prefix=ep[:t0])
+                        assert reply["pos"] == t0
+                        outs[i][r].append(reply["pred"])
+                    else:
+                        c.reset()
+                    for t in range(t0, len(ep)):
                         outs[i][r].append(c.step(ep[t])["pred"])
                     assert c.close_episode() is True
             except Exception as exc:  # noqa: BLE001 - handed to the test
@@ -517,6 +808,88 @@ def test_concurrent_clients_get_what_serial_decode_gives():
     assert snap.get("serve_errors", 0) == 0
     assert snap.get("serve_pool_rebuilds", 0) == 0
     assert "serve_ticks_overlapped" in snap
+
+
+def _tiny_linear_attention_model(slots):
+    """Two periods of three gated delta-rule layers (a float32 matrix
+    state a head, convolution tails) and one full-attention layer: what
+    a reset has to zero, and a prefill goes on from."""
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+    from blendjax.serve.server import SeqFormerModel
+    from chipbench import reference_olmohybrid as ref
+
+    widths = dict(
+        hidden_size=64, intermediate_size=128, num_hidden_layers=8,
+        num_attention_heads=2, num_key_value_heads=2,
+        layer_types=(["linear_attention"] * 3 + ["full_attention"]) * 2,
+        linear_num_key_heads=2, linear_num_value_heads=2,
+        linear_key_head_dim=8, linear_value_head_dim=16,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+        rms_norm_eps=1e-6, vocab_size=128, tie_word_embeddings=False)
+    served = seqformer.describe_token_model(
+        ref.make_params(widths, 5, jnp.float32), widths)
+
+    def build():
+        return SeqFormerModel(served, slots=slots, length=32,
+                              compute_dtype=jnp.float32,
+                              cache_dtype=jnp.float32)
+
+    rng = np.random.default_rng(6)
+    return build, lambda n: rng.integers(0, 128, (n, 1)).astype(np.int32)
+
+
+def _tiny_seqformer(slots):
+    params, _ = _tiny_model()
+    from blendjax.serve.server import SeqFormerModel
+
+    rng = np.random.default_rng(7)
+    return (lambda: SeqFormerModel(params, slots=slots, length=16),
+            lambda n: rng.standard_normal((n, 5), np.float32))
+
+
+@pytest.mark.parametrize("tiny,atol", [
+    (_tiny_seqformer, 1e-5),
+    # through eight layers with a norm after each, a batched step lies
+    # up to ~1e-3 from the row stepped alone; a state left behind by the
+    # slot's last tenant reads above 0.05 (test_linear_attention_model.py)
+    (_tiny_linear_attention_model, 5e-3),
+], ids=["seqformer", "recurrent_state"])
+def test_prefixed_resets_among_steppers_get_what_a_fenced_twin_gives(
+        tiny, atol):
+    """Every slot is reused twice, every reset (a rewind, and for the
+    recurrent model a zeroing of state) and its prefill dispatched
+    behind whatever tick the others have in flight; the twin fences
+    each call where it makes it."""
+    n, rounds = 5, 3
+    build, draw = tiny(n)
+    episodes = [[draw(6 + (2 * i + 3 * r) % 7) for r in range(rounds)]
+                for i in range(n)]
+
+    def prefix_of(i, r):
+        return 2 + (i + r) % 4
+
+    outs, snap = _drive(build(), episodes, rounds, max_batch=4,
+                        prefix_of=prefix_of)
+    twin, row = build(), np.asarray([0])
+    for i, client in enumerate(episodes):
+        for r, ep in enumerate(client):
+            t0 = prefix_of(i, r)
+            twin.reset_rows(row)
+            want = [twin.prefill_rows(row, ep[:t0])]
+            want += [np.asarray(twin.step_rows(row, ep[t][None]))[0]
+                     for t in range(t0, len(ep))]
+            assert len(outs[i][r]) == len(want)
+            for got, ref in zip(outs[i][r], want):
+                np.testing.assert_allclose(got, ref, atol=atol, rtol=atol)
+    assert snap["serve_prefills"] == snap["serve_resets"] == n * rounds
+    assert 1 <= snap["serve_prefills_overlapped"] <= n * rounds
+    assert snap.get("serve_errors", 0) == 0
+    assert snap.get("serve_pool_rebuilds", 0) == 0
+    if "serve_state_resets" in snap:
+        assert snap["serve_state_resets"] == n * rounds
 
 
 def test_concurrent_token_clients_and_the_routed_counts_match_serial():

@@ -156,11 +156,34 @@ def test_tick_phases_lie_inside_a_tick(served):
     # compute, the fence where the fetch happens
     assert _inside(served, "serve.step.dispatch", "serve.tick.compute")
     assert _inside(served, "serve.step.fence", "serve.retire")
-    # every launched tick was retired, in order
+    # every launched tick was retired, in order (a retire with ``rows``
+    # is a tick's; one with ``prefill`` is a launched prefill's)
     launches = sorted(lo for lo, _, _ in served.events["serve.tick.compute"])
-    retires = sorted(lo for lo, _, _ in served.events["serve.retire"])
+    retires = sorted(lo for lo, _, st in served.events["serve.retire"]
+                     if "rows" in st)
     assert len(launches) == len(retires)
     assert all(a < b for a, b in zip(launches, retires))
+
+
+def test_a_launched_prefill_is_dispatched_at_admission_and_fenced_at_retire(
+        served):
+    # the SeqFormer's reset(prefix=) of 3: ``serve.prefill`` spans the
+    # dispatch, inside admission; the fence opens where the reply is
+    # fetched, inside the retire that answers the reset
+    assert _inside(served, "serve.prefill.dispatch", "serve.prefill")
+    assert _inside(served, "serve.prefill", "serve.admit")
+    assert _inside(served, "serve.prefill.fence", "serve.retire")
+    prefills = [(lo, hi) for lo, hi, st in served.events["serve.prefill"]
+                if st.get("len") == 3]
+    fences = served.events["serve.prefill.fence"]
+    retired = [(lo, hi) for lo, hi, st in served.events["serve.retire"]
+               if st.get("prefill") == 3]
+    assert len(prefills) == len(fences) == len(retired) == 1
+    assert prefills[0][1] <= retired[0][0] <= fences[0][0]
+    # a model that computes on the host launches nothing: the linear
+    # server's prefill of 5 has no retire of its own
+    assert not [st for _, _, st in served.events["serve.retire"]
+                if st.get("prefill") == 5]
 
 
 def test_span_arguments_become_event_stats(served):
@@ -172,13 +195,15 @@ def test_span_arguments_become_event_stats(served):
 
 def test_prefill_and_idle_counters(served):
     for name in ("serve_prefill_us", "serve_idle_us",
-                 "serve_ticks_overlapped", "serve_fetch_wait_us"):
+                 "serve_ticks_overlapped", "serve_fetch_wait_us",
+                 "serve_prefills_overlapped"):
         assert name in SERVE_EVENTS
         # the hub zero-fills them before any server has reported
         assert TelemetryHub().scrape()["counters"][name] == 0
     # one client: no tick was ever launched behind another, and the
     # program says so (the key is there, at 0)
     assert served.after["serve_ticks_overlapped"] == 0
+    assert served.after["serve_prefills_overlapped"] == 0
     assert served.before.get("serve_prefill_us", 0) == 0
     assert served.after["serve_prefills"] == 1
     assert 0 < served.after["serve_prefill_us"] <= served.wall_us
