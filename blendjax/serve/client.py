@@ -30,7 +30,9 @@ import time
 
 import numpy as np
 
+from blendjax import wire
 from blendjax.btt.faults import FaultPolicy
+from blendjax.obs.spans import now_us
 from blendjax.utils.timing import fleet_counters
 
 logger = logging.getLogger("blendjax")
@@ -113,6 +115,11 @@ class ServeClient:
         self._fallback_failures = 0
         self._fallback_backoff_s = float(fallback_backoff_s)
         self._fallback_backoff_max_s = float(fallback_backoff_max_s)
+        #: the server's send stamp on the last reply (``wire.
+        #: SENT_US_KEY``), carried back on the next request so that the
+        #: server can count this client's turnaround; None after a
+        #: failed RPC
+        self._reply_sent_us = None
 
     def _channel(self):
         if self._chan is None:
@@ -149,6 +156,12 @@ class ServeClient:
 
         msg = dict(payload or {})
         msg["cmd"] = cmd
+        # the send stamp (a retry re-sends it: the server counts a
+        # retry's wire time nowhere), and the last reply's
+        msg[wire.SENT_US_KEY] = now_us()
+        if self._reply_sent_us is not None:
+            msg[wire.REPLY_SENT_US_KEY] = self._reply_sent_us
+        self._reply_sent_us = None
         # the last replica (gateway-stamped) and weight version
         # (bus-stamped) that answered ride the transport-error text and
         # the client span: when a fleet or a rollout misbehaves, the
@@ -210,6 +223,7 @@ class ServeClient:
                 self._fallback_failures += 1
             raise
         self._fallback_failures = 0
+        self._reply_sent_us = reply.pop(wire.SENT_US_KEY, None)
         rep = reply.get("replica")
         if rep is not None:
             self.replica = rep

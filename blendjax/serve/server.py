@@ -314,6 +314,110 @@ HYBRID_EVENTS = ("serve_ctx_positions", "serve_rows_stepped",
                  "serve_state_bytes")
 
 
+#: The phase clock's phases (``_PhaseClock``), each a counter in
+#: ``SERVE_EVENTS`` in microseconds: exclusive, and together the serve
+#: loop's wall time (docs/serving.md "The phase clock").  The last,
+#: ``serve_loop_us``, is the loop's own code between the others.
+SERVE_PHASES = ("serve_idle_us", "serve_poll_us", "serve_slice_us",
+                "serve_admit_us", "serve_prefill_dispatch_us",
+                "serve_assemble_us", "serve_dispatch_us",
+                "serve_fetch_wait_us", "serve_reply_us", "serve_weights_us",
+                "serve_loop_us")
+(_IDLE, _POLL, _SLICE, _ADMIT, _PREFILL, _ASSEMBLE, _DISPATCH, _FETCH,
+ _REPLY, _WEIGHTS, _LOOP) = SERVE_PHASES
+#: the phases in which the thread waits on the clients
+_WAITS = frozenset((_IDLE, _POLL))
+
+
+class _PhaseClock:
+    """``PolicyServer``'s thread's wall time cut into the exclusive
+    :data:`SERVE_PHASES`.  One ``perf_counter_ns`` a switch: the time
+    since the last switch goes to the phase being left, and to
+    ``serve_drained_us`` (and, for a wait on the clients,
+    ``serve_drained_wait_us``) where nothing was launched through it,
+    which ``drained()`` says at each switch (a fetch never is: the entry
+    being fetched was launched).  The nanoseconds are pushed into the
+    counters as whole microseconds, the rest carried, at the first
+    switch a millisecond after the last push (so a phase of a
+    millisecond or more is in the counters when it ends) and at
+    :meth:`settle`: the phases add up to the wall time however short
+    they are.  Used by one thread; :meth:`__call__` makes a phase a
+    ``with`` block and the span of its name."""
+
+    __slots__ = ("_counters", "_drained", "_ns", "_pushed", "_t", "_empty",
+                 "phase")
+
+    def __init__(self, counters, drained):
+        self._counters, self._drained = counters, drained
+        # counter -> nanoseconds not yet pushed
+        self._ns = dict.fromkeys(
+            SERVE_PHASES + ("serve_drained_us", "serve_drained_wait_us"), 0)
+        self.restart()
+
+    def restart(self):
+        """Count from now, in the loop's own phase."""
+        self.phase = _LOOP
+        self._empty = self._drained()
+        self._t = self._pushed = time.perf_counter_ns()
+
+    def switch(self, phase):
+        """Enter ``phase``; returns the phase left."""
+        now = time.perf_counter_ns()
+        ns, self._t = now - self._t, now
+        left, acc = self.phase, self._ns
+        acc[left] += ns
+        if self._empty:
+            acc["serve_drained_us"] += ns
+            if left in _WAITS:
+                acc["serve_drained_wait_us"] += ns
+        if now - self._pushed >= 1_000_000:
+            self._push(now)
+        self.phase = phase
+        self._empty = phase != _FETCH and self._drained()
+        return left
+
+    def _push(self, now):
+        self._pushed = now
+        acc, due = self._ns, []
+        for name, ns in acc.items():
+            if ns >= 1000:
+                us, acc[name] = divmod(ns, 1000)
+                due.append((name, us))
+        if due:
+            self._counters.incr_many(due)
+
+    def settle(self):
+        """Push everything counted up to now (before a snapshot)."""
+        self.switch(self.phase)
+        self._push(self._t)
+
+    def __call__(self, phase, name=None, **args):
+        """``with clock(phase, name, **args):`` the block is ``phase``
+        (the enclosing one resumes after it) and, with a ``name``, the
+        :func:`~blendjax.utils.timing.span` of that name."""
+        return _Phase(self, phase, None if name is None else span(
+            name, **args))
+
+
+class _Phase:
+    __slots__ = ("_clock", "_phase", "_span", "_left")
+
+    def __init__(self, clock, phase, span_):
+        self._clock, self._phase, self._span = clock, phase, span_
+
+    def __enter__(self):
+        if self._span is not None:
+            self._span.__enter__()
+        self._left = self._clock.switch(self._phase)
+        return self._span
+
+    def __exit__(self, *exc):
+        self._clock.switch(self._left)
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        return False
+
+
 class SlotPoolLost(RuntimeError):
     """A donated call failed after it had taken the slot pool: the
     model holds a fresh, EMPTY pool and every lease on it is void (the
@@ -848,6 +952,8 @@ class PolicyServer:
         # yet answered, in dispatch order: at most one TICK between two
         # turns of the serve loop, two inside a turn
         self._launched = deque()
+        self._clock = _PhaseClock(self.counters,
+                                  lambda: not self._launched)
         # Slot pools live per hosted model (:class:`_ModelState`):
         # ``live`` maps slot -> [episode lease id, monotonic last-use].
         # The lease id disambiguates slot REUSE: an evicted episode's
@@ -1080,20 +1186,24 @@ class PolicyServer:
         dispatch = getattr(st.model, "prefill_reply", st.model.prefill_rows)
         behind = bool(self._launched)
         t0 = time.perf_counter()
-        try:
-            with span("serve.prefill", len=int(prefix.shape[0])):
+        # the entry joins ``_launched`` inside the phase, so that the
+        # clock's next switch sees it launched
+        with self._clock(_PREFILL, "serve.prefill",
+                         len=int(prefix.shape[0])):
+            try:
                 reply = dispatch(np.asarray([slot]), prefix)
-        except Exception as exc:  # noqa: BLE001 - surfaced to client
-            logger.exception("policy server: prefill failed")
-            if isinstance(exc, SlotPoolLost):
-                self._pool_lost(st)
-            return fail(f"prefill failed: {type(exc).__name__}: {exc}")
-        ent = _Prefilling(st, reply, slot, episode, int(prefix.shape[0]),
-                          behind, time.perf_counter() - t0)
-        if not hasattr(reply, "is_ready"):
-            return self._prefilled(ent, reply, overlapped=False)
-        self._launched.append(ent)
-        return ent
+            except Exception as exc:  # noqa: BLE001 - surfaced to client
+                logger.exception("policy server: prefill failed")
+                if isinstance(exc, SlotPoolLost):
+                    self._pool_lost(st)
+                return fail(f"prefill failed: {type(exc).__name__}: {exc}")
+            ent = _Prefilling(st, reply, slot, episode,
+                              int(prefix.shape[0]), behind,
+                              time.perf_counter() - t0)
+            if hasattr(reply, "is_ready"):
+                self._launched.append(ent)
+                return ent
+        return self._prefilled(ent, reply, overlapped=False)
 
     def _prefilled(self, ent, pred, overlapped):
         """Count a prefill that ran to its end (``overlapped``: it
@@ -1159,9 +1269,14 @@ class PolicyServer:
                 }
                 for s in self._models.values()
             },
-            "counters": self.counters.snapshot(),
+            "counters": self._settled_counters(),
             "pid": os.getpid(),
         }
+
+    def _settled_counters(self):
+        """The counters, the phase clock's running phase pushed first."""
+        self._clock.settle()
+        return self.counters.snapshot()
 
     def _cmd_telemetry(self, msg):
         """This process's telemetry in the TelemetryHub merge shape —
@@ -1192,7 +1307,7 @@ class PolicyServer:
                 **self._device,
             },
             "pid": os.getpid(),
-            "counters": self.counters.snapshot(),
+            "counters": self._settled_counters(),
             "stages": self.timer.snapshot_serialized(),
         }
 
@@ -1220,9 +1335,14 @@ class PolicyServer:
         under it, and every reply (a prefilling reset's too) is stamped
         (``_finish``) with the version that executed it.  A snapshot
         the model refuses (structure/shape drift) is discarded and
-        counted; the last good version keeps serving either way."""
+        counted; the last good version keeps serving either way.  The
+        whole of it, the poll too, is the phase ``serve_weights_us``."""
         if self.subscriber is None:
             return
+        with self._clock(_WEIGHTS, "serve.weights"):
+            self._adopt_weights()
+
+    def _adopt_weights(self):
         snap = self.subscriber.poll()
         if snap is None:
             return
@@ -1244,8 +1364,7 @@ class PolicyServer:
                     f"snapshot for unhosted model {target!r} "
                     f"(hosted: {sorted(self._models)})"
                 )
-            with span("serve.weights"):
-                st.model.apply_weights(snap.tree())
+            st.model.apply_weights(snap.tree())
         except Exception as exc:  # noqa: BLE001 - keep serving last good
             self.counters.incr("weight_apply_failed")
             logger.warning(
@@ -1335,6 +1454,9 @@ class PolicyServer:
     def _send(self, ident, reply, ding=True):
         import zmq
 
+        # the send stamp the client's next request carries back
+        # (``serve_client_turn_us``); a cached reply is stamped anew
+        reply[wire.SENT_US_KEY] = now_us()
         if ident is not None and getattr(ident, "shm_channel", False):
             # the request arrived over shm: the reply goes back down
             # the same channel (a dead/full channel is dropped — the
@@ -1379,6 +1501,7 @@ class PolicyServer:
             self.counters.incr("serve_dup_inflight")
             self._pending[mid].ident = ident
             return
+        self._count_wire(msg, t0_us)
         if cmd != "step":
             reply = self._control_reply(msg)
             if isinstance(reply, _Prefilling):
@@ -1403,6 +1526,22 @@ class PolicyServer:
         self._queue.append(ent)
         if mid is not None:
             self._pending[mid] = ent
+
+    def _count_wire(self, msg, t_us):
+        """A request's time on the wire (its send stamp to now) and,
+        where it carries the previous reply's send stamp, its client's
+        turnaround (that reply's send to this request's send); a request
+        without stamps counts nothing."""
+        sent = msg.get(wire.SENT_US_KEY)
+        if not isinstance(sent, int):
+            return
+        due = [("serve_wire_in_us", max(0, t_us - sent)),
+               ("serve_wire_in_n", 1)]
+        prev = msg.get(wire.REPLY_SENT_US_KEY)
+        if isinstance(prev, int):
+            due += [("serve_client_turn_us", max(0, sent - prev)),
+                    ("serve_client_turn_n", 1)]
+        self.counters.incr_many(due)
 
     def _answer_step(self, ent, reply, ding=True):
         """Answer one step entry, wherever it got to (refused at
@@ -1442,7 +1581,7 @@ class PolicyServer:
         immediately instead of making them wait out another admission
         window."""
         with span("serve.tick") as tick:
-            with span("serve.tick.assemble"):
+            with self._clock(_ASSEMBLE, "serve.tick.assemble"):
                 t_assemble = time.perf_counter()
                 head = None
                 skipped = deque()
@@ -1530,7 +1669,9 @@ class PolicyServer:
                 t_compute = time.perf_counter()
                 self.timer.add("batch_assemble", t_compute - t_assemble)
             tick.set_metadata(rows=n, bucket=bucket)
-            with span("serve.tick.compute"):
+            # the tick joins ``_launched`` inside the phase, so that the
+            # clock's next switch sees it launched
+            with self._clock(_DISPATCH, "serve.tick.compute"):
                 try:
                     reply = model.step_rows(idx, obs_arr)
                 except Exception as exc:  # noqa: BLE001 - must survive
@@ -1539,11 +1680,11 @@ class PolicyServer:
                         self._pool_lost(head)
                     self._step_failed(batch, exc)
                     return more
-            self.counters.incr("serve_ticks_overlapped",
-                               int(self._ticks_launched() > 0))
-            self._launched.append(_Launched(
-                head, batch, reply, bucket, pos_before,
-                time.perf_counter() - t_compute))
+                behind = self._ticks_launched() > 0
+                self._launched.append(_Launched(
+                    head, batch, reply, bucket, pos_before,
+                    time.perf_counter() - t_compute))
+            self.counters.incr("serve_ticks_overlapped", int(behind))
             return more
 
     def _ticks_launched(self):
@@ -1565,25 +1706,28 @@ class PolicyServer:
         with span("serve.retire", **what):
             t_fetch = time.perf_counter()
             try:
-                rows = np.asarray(ent.reply)
+                with self._clock(_FETCH):  # the model's ``*.fence`` span
+                    rows = np.asarray(ent.reply)
             except Exception as exc:  # noqa: BLE001 - must survive
                 logger.exception("policy server: a launched %s failed",
                                  "step" if tick else "prefill")
-                behind = []
-                if isinstance(exc, SlotPoolLost):
-                    self._pool_lost(ent.state)
-                    behind = [t for t in self._launched
-                              if t.state is ent.state]
-                for t in behind:
-                    self._launched.remove(t)
-                for t in [ent] + behind:
-                    self._launched_failed(t, exc)
+                with self._clock(_REPLY, "serve.tick.reply"):
+                    behind = []
+                    if isinstance(exc, SlotPoolLost):
+                        self._pool_lost(ent.state)
+                        behind = [t for t in self._launched
+                                  if t.state is ent.state]
+                    for t in behind:
+                        self._launched.remove(t)
+                    for t in [ent] + behind:
+                        self._launched_failed(t, exc)
                 return
             waited = time.perf_counter() - t_fetch
-            if tick:
-                self._answer_tick(ent, rows, waited)
-            else:
-                self._answer_prefill(ent, rows, waited)
+            with self._clock(_REPLY, "serve.tick.reply"):
+                if tick:
+                    self._answer_tick(ent, rows, waited)
+                else:
+                    self._answer_prefill(ent, rows, waited)
 
     def _launched_failed(self, ent, exc):
         """Error-reply everybody a launched entry was to answer."""
@@ -1617,28 +1761,25 @@ class PolicyServer:
         model = tick.state.model
         n = len(tick.batch)
         t_reply = time.perf_counter()
-        self.counters.incr("serve_fetch_wait_us", int(waited * 1e6))
         # the host's time inside the model call for this tick: its
         # dispatch and its fetch, not what ran between the two
         self.timer.add("compute", tick.compute_s + waited)
-        with span("serve.tick.reply"):
-            self.counters.incr("serve_batches")
-            if hasattr(model, "drain_events"):
-                for name, count in model.drain_events().items():
-                    self.counters.incr(name, count)
-            if tick.bucket > n:
-                self.counters.incr("serve_batch_pad", tick.bucket - n)
-            for j, (ent, _, _) in enumerate(tick.batch):
-                reply = {"pred": np.ascontiguousarray(preds[j])}
-                if tick.pos_before[j] is not None:
-                    reply["pos"] = tick.pos_before[j]
-                # deferred doorbells: the whole batch's shm replies
-                # ride ONE wake per channel (flushed below), not one
-                # ding per record
-                self._answer_step(ent, reply, ding=False)
-            if self._shm is not None:
-                self._shm.flush_bells()
-            self.timer.add("reply", time.perf_counter() - t_reply)
+        self.counters.incr("serve_batches")
+        if hasattr(model, "drain_events"):
+            for name, count in model.drain_events().items():
+                self.counters.incr(name, count)
+        if tick.bucket > n:
+            self.counters.incr("serve_batch_pad", tick.bucket - n)
+        for j, (ent, _, _) in enumerate(tick.batch):
+            reply = {"pred": np.ascontiguousarray(preds[j])}
+            if tick.pos_before[j] is not None:
+                reply["pos"] = tick.pos_before[j]
+            # deferred doorbells: the whole batch's shm replies ride ONE
+            # wake per channel (flushed below), not one ding per record
+            self._answer_step(ent, reply, ding=False)
+        if self._shm is not None:
+            self._shm.flush_bells()
+        self.timer.add("reply", time.perf_counter() - t_reply)
 
     # -- serving -------------------------------------------------------------
 
@@ -1715,7 +1856,7 @@ class PolicyServer:
     def _admit_ready(self):
         """Admit what has arrived on either wire (resets are handled in
         here: a prefill is dispatched, and joins what is launched)."""
-        with span("serve.admit"):
+        with self._clock(_ADMIT, "serve.admit"):
             self._drain()
             self._drain_shm()
 
@@ -1724,11 +1865,8 @@ class PolicyServer:
         request to arrive: the clients' turnaround, which no change to
         the server recovers.  ``serve_idle_us`` is the one record of
         it."""
-        t0 = time.perf_counter()
-        with span("serve.idle"):
+        with self._clock(_IDLE, "serve.idle"):
             self._poller.poll(poll_ms)
-        self.counters.incr("serve_idle_us",
-                           int((time.perf_counter() - t0) * 1e6))
 
     def _window(self):
         """The admission window: wait for co-arriving requests (the
@@ -1762,14 +1900,19 @@ class PolicyServer:
                     if _reply_ready(self._launched[0].reply):
                         break
                     t_slice = time.perf_counter() + 1e-3
-                    if not self._poller.poll(1):
+                    with self._clock(_POLL, "serve.poll"):
+                        arrived = self._poller.poll(1)
+                    if not arrived:
                         continue
-                    time.sleep(max(0.0, t_slice - time.perf_counter()))
+                    with self._clock(_SLICE, "serve.slice"):
+                        time.sleep(max(0.0, t_slice - time.perf_counter()))
                 else:
                     rem_ms = (t_end - time.perf_counter()) * 1e3
                     if rem_ms <= 0:
                         break
-                    if not self._poller.poll(max(1, int(rem_ms))):
+                    with self._clock(_POLL, "serve.poll"):
+                        arrived = self._poller.poll(max(1, int(rem_ms)))
+                    if not arrived:
                         break  # window elapsed with nothing new
                 self._admit_ready()
 
@@ -1807,6 +1950,7 @@ class PolicyServer:
         flight, and so does the loop's exit."""
         import zmq
 
+        self._clock.restart()
         while stop_event is None or not stop_event.is_set():
             try:
                 self._poll_weights()
@@ -1821,6 +1965,7 @@ class PolicyServer:
             self._turn()
         while self._launched:
             self._retire()  # stopped: what was launched is still answered
+        self._clock.settle()
 
     def close(self):
         try:

@@ -181,9 +181,9 @@ REPLAY_EVENTS = (
 #: and not between its ticks (0 for a lone client or a model that
 #: computes on the host);
 #: ``serve_fetch_wait_us`` — microseconds the server's thread was
-#: blocked fetching a launched tick's reply (waiting for the device):
-#: the one place it waits for a tick (a launched prefill's wait is in
-#: ``serve_prefill_us``);
+#: blocked fetching a launched entry's reply (waiting for the device):
+#: a tick's or, since PR 40, a prefill's (so a launched prefill's wait
+#: is in both this and ``serve_prefill_us``);
 #: ``serve_prefills_overlapped`` — prefills (of ``serve_prefills``)
 #: that did not have the device's queue to themselves: something
 #: launched was still unfetched when the prefill was dispatched, or
@@ -198,6 +198,29 @@ REPLAY_EVENTS = (
 #: the rows whose recurrent state a reset zeroed.
 #: ``serve_state_bytes`` — bytes of recurrent state and convolution tails
 #: that the real rows of such a model's decode ticks read and wrote.
+#: The phase clock (``PolicyServer``'s thread, docs/serving.md "The phase
+#: clock"): the serve loop's wall time cut into exclusive phases, each a
+#: running quantity in microseconds, which together add up to the
+#: thread's wall time: ``serve_idle_us`` and ``serve_fetch_wait_us``
+#: above, and ``serve_poll_us`` (the admission window blocked in
+#: ``poll``), ``serve_slice_us`` (the window sleeping out a 1 ms slice
+#: after something arrived), ``serve_admit_us`` (draining both wires and
+#: admitting, less a prefill's dispatch), ``serve_prefill_dispatch_us``
+#: (a prefill's dispatch), ``serve_assemble_us`` (a tick's assembly),
+#: ``serve_dispatch_us`` (a tick's dispatch), ``serve_reply_us``
+#: (answering a tick's rows, or a reset at its retire),
+#: ``serve_weights_us`` (polling the WeightBus and adopting a snapshot)
+#: and ``serve_loop_us`` (the loop's own code between them).
+#: ``serve_drained_us`` — the thread's time with nothing launched and
+#: unfetched (the device's queue certainly empty: a lower bound of its
+#: idle time); ``serve_drained_wait_us`` — the part of it spent in
+#: ``serve_idle_us`` or ``serve_poll_us``, waiting on the clients.
+#: ``serve_wire_in_us`` / ``serve_wire_in_n`` — a request's send stamp
+#: (``wire.SENT_US_KEY``) to its admission, over the requests that
+#: carried one; ``serve_client_turn_us`` / ``serve_client_turn_n`` — the
+#: previous reply's send stamp to this request's send, over the requests
+#: that carried both (``wire.REPLY_SENT_US_KEY``).  A retry answered
+#: from the cache, or dropped as a duplicate in flight, counts in none.
 SERVE_EVENTS = (
     "serve_requests", "serve_replies", "serve_batches",
     "serve_batch_pad", "serve_cache_hits", "serve_dup_inflight",
@@ -211,6 +234,12 @@ SERVE_EVENTS = (
     "serve_prefills_overlapped",
     "serve_ctx_positions", "serve_rows_stepped", "serve_window_positions",
     "serve_state_resets", "serve_state_bytes",
+    "serve_poll_us", "serve_slice_us", "serve_admit_us",
+    "serve_prefill_dispatch_us", "serve_assemble_us", "serve_dispatch_us",
+    "serve_reply_us", "serve_weights_us", "serve_loop_us",
+    "serve_drained_us", "serve_drained_wait_us",
+    "serve_wire_in_us", "serve_wire_in_n",
+    "serve_client_turn_us", "serve_client_turn_n",
 )
 
 #: Canonical serve-gateway event names (see docs/serving.md
@@ -583,6 +612,12 @@ class EventCounters:
     def incr(self, name, n=1):
         with self._lock:
             self._counts[name] += n
+
+    def incr_many(self, pairs):
+        """``incr`` of each ``(name, n)`` in ``pairs``, under one lock."""
+        with self._lock:
+            for name, n in pairs:
+                self._counts[name] += n
 
     def get(self, name):
         with self._lock:

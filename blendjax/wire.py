@@ -64,6 +64,20 @@ SPAN_KEY = "btspan"
 #: they are tracing.
 SPANS_KEY = "btspans"
 
+#: Key under which a serve client stamps a request, and a ``PolicyServer``
+#: every reply, with the wall-epoch microseconds it was sent at
+#: (:func:`blendjax.obs.spans.now_us`, the timebase spans share across
+#: processes on one host).  The server reads a request's stamp as the
+#: time the request lay on the wire; receivers that ignore it keep
+#: working.
+SENT_US_KEY = "btsent"
+
+#: Key under which a serve client stamps a request with the
+#: :data:`SENT_US_KEY` of the previous reply it received: the server
+#: reads the stretch from that reply's send to this request's send as
+#: the client's turnaround.
+REPLY_SENT_US_KEY = "btprev"
+
 _ARRAY_PLACEHOLDER = "__bjx_nd__"
 
 #: Public alias: key under which a raw-buffer header stores the payload

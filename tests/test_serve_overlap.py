@@ -181,7 +181,7 @@ def test_second_tick_is_launched_before_the_first_is_fetched(served):
     snap = counters.snapshot()
     assert snap["serve_ticks_overlapped"] == 1
     assert snap["serve_batches"] == 2
-    assert snap["serve_fetch_wait_us"] > 0
+    assert snap["serve_fetch_wait_us"] > 0  # the ticks' waits (no prefill)
     assert [rows for rows, _ in model.calls] == [(a.slot,), (b.slot,)]
 
 
@@ -467,12 +467,18 @@ def test_a_lone_prefill_is_retired_at_once_and_overlaps_nothing():
         _until(lambda: len(model.prefills) == 1, "the prefill")
         prefill = model.prefills[0][1]
         assert prefill.fetching.wait(2.0), "the server did not go to fetch"
+        time.sleep(0.02)
         prefill.gate.set()
         assert admitted.result()["pos"] == 3
         assert time.monotonic() - t0 < 2.5  # far inside tick_ms
         snap = counters.snapshot()
         assert snap["serve_prefills"] == 1
         assert snap.get("serve_prefills_overlapped", 0) == 0
+        # the wait at a prefill's fetch is in ``serve_fetch_wait_us``
+        # (since PR 40; no tick ran) as well as in ``serve_prefill_us``
+        assert snap.get("serve_batches", 0) == 0
+        assert 15_000 <= snap["serve_fetch_wait_us"] \
+            <= snap["serve_prefill_us"]
         c.close()
 
 

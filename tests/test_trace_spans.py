@@ -15,6 +15,7 @@ import glob
 import os
 import subprocess
 import sys
+import threading
 import time
 import types
 
@@ -42,7 +43,23 @@ SERVER_SPANS = (
     "serve.idle", "serve.admit", "serve.prefill", "serve.window",
     "serve.tick", "serve.tick.assemble", "serve.tick.compute",
     "serve.retire", "serve.tick.reply", "serve.weights",
+    "serve.poll", "serve.slice",
 )
+#: the phase clock's leaf phases as spans (``serve_fetch_wait_us`` is the
+#: model's ``*.fence``): on the server's thread no two of them overlap
+LEAF_SPANS = (
+    "serve.idle", "serve.poll", "serve.slice", "serve.prefill",
+    "serve.tick.assemble", "serve.tick.compute", "serve.step.fence",
+    "serve.prefill.fence", "serve.tick.reply",
+)
+#: the two phases that may hold others, and which: admission a prefill's
+#: dispatch, a weight poll the retires of what a staged snapshot finds
+#: launched
+HOLDERS = {
+    "serve.admit": ("serve.prefill",),
+    "serve.weights": ("serve.step.fence", "serve.prefill.fence",
+                      "serve.tick.reply"),
+}
 MODEL_SPANS = (
     "serve.step.dispatch", "serve.step.fence",
     "serve.prefill.dispatch", "serve.prefill.fence", "serve.reset_rows",
@@ -97,11 +114,82 @@ class _OneSnapshot:
         pass
 
 
+class _GatedReply:
+    """A step's reply, ready when the test opens its gate."""
+
+    def __init__(self, rows):
+        self.rows, self.gate = rows, threading.Event()
+
+    def is_ready(self):
+        return self.gate.is_set()
+
+    def __array__(self, dtype=None, copy=None):
+        assert self.gate.wait(20.0), "the test never opened the gate"
+        return self.rows
+
+
+class _GatedModel:
+    """``pred = sum(obs)``, handed out gated."""
+
+    kind = "gated"
+    obs_dim = 4
+
+    def __init__(self):
+        self.slots = self.pad_slot = 2
+        self.calls = []
+
+    def reset_rows(self, idx):
+        pass
+
+    def step_rows(self, idx, obs):
+        self.calls.append(_GatedReply(obs.sum(-1, keepdims=True)))
+        return self.calls[-1]
+
+
+def _gated_session():
+    """Two clients of a gated model: a's tick launched and not ready, so
+    the window polls the wire in slices (``serve.poll``); b's step arrives
+    in one and the slice is slept out (``serve.slice``); b's tick is
+    launched behind a's, whose fetch then waits for the gate."""
+    model, counters = _GatedModel(), EventCounters()
+    with start_server_thread(model, counters=counters) as h:
+        a = ServeClient(h.address, timeoutms=20000)
+        b = ServeClient(h.address, timeoutms=20000)
+        a.reset()
+        b.reset()
+        out = {}
+        ta = threading.Thread(target=lambda: out.update(a=a.step(
+            np.ones(4, np.float32))))
+        ta.start()
+        deadline = time.monotonic() + 20
+        while not model.calls:
+            assert time.monotonic() < deadline
+            time.sleep(0.002)
+        time.sleep(0.02)  # the window polls
+        tb = threading.Thread(target=lambda: out.update(b=b.step(
+            np.ones(4, np.float32))))
+        tb.start()
+        while len(model.calls) < 2:
+            assert time.monotonic() < deadline
+            time.sleep(0.002)
+        time.sleep(0.02)  # a's fetch waits
+        for reply in model.calls:
+            reply.gate.set()
+        ta.join(20)
+        tb.join(20)
+        assert out["a"]["pred"][0] == out["b"]["pred"][0] == 4.0
+        after = a.stats()["counters"]
+        a.close()
+        b.close()
+    return after
+
+
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
     """One traced session: a ``LinearModel`` server answering a
     ``reset(prefix=)``, a few ``step``s and a weight swap, then a tiny
-    ``SeqFormerModel`` driven the same way."""
+    ``SeqFormerModel`` driven the same way, then two clients of a model
+    whose replies the test holds back."""
     trace_dir = tmp_path_factory.mktemp("trace")
     counters = EventCounters()
     t_wall = time.perf_counter()
@@ -126,10 +214,11 @@ def served(tmp_path_factory):
             c.step(np.ones(4, np.float32))
             c.close_episode()
             c.close()
+        gated = _gated_session()
     wall_us = (time.perf_counter() - t_wall) * 1e6
     return types.SimpleNamespace(
         events=_host_events(str(trace_dir)), before=before, after=after,
-        wall_us=wall_us)
+        gated=gated, wall_us=wall_us)
 
 
 @pytest.mark.parametrize("name", SERVER_SPANS + MODEL_SPANS)
@@ -213,6 +302,34 @@ def test_prefill_and_idle_counters(served):
     span_us = sum(hi - lo for lo, hi, st in served.events["serve.prefill"]
                   if st.get("len") == 5) / 1e3
     assert served.after["serve_prefill_us"] >= 0.5 * span_us
+
+
+def test_the_window_polls_and_slices_are_spans(served):
+    # a's tick in flight: the window read the wire in slices of a
+    # millisecond, and b's step arrived in one, which was slept out
+    polls = served.events["serve.poll"]
+    assert len(polls) >= 5
+    assert _inside(served, "serve.poll", "serve.window")
+    assert _inside(served, "serve.slice", "serve.window")
+    assert all(hi - lo < 50e6 for lo, hi, _ in served.events["serve.slice"])
+    # the clock counted the same phases, and the wait at a's fetch
+    assert served.gated["serve_poll_us"] > 0
+    assert served.gated["serve_slice_us"] > 0
+    assert served.gated["serve_fetch_wait_us"] >= 10_000
+
+
+def test_the_leaf_spans_do_not_overlap(served):
+    leaves = sorted((lo, hi, name) for name in LEAF_SPANS
+                    for lo, hi, _ in served.events.get(name, ()))
+    assert len(leaves) > 30
+    for (lo, hi, name), (lo2, hi2, name2) in zip(leaves, leaves[1:]):
+        assert hi <= lo2, (name, name2, hi - lo2)
+    # admission and a weight poll hold no leaf but their own, whole
+    for holder, held in HOLDERS.items():
+        for lo, hi, _ in served.events[holder]:
+            inside = [(a, b, n) for a, b, n in leaves if a < hi and lo < b]
+            assert all(n in held and lo <= a and b <= hi
+                       for a, b, n in inside), (holder, inside)
 
 
 def test_stage_and_span_annotate_when_jax_is_loaded(tmp_path):
