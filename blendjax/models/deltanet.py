@@ -29,12 +29,14 @@ Two paths compute the same mixer:
   * exp(gamma_C - gamma))``.  Everything that does not hold ``S`` is
   computed for all chunks at once; a ``lax.scan`` carries ``S`` across
   them.
-- :func:`mix_step` (decode): one position a row, ``S`` and the tails read
-  and written whole.
+- :func:`mix_step` (decode): one position a row, the tails read and
+  written whole, ``S`` stepped where it lies in the pool by one kernel
+  (:mod:`blendjax.ops.gdn_update`).
 
 Both go on from a state handed in (zeros for a fresh sequence) and hand
 back the state after their last position: the caller owns where it lives
-(:func:`blendjax.models.seqformer.init_cache`).
+(:func:`blendjax.models.seqformer.init_cache`); :func:`mix_step` is handed
+the pool's whole leaf and the rows it steps (:data:`STEPS_IN_PLACE`).
 
 Parameters of a block's ``"gdn"`` entry (no biases)::
 
@@ -47,7 +49,8 @@ The products of the projections run in the compute dtype and accumulate
 in float32.  Float32 whatever the compute dtype: the convolutions' sums,
 both l2 norms, the gates and their exponentials, ``S``, every product that
 updates or reads it (at ``Precision.HIGHEST``: on a TPU a float32 product
-is otherwise rounded to bfloat16 on its way in), and the output norm.
+is otherwise rounded to bfloat16 on its way in; the decode kernel's are
+element by element on the vector unit), and the output norm.
 """
 
 from __future__ import annotations
@@ -60,6 +63,7 @@ import numpy as np
 from jax import lax
 
 from blendjax.models.layers import rms_norm, scaled_normal
+from blendjax.ops import gdn_update
 
 #: positions solved together; ``S`` is carried across chunks
 CHUNK = 64
@@ -68,6 +72,9 @@ _HIGHEST = lax.Precision.HIGHEST
 
 #: the three convolved streams, in the order of their tails
 STREAMS = ("q", "k", "v")
+#: how many leading parts of the state (``state_shapes``' order) the
+#: decode step takes as the pool's whole leaf, with the rows it steps
+STEPS_IN_PLACE = 1
 
 
 @jax.tree_util.register_static
@@ -236,22 +243,22 @@ def mix_sequence(p, x, state, tail_q, tail_k, tail_v, dtype):
     return (_gate_out(p, o, x, dtype), state, *tails)
 
 
-def mix_step(p, x, state, tail_q, tail_k, tail_v, dtype):
-    """One position a row: ``x`` (B, d), ``state`` (B, H, dv, dk) float32,
-    the tails (B, taps - 1, width) -> ``(out (B, d), new state, the three
-    new tails)``; :func:`mix_sequence` at T = 1 without its solve.  The
-    read is taken from the old state (``S_t q = alpha S q + u (k . q)``),
-    so the state is read once for both products and once for its
-    update."""
+def mix_step(p, x, pool, tail_q, tail_k, tail_v, dtype, rows=None):
+    """One position a row: ``x`` (B, d), ``pool`` a cache's whole state
+    leaf ``(S, ..., dv, dk)`` float32 (the heads on the axes between) of
+    which ``rows`` (B,) are stepped (by default its first B), the tails
+    (B, taps - 1, width) -> ``(out (B, d), the pool with the rows' new
+    state, the three new tails)``; :func:`mix_sequence` at T = 1 without
+    its solve.  The state is stepped where it lies, by
+    :func:`blendjax.ops.gdn_update.gdn_update`: each row's read once, for
+    both products and its update (the read is taken from the old state,
+    ``S_t q = alpha S q + u (k . q)``), and written back in place."""
     q, k, v, tails = _streams(p, x[:, None], (tail_q, tail_k, tail_v), dtype)
     q, k, v = q[:, 0], k[:, 0], v[:, 0]
     g, beta = gates(p, x, dtype)
+    if rows is None:
+        rows = jnp.arange(x.shape[0])
     with jax.named_scope("update"):
-        alpha = jnp.exp(g)[..., None]                   # (B, H, 1)
-        state = state.astype(jnp.float32)
-        s_k = jnp.sum(state * k[:, :, None, :], -1)     # (B, H, dv)
-        s_q = jnp.sum(state * q[:, :, None, :], -1)
-        u = beta[..., None] * (v - alpha * s_k)
-        state = alpha[..., None] * state + u[..., None] * k[:, :, None, :]
-        o = alpha * s_q + u * jnp.sum(k * q, -1, keepdims=True)
-    return (_gate_out(p, o, x, dtype), state, *tails)
+        o, pool = gdn_update.gdn_update(pool, rows, q, k, v, jnp.exp(g),
+                                        beta)
+    return (_gate_out(p, o, x, dtype), pool, *tails)

@@ -53,6 +53,9 @@ from blendjax.models.layers import scaled_normal
 #: chunks.  At 5120 channels x 16 states a chunk's float32 terms are
 #: 21 MB each.
 SCAN_CHUNK = 64
+#: no part of the state is stepped where it lies: the decode step is
+#: handed the stepped rows' state, gathered
+STEPS_IN_PLACE = 0
 
 
 def init(key, d_model, d_inner, d_state, d_conv, dt_rank, dtype=jnp.float32):

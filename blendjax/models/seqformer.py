@@ -67,6 +67,7 @@ sublayer's input (``x + f(ln(x))``), `post_ln1` / `post_ln2` its output
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -184,7 +185,9 @@ def _latent(params):
 
 
 #: the recurrent mixers, by the block entry that holds one: the module
-#: (``state_shapes``, ``mix_sequence``, ``mix_step``) and the cache entries
+#: (``state_shapes``, ``mix_sequence``, ``mix_step``, ``STEPS_IN_PLACE``:
+#: how many leading entries its step reads and writes in the pool, handed
+#: the whole leaf and the stepped rows) and the cache entries
 #: it keeps, in ``state_shapes``' order: a float32 state, then what the
 #: cache's dtype keeps (convolution tails).  No position indexes them:
 #: they are written whole, and zeroed (not masked) when a row is rewound.
@@ -1379,21 +1382,28 @@ class _HybridStep:
         mixer = _mixer(blk)
         if mixer:
             kind, module, names = mixer
+            # the module's first `whole` leaves go in and come out whole:
+            # its step reads and writes the stepped rows where they lie
+            whole = module.STEPS_IN_PLACE
+            step = (functools.partial(module.mix_step, rows=self.rows)
+                    if whole else module.mix_step)
             with jax.named_scope(kind):
                 pools = [self.cache[name][i] for name in names]
                 with jax.named_scope("gather"):
-                    state = [_pool_rows(pool, self.rows).reshape(-1, *shape)
-                             for pool, shape in zip(
-                                 pools, module.state_shapes(blk[kind]))]
-                out, *state = module.mix_step(blk[kind], h, *state, dtype)
+                    state = pools[:whole] + [
+                        _pool_rows(pool, self.rows).reshape(-1, *shape)
+                        for pool, shape in zip(
+                            pools[whole:],
+                            module.state_shapes(blk[kind])[whole:])]
+                out, *state = step(blk[kind], h, *state, dtype)
                 if kind == "ssm":  # its scan output, for the memory units
                     self.memory, *state = state
                 with jax.named_scope("scatter"):
                     # the whole state of the stepped rows, and of no other
-                    self._keep(i, names, [
+                    self._keep(i, names, state[:whole] + [
                         pool.at[self.rows].set(new.reshape(
                             -1, *pool.shape[1:]).astype(pool.dtype))
-                        for pool, new in zip(pools, state)])
+                        for pool, new in zip(pools[whole:], state[whole:])])
             return out
         if "gmu" in blk:
             self._keep(i, (), ())

@@ -284,7 +284,8 @@ def test_linear_attention_pool_is_served_in_place_on_the_chip(
     ``init_cache`` keeps the heads in three pieces of ten, each under the
     gather's limit); the step's temporaries stay near the stepped rows'
     gathered K/V and state; the prefill's full attention is the flash
-    kernel."""
+    kernel; the step's state update is the ``gdn_update`` kernel, which
+    reads and writes the stepped rows' state in the pool."""
     import importlib
 
     import jax
@@ -345,12 +346,22 @@ def test_linear_attention_pool_is_served_in_place_on_the_chip(
     assert not whole, whole[:3]
     assert "mini-gather-slice" not in text
     if what == "step":
-        # the gathered K/V rows of one full layer (twice over: keys and
-        # values) and the stepped rows' state, its 96-wide minor axis
-        # padded to 128 lanes, a few times over
-        kv_rows = 2 * n * 1536 * 3840 * 2
+        # each linear layer's state is stepped where it lies, by the one
+        # kernel: the pool goes in and comes out aliased, and nothing
+        # holds the stepped rows' state on its own (no gather, no update,
+        # no scatter of it)
+        assert len(re.findall(r"%gdn_update[.\d]* = ", text)) == 3
+        assert "tpu_custom_call" in text
+        assert not re.search(rf"= f32\[{n},3,10,192,96\]", text)
+        # what is left is one full layer's gathered keys of the stepped
+        # rows, the head's float32 logits and, of the state, the kernel's
+        # operands and `o` alone: under a sixteenth of the stepped rows'
+        # state, its 96-wide minor axis padded to 128 lanes (the gathered
+        # and updated copies took a sixth of it before the kernel)
+        keys = n * 1536 * 3840 * 2
+        logits = n * 100352 * 4
         state_rows = n * 30 * 192 * 128 * 4
-        assert mem.temp_size_in_bytes < 1.5 * kv_rows + 4 * state_rows
+        assert mem.temp_size_in_bytes < keys + logits + state_rows / 16
     else:
         assert "flash_fwd" in text and "tpu_custom_call" in text
 
