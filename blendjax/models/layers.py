@@ -71,7 +71,12 @@ def yarn_inv_freq(dh, base, factor, beta_fast, beta_slow, original_max):
     """YaRN inverse frequencies (``deepseek_yarn``) over ``dh // 2`` rope
     pairs: ``base^(-2i/dh)`` below the correction dimension of
     ``beta_fast`` rotations over ``original_max`` positions, that over
-    ``factor`` above the one of ``beta_slow``, the linear ramp between."""
+    ``factor`` above the one of ``beta_slow``, the linear ramp between.
+    The same for Hugging Face's ``rope_type: yarn`` at its default
+    ``truncate: true``: the two correction dimensions are floored and
+    ceiled to whole pairs and clipped to ``[0, dh - 1]``, so the ramp runs
+    between whole pair indices (at ``dh`` 128, ``theta`` 500000 and 8192
+    original positions, from pair 18 to pair 35)."""
     def correction_dim(rotations):
         return dh * math.log(original_max / (rotations * 2 * math.pi)) / (
             2 * math.log(base))
@@ -85,14 +90,16 @@ def yarn_inv_freq(dh, base, factor, beta_fast, beta_slow, original_max):
     return plain * (1.0 - ramp) + plain / factor * ramp
 
 
-def rope_table(positions, dh, base=10000.0, yarn=None):
+def rope_table(positions, dh, base=10000.0, yarn=None, attention_factor=None):
     """Rotary-embedding cos/sin tables for ``positions`` (any traced or
     static int array) at per-head dim ``dh`` (even).  f32: the rotation
     is applied in f32 and cast back by :func:`apply_rope`.
 
-    ``yarn`` (``factor, beta_fast, beta_slow, original_max, mscale,
-    mscale_all_dim``) scales the frequencies as :func:`yarn_inv_freq`
-    does and the tables by ``m(mscale) / m(mscale_all_dim)``.
+    ``yarn`` (``factor, beta_fast, beta_slow, original_max``, then for
+    ``deepseek_yarn`` ``mscale, mscale_all_dim``) scales the frequencies
+    as :func:`yarn_inv_freq` does and the tables by ``attention_factor``
+    where it is given (Hugging Face's ``rope_type: yarn``), else by
+    ``m(mscale) / m(mscale_all_dim)``.
 
     Precision bound: the highest-frequency angle equals the raw
     position, and f32's ulp at position p is ~p * 6e-8 radians — sub-
@@ -105,11 +112,13 @@ def rope_table(positions, dh, base=10000.0, yarn=None):
         freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
         ang = positions.astype(jnp.float32)[:, None] * freqs[None]
         return jnp.cos(ang), jnp.sin(ang)
-    factor, beta_fast, beta_slow, original_max, mscale, mscale_all = yarn
+    factor, beta_fast, beta_slow, original_max, *mscales = yarn
     freqs = yarn_inv_freq(dh, base, factor, beta_fast, beta_slow,
                           original_max)
     ang = positions.astype(jnp.float32)[:, None] * freqs[None]
-    m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
+    m = attention_factor
+    if m is None:
+        m = yarn_mscale(factor, mscales[0]) / yarn_mscale(factor, mscales[1])
     return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 

@@ -233,13 +233,17 @@ def moe_apply_topk(p, x, dtype, k=2, capacity_factor=1.25, dispatch="sort"):
 @dataclasses.dataclass(frozen=True)
 class RouteSpec:
     """What the shapes of a held-share ``"moe"`` entry do not say: the
-    experts chosen per token, the factor on the normalised weights, and
-    the id of the first routed expert held here (the count is the
-    stacked weights' leading axis)."""
+    experts chosen per token, the factor on the normalised weights, the
+    id of the first routed expert held here (the count is the stacked
+    weights' leading axis), what the scores are (``"sigmoid"``, selected
+    with the router's bias; ``"softmax"`` over all routed experts) and
+    whether the selected weights are renormalised to sum ``scale``."""
 
     top_k: int
     scale: float = 1.0
     first: int = 0
+    score: str = "sigmoid"
+    renorm: bool = True
 
 
 def gated_mlp(p, x, dtype):
@@ -276,23 +280,36 @@ def held_init(key, d, d_ff, n_routed, held, spec, shared=True,
     return p
 
 
-def route_sigmoid(router, x, spec):
-    """Float32 routing over all routed experts: ``s = sigmoid(x W_r)``,
-    the ``top_k`` of ``s + bias`` selected, and the selected scores (the
-    bias not in them) normalised to sum ``spec.scale``.  Returns
-    ``(sel (n, k) int32, g (n, k) float32)``.  Nothing is dropped."""
-    s = jax.nn.sigmoid(x.astype(jnp.float32)
-                       @ router["w"].astype(jnp.float32))
-    _, sel = jax.lax.top_k(s + router["bias"].astype(jnp.float32),
-                           spec.top_k)
-    w = jnp.take_along_axis(s, sel, axis=-1)
-    return sel, spec.scale * w / w.sum(-1, keepdims=True)
+def route(router, x, spec):
+    """Float32 routing over all routed experts, by ``spec.score``:
+
+    - ``"sigmoid"``: ``s = sigmoid(x W_r)``, the ``top_k`` of ``s + bias``
+      selected, and the selected scores (the bias not in them);
+    - ``"softmax"``: ``p = softmax(x W_r)`` over every routed expert and
+      its ``top_k`` selected, with their probabilities;
+
+    the selected weights normalised to sum 1 where ``spec.renorm`` says
+    so, and times ``spec.scale``.  Returns ``(sel (n, k) int32, g (n, k)
+    float32)``.  Nothing is dropped."""
+    logits = x.astype(jnp.float32) @ router["w"].astype(jnp.float32)
+    if spec.score == "softmax":
+        w, sel = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), spec.top_k)
+    elif spec.score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        _, sel = jax.lax.top_k(s + router["bias"].astype(jnp.float32),
+                               spec.top_k)
+        w = jnp.take_along_axis(s, sel, axis=-1)
+    else:
+        raise ValueError(f"unknown routing score {spec.score!r}")
+    if spec.renorm:
+        return sel, spec.scale * w / w.sum(-1, keepdims=True)
+    return sel, spec.scale * w
 
 
 def moe_apply_held(p, x, dtype, valid=None):
     """The part of a routed layer that this rank's experts give, plus
     the shared expert: ``x`` (n, d) is routed over all experts
-    (:func:`route_sigmoid`), the assignments whose expert lies in
+    (:func:`route`), the assignments whose expert lies in
     ``[first, first + held)`` are sorted by expert and multiplied group
     by group (``jax.lax.ragged_dot``: no capacity, no padding arena, no
     drop), and every token's held contributions are summed under their
@@ -307,7 +324,7 @@ def moe_apply_held(p, x, dtype, valid=None):
     k = spec.top_k
     held = p["gate"].shape[0]
     with jax.named_scope("route"):
-        sel, g = route_sigmoid(p["router"], x, spec)
+        sel, g = route(p["router"], x, spec)
         local = sel - spec.first
         here = jnp.logical_and(local >= 0, local < held)
         # held assignments sort to the front by expert, the rest last
@@ -325,7 +342,14 @@ def moe_apply_held(p, x, dtype, valid=None):
             jnp.asarray(made), counted.sum(), (counted > 0).sum(),
         ]).astype(jnp.int32)
     with jax.named_scope("experts"):
-        xs = x.astype(dtype)[order // k]                     # (n * k, d)
+        xs = x.astype(dtype)
+        if d % 128 == 0:
+            # each row as whole 128-lane tiles: the TPU compiler fused the
+            # flat gather of 12288 rows of 2304 into a kernel that overran
+            # its scoped VMEM, and refused the program
+            xs = xs.reshape(n, d // 128, 128)[order // k].reshape(n * k, d)
+        else:
+            xs = xs[order // k]                              # (n * k, d)
         h = jax.nn.silu(jax.lax.ragged_dot(xs, p["gate"].astype(dtype),
                                            sizes)) \
             * jax.lax.ragged_dot(xs, p["up"].astype(dtype), sizes)

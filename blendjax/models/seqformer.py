@@ -35,7 +35,8 @@ says which it is: `ln*` with a `bias` is LayerNorm, without one RMSNorm;
 (:mod:`blendjax.models.mla`: one low-rank cache row a position,
 expanded in the forward pass, absorbed in a decode step); an `mlp` with
 a `gate` is the gated SiLU MLP; a `moe` with a `route` is the held share
-of a sigmoid-routed layer (:func:`blendjax.models.moe.moe_apply_held`);
+of a routed layer, its scores sigmoid or softmax as the route says
+(:func:`blendjax.models.moe.moe_apply_held`);
 `embed` with a `table` takes int32 ids, and a `head` without a bias
 answers with float32 logits over its vocabulary slice
 (:func:`init_token_model`); no `head` at all is the embedding tied.
@@ -54,10 +55,15 @@ buffer).  Such a model has no positional encoding.  A `gdn` entry is
 gated delta-rule linear attention (:mod:`blendjax.models.deltanet`: a
 float32 matrix state a head and three convolution tails per sequence);
 `wq`/`wk`/`wv`/`wo` without a `diff` entry, inside such a model, are plain
-softmax attention over a full-length K/V, with a `q_norm` / `k_norm`
-RMSNorm over the whole projection where the block holds them.  The kinds
-are then the configuration's own ``layer_types``
-(:func:`init_linear_hybrid_model`).
+softmax attention, with a `q_norm` / `k_norm` RMSNorm where the block
+holds them (over each head where its scale is a head wide, else over the
+whole projection) and, where the block holds an `attn` entry (its static
+:class:`AttnSpec`), a window (a ring of that many positions) and a
+rotary embedding of its own; without one, over a full-length K/V and
+unrotated.  The kinds are then the configuration's own ``layer_types``
+(:func:`init_linear_hybrid_model`; :func:`describe_token_model`): a
+model of window and full attention layers only, with no recurrent
+block, is such a model too.
 
 **The norm's place is read off the block too**: `ln1` / `ln2` norm a
 sublayer's input (``x + f(ln(x))``), `post_ln1` / `post_ln2` its output
@@ -83,6 +89,7 @@ from blendjax.models.layers import (
     rms_norm,
     rope_table,
     scaled_normal,
+    yarn_mscale,
 )
 from blendjax.models.moe import (
     RouteSpec,
@@ -207,10 +214,43 @@ def _mixer(blk):
     return None
 
 
-def _hybrid(params):
-    """Whether the model mixes layer kinds (recurrent blocks among them):
-    its cache then holds recurrent state beside keys and values."""
+def _recurrent(params):
+    """Whether any block keeps recurrent state: it is zeroed, not
+    masked, when a row is rewound."""
     return any(_mixer(blk) for blk in params["blocks"])
+
+
+def _hybrid(params):
+    """Whether the model mixes layer kinds (recurrent blocks, or plain
+    attention blocks of described kinds): its cache then holds, block by
+    block, rings and full-length keys and values side by side (and the
+    recurrent state), and :class:`_HybridStep` steps it."""
+    return _recurrent(params) or any("attn" in blk
+                                     for blk in params["blocks"])
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class AttnSpec:
+    """What the shapes of a plain attention block of a model of mixed
+    kinds do not say (its entry ``attn``): how many positions a query
+    sees, its own included (``window``; None: all of them), and its
+    rotary embedding (``theta``; None: none): the default rotation, or
+    with ``yarn`` (``factor, beta_fast, beta_slow, original_max``) the
+    YaRN frequencies, the tables times ``attention_factor``
+    (:func:`~blendjax.models.layers.rope_table`)."""
+
+    window: int | None = None
+    theta: float | None = None
+    yarn: tuple | None = None
+    attention_factor: float | None = None
+
+
+def _window_of(blk):
+    """The positions a query of an attention block sees, or None (all)."""
+    if "diff" in blk:
+        return blk["diff"]["spec"].window
+    return blk["attn"].window if "attn" in blk else None
 
 
 def _windows_are_described(hybrid, window):
@@ -252,21 +292,36 @@ def _diff_kind(blk):
     return "full" if blk["diff"]["spec"].window is None else "window"
 
 
-def _plain_qkv(blk, h, dtype):
+def _plain_qkv(blk, h, dtype, pos=None):
     """The bias-free projections of a plain attention block inside a
     model of mixed kinds: normed input ``h`` (..., d) -> ``(q (..., H,
-    Dh), k, v (..., Hkv, Dh))``, ``q`` and ``k`` through the block's
-    RMSNorm over the whole projection where it holds one."""
+    Dh), k, v (..., Hkv, Dh))``, ``Dh`` the weights' own.  ``q`` and ``k``
+    go through the block's RMSNorm where it holds one (over each head
+    where the scale is ``Dh`` wide, else over the whole projection), then
+    through its rotation where its :class:`AttnSpec` has one: at ``pos``
+    (B,), one position a row, or for a sequence ``(B, T, ...)`` at
+    ``0 .. T - 1``."""
     q, k, v = (jnp.einsum("...d,dhk->...hk", h.astype(dtype),
                           blk[n].astype(dtype)) for n in ("wq", "wk", "wv"))
 
     def normed(name, t):
         if name not in blk:
             return t
+        if blk[name]["scale"].shape[-1] == t.shape[-1]:
+            return _ln_apply(blk[name], t)
         return _ln_apply(blk[name],
                          t.reshape(*t.shape[:-2], -1)).reshape(t.shape)
 
-    return normed("q_norm", q), normed("k_norm", k), v
+    q, k = normed("q_norm", q), normed("k_norm", k)
+    spec = blk.get("attn")
+    if spec is not None and spec.theta is not None:
+        if pos is None:
+            pos = jnp.arange(q.shape[-3])
+        cos, sin = rope_table(pos, q.shape[-1], spec.theta, spec.yarn,
+                              spec.attention_factor)
+        # (T, Dh/2) or (B, Dh/2) tables over (B, T, H, Dh) or (B, H, Dh)
+        q, k = apply_rope_rows(q, cos, sin), apply_rope_rows(k, cos, sin)
+    return q, k, v
 
 
 def _plain_out(blk, a, dtype):
@@ -377,14 +432,16 @@ def token_model_specs(config, first=0):
 
 
 #: a published ``layer_types`` entry -> the kind it is here
-_LAYER_TYPES = {"linear_attention": "gdn", "full_attention": "full"}
+_LAYER_TYPES = {"linear_attention": "gdn", "full_attention": "full",
+                "sliding_attention": "window"}
 
 
 def hybrid_layer_kinds(config):
     """The kind of every layer of a model of mixed layer kinds.  Where
     the configuration publishes ``layer_types`` they are its first
     ``num_hidden_layers`` entries (``"gdn"`` for ``linear_attention``,
-    ``"full"`` for ``full_attention``).  Otherwise the rule of a
+    ``"full"`` for ``full_attention``, ``"window"`` for
+    ``sliding_attention``).  Otherwise the rule of a
     decoder-hybrid-decoder model
     (arXiv:2507.06607), from ``num_hidden_layers`` and ``mb_per_layer``:
     in the first half every ``mb_per_layer``-th layer is ``"ssm"`` and
@@ -475,26 +532,61 @@ def init_hybrid_model(key, config, dtype=jnp.float32):
         "blocks": blocks, "ln_f": norm()}, c)
 
 
-def _describe_linear_hybrid(arrays, config):
-    """The static entries of a model whose ``layer_types`` mix linear and
-    full attention: each norm's epsilon and each linear block's
-    :class:`deltanet.GdnSpec`."""
+def _attn_spec(config, layer_type, kind):
+    """A plain attention layer's :class:`AttnSpec` from the published
+    keys, or None where it has neither a window nor a rotation:
+    ``sliding_window`` for a window layer, and ``rope_parameters`` (one
+    entry for every kind, or an entry by layer type) of ``rope_type``
+    ``default`` or ``yarn``; a ``rope_theta`` of null is no rotation."""
+    window = config["sliding_window"] if kind == "window" else None
+    rope = config.get("rope_parameters") or {}
+    rope = rope.get(layer_type, rope)
+    if rope.get("rope_theta") is None:
+        return None if window is None else AttnSpec(window)
+    yarn = scale = None
+    rope_type = rope.get("rope_type", "default")
+    if rope_type == "yarn":
+        yarn = (float(rope["factor"]), rope["beta_fast"], rope["beta_slow"],
+                rope["original_max_position_embeddings"])
+        scale = rope.get("attention_factor")
+        scale = float(yarn_mscale(yarn[0]) if scale is None else scale)
+    elif rope_type != "default":
+        raise ValueError(f"rope_type {rope_type!r} is not served")
+    return AttnSpec(window, float(rope["rope_theta"]), yarn, scale)
+
+
+def _describe_layer_types(arrays, config, first=0):
+    """The static entries of a model whose configuration lists its
+    ``layer_types`` (linear, window and full attention): each norm's
+    epsilon, each linear block's :class:`deltanet.GdnSpec`, each plain
+    attention block's :class:`AttnSpec` where its kind has a window or a
+    rotation, and each routed layer's ``RouteSpec``: softmax over all
+    ``num_experts``, the ``num_experts_per_tok`` renormalised where
+    ``norm_topk_prob`` says so, the held experts from ``first``."""
     eps = float(config["rms_norm_eps"])
     kinds = hybrid_layer_kinds(config)
     if len(kinds) != len(arrays["blocks"]):
         raise ValueError(f"{len(arrays['blocks'])} blocks for {len(kinds)} "
                          "layers")
     arrays["ln_f"]["spec"] = NormSpec(eps)
-    for layer, (kind, blk) in enumerate(zip(kinds, arrays["blocks"])):
-        if ("gdn" if "gdn" in blk else "full") != kind:
+    for layer, (kind, layer_type, blk) in enumerate(zip(
+            kinds, config["layer_types"], arrays["blocks"])):
+        if ("gdn" in blk) != (kind == "gdn"):
             raise ValueError(f"layer {layer} is {kind!r} by the "
                              f"configuration and holds {sorted(blk)}")
-        for name in ("post_ln1", "post_ln2", "q_norm", "k_norm"):
+        for name in ("ln1", "ln2", "post_ln1", "post_ln2", "q_norm",
+                     "k_norm"):
             if name in blk:
                 blk[name]["spec"] = NormSpec(eps)
         if kind == "gdn":
             blk["gdn"]["spec"] = deltanet.GdnSpec(
                 bool(config["linear_allow_neg_eigval"]), eps)
+        elif spec := _attn_spec(config, layer_type, kind):
+            blk["attn"] = spec
+        if "moe" in blk:
+            blk["moe"]["route"] = RouteSpec(
+                top_k=config["num_experts_per_tok"], first=first,
+                score="softmax", renorm=bool(config["norm_topk_prob"]))
     return arrays
 
 
@@ -544,16 +636,17 @@ def init_linear_hybrid_model(key, config, dtype=jnp.float32):
     if not c.get("tie_word_embeddings"):
         model["head"] = {"w": scaled_normal(kh, (d, c["vocab_size"]), d,
                                             dtype)}
-    return _describe_linear_hybrid(model, c)
+    return _describe_layer_types(model, c)
 
 
 def describe_token_model(arrays, config, first=0):
     """Weights made elsewhere in this layout (arrays only) become a model:
     every block is given its static entries.  Returns ``arrays``.  A
     configuration with ``layer_types`` or ``mb_per_layer`` describes a
-    model of mixed layer kinds (:func:`hybrid_layer_kinds`)."""
+    model of mixed layer kinds (:func:`hybrid_layer_kinds`).  ``first``
+    is the first routed expert held here."""
     if "layer_types" in config:
-        return _describe_linear_hybrid(arrays, config)
+        return _describe_layer_types(arrays, config, first)
     if "mb_per_layer" in config:
         return _describe_hybrid(arrays, config)
     mla_spec, route = token_model_specs(config, first)
@@ -798,8 +891,9 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
                 q, k, v = _plain_qkv(blk, _pre(blk, "ln1", x), compute_dtype)
                 sink.append({"k": k.reshape(*k.shape[:2], -1),
                              "v": v.reshape(*v.shape[:2], -1)})
-                with jax.named_scope("full"):
-                    a = diff_attn_fn(q, k, v, q.shape[-1] ** -0.5, None)
+                window = _window_of(blk)
+                with jax.named_scope("window" if window else "full"):
+                    a = diff_attn_fn(q, k, v, q.shape[-1] ** -0.5, window)
                 x = x + _post(blk, "ln1", _plain_out(blk, a, compute_dtype))
         else:
             with jax.named_scope("attn"):
@@ -990,7 +1084,9 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
     pieces, H / pieces, dv, dk)`` float32 (the heads cut in the fewest
     pieces that :func:`_pool_rows` gathers without the compiler slicing
     the whole pool) and three flat tails.  A plain attention layer of
-    such a model keeps a full-length pair.  Every leaf is flat behind its
+    such a model keeps a ring of its window (:class:`AttnSpec`) or a
+    full-length pair; a model of such layers alone keeps nothing else.
+    Every leaf is flat behind its
     row or position, which is the layout the TPU compiler keeps as it is handed
     it (compiled for a described v5e; ``tests/test_tpu_compile.py``):
     a minor pair of axes like ``(Hkv / 2, 2 Dh)`` or ``(d_conv - 1,
@@ -1050,8 +1146,7 @@ def init_cache(params, batch_size, dtype=jnp.bfloat16, length=None,
                         dtype if j else jnp.float32)
             elif "wk" in blk:
                 _, h_kv, dh = blk["wk"].shape
-                window = blk["diff"]["spec"].window if "diff" in blk else None
-                ring = min(window or length, length)
+                ring = min(_window_of(blk) or length, length)
                 for name in ("k", "v"):
                     own[name] = jnp.zeros((batch_size, ring, h_kv * dh),
                                           dtype)
@@ -1222,10 +1317,12 @@ def decode_step(params, cache, obs_t, compute_dtype=jnp.bfloat16,
 def _decode(params, cache, obs_t, compute_dtype=jnp.bfloat16,
             moe_impl="dense", moe_k=2, moe_capacity_factor=1.25,
             moe_dispatch="sort", window=None, slots=None, valid=None):
-    """:func:`decode_step`, returning the held-share layers' counts too
-    (``(prediction, cache, auxs)``); ``valid`` (B,) marks the rows those
-    counts are over (a padded bucket's pad rows are computed like any
-    other and counted by nobody)."""
+    """:func:`decode_step`, returning the model's counts too
+    (``(prediction, cache, auxs)``: each held-share layer's under
+    ``counts``, a model of mixed kinds' :meth:`_HybridStep.counts` under
+    ``live``); ``valid`` (B,) marks the rows those counts are over (a
+    padded bucket's pad rows are computed like any other and counted by
+    nobody)."""
     from jax import lax
 
     pool_pos = cache["pos"]
@@ -1357,13 +1454,12 @@ class _HybridStep:
         """``HYBRID_EVENTS``' device half over the ``valid`` rows (all by
         default): the live positions of the rows stepped (the one being
         written included), the rows, and the positions live in one
-        window ring."""
+        window ring (the first window layer's, differential or plain)."""
         live = self.pos + 1
         valid = jnp.ones_like(live, bool) if valid is None else valid
-        window = next((blk["diff"]["spec"].window for blk in params["blocks"]
-                       if "wk" in blk and "diff" in blk
-                       and blk["diff"]["spec"].window), 0)
-        return {"counts": jnp.stack([
+        window = next((_window_of(blk) for blk in params["blocks"]
+                       if "wk" in blk and _window_of(blk)), 0)
+        return {"live": jnp.stack([
             jnp.sum(jnp.where(valid, live, 0)), jnp.sum(valid),
             jnp.sum(jnp.where(valid, jnp.minimum(live, window), 0))])}
 
@@ -1413,7 +1509,7 @@ class _HybridStep:
             if "diff" in blk:
                 q = diffattn.project_q(blk, h, dtype)
             else:  # plain attention: the three projections at once
-                q, *fresh = _plain_qkv(blk, h, dtype)
+                q, *fresh = _plain_qkv(blk, h, dtype, self.pos)
             if "wk" not in blk:
                 self._keep(i, (), ())
             else:
@@ -1434,7 +1530,9 @@ class _HybridStep:
                                  for pool in pools]
                 self.kv_rows = pools
             if "diff" not in blk:
-                with jax.named_scope("full"):
+                # a window layer's ring holds its window: the ring's mask is
+                # the window's
+                with jax.named_scope("window" if _window_of(blk) else "full"):
                     return _plain_out(blk, _attend_rows(
                         q, *self.kv_rows, self.pos, dtype), dtype)
             with jax.named_scope(_diff_kind(blk)):

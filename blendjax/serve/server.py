@@ -304,8 +304,10 @@ MOE_EVENTS = ("serve_moe_assignments", "serve_moe_assignments_held",
 
 #: the counts a step of a model of mixed layer kinds returns, in
 #: ``seqformer._HybridStep.counts``' order (a model without window layers
-#: counts 0 window positions), the one its resets make (rows whose
-#: recurrent state was zeroed), and the bytes of recurrent state and
+#: counts 0 window positions; a routed one returns them after
+#: ``MOE_EVENTS``), the one its resets make (rows whose recurrent state
+#: was zeroed: a model without recurrent state makes none), and the bytes
+#: of recurrent state and
 #: convolution tails its steps' real rows read and wrote (the rows
 #: stepped times twice ``seqformer.state_row_bytes``, added where the
 #: step's counts are fetched); all in ``SERVE_EVENTS``
@@ -531,17 +533,22 @@ class SeqFormerModel:
             self.obs_dim = (emb["w"] if "w" in emb else emb["w_q"]).shape[0]
             self.obs_dtype = np.float32
         routed = any("route" in blk.get("moe", ()) for blk in params["blocks"])
+        hybrid = seqformer._hybrid(params)
         # recurrent state in the pool: a reset zeroes it (and counts it)
-        self._recurrent = seqformer._hybrid(params)
+        self._recurrent = seqformer._recurrent(params)
         if window is not None and seqformer._latent(params):
             raise ValueError("latent attention has no windowed path")
-        if window is not None and self._recurrent:
+        if window is not None and hybrid:
             raise ValueError("a model of mixed layer kinds takes its "
                              "windows from its description")
-        # the names of the counts a step returns beside its reply
-        self._step_events = (MOE_EVENTS if routed else
-                             HYBRID_EVENTS[:3] if self._recurrent else ())
-        counted = bool(self._step_events)
+        # the counts a step returns beside its reply: the held-share
+        # layers' (their aux entry `counts`, summed over layers), then a
+        # hybrid step's (`live`), under these names in this order
+        kinds = (("counts",) if routed else ()) + (("live",) if hybrid
+                                                   else ())
+        self._step_events = ((MOE_EVENTS if routed else ())
+                             + (HYBRID_EVENTS[:3] if hybrid else ()))
+        counted = bool(kinds)
         self._events = {}
         cdt = compute_dtype or jnp.float32
         self._cache_dtype = cache_dtype or cdt
@@ -578,11 +585,13 @@ class SeqFormerModel:
                     slots=idx, valid=(idx != pad) if counted else None,
                 )
             if counted:
-                # the model's counts over the real rows (the held-share
-                # layers', summed over layers; a hybrid step's): they ride
-                # the reply's fence
-                return (reply_row(pred),
-                        sum(a["counts"] for a in auxs)), cache
+                # the model's counts over the real rows: they ride the
+                # reply's fence
+                row = reply_row(pred)
+                counts = [sum(a[kind] for a in auxs if kind in a)
+                          for kind in kinds]
+                return (row, counts[0] if len(counts) == 1
+                        else jnp.concatenate(counts)), cache
             return reply_row(pred), cache
 
         # one compilation per (bucket,) shape — the bucket/recompile
@@ -744,7 +753,7 @@ class SeqFormerModel:
     def drain_events(self):
         """Counts the model's steps and resets made since the last call
         (the routed layers' ``MOE_EVENTS``, a hybrid model's
-        ``HYBRID_EVENTS``), for the server's counters."""
+        ``HYBRID_EVENTS``, or both), for the server's counters."""
         events, self._events = self._events, {}
         return events
 

@@ -160,7 +160,7 @@ def test_routing(case):
     router = {"w": jax.random.normal(kw, (32, 16)) * 32 ** -0.5,
               "bias": jnp.zeros((16,))}
     x = jax.random.normal(kx, (50, 32))
-    sel, g = moe.route_sigmoid(router, x, spec)
+    sel, g = moe.route(router, x, spec)
     if case == "weights":
         np.testing.assert_allclose(g.sum(-1), 2.5, rtol=1e-6)
         return
@@ -168,7 +168,7 @@ def test_routing(case):
         # a bias on expert 7 pulls it into every selection and leaves the
         # weight it gets (its own score's share) as it would be unbiased
         biased = dict(router, bias=router["bias"].at[7].set(10.0))
-        sel_b, g_b = moe.route_sigmoid(biased, x, spec)
+        sel_b, g_b = moe.route(biased, x, spec)
         assert (sel_b == 7).any(-1).all() and not (sel == 7).any(-1).all()
         s = jax.nn.sigmoid(x @ router["w"])
         picked = jnp.take_along_axis(s, sel_b, -1)

@@ -366,6 +366,92 @@ def test_linear_attention_pool_is_served_in_place_on_the_chip(
         assert "flash_fwd" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("what,n", [("step", 32), ("step", 64),
+                                    ("prefill", 1024), ("prefill", 1536),
+                                    ("prefill", 2048)])
+def test_window_and_full_pool_is_served_in_place_on_the_chip(
+        one_chip, what, n, monkeypatch):
+    """Sliding-window and full grouped-query attention beside
+    softmax-routed experts, at the published widths
+    (chipbench/configs/mellum2_ep4_serve_bf16.json) over one period: three
+    window layers and one full layer, 16 of 64 experts held.  The donated
+    pool (a ring of 1024 positions per window layer, 2560 per full layer,
+    K/V flat behind the position) is aliased to the output; no leaf of it
+    is copied, re-laid or sliced whole; the rows are read by pieces; the
+    grouped products are the compiler's own kernel; the step's
+    temporaries stay near one layer's gathered full K/V rows and the
+    head's logits; the prefill's attention, windowed on the window layers,
+    is the flash kernel."""
+    import importlib
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+    from blendjax.serve.server import SeqFormerModel
+    from chipbench import reference_mellum2
+
+    flash_attention = importlib.import_module("blendjax.ops.flash_attention")
+    monkeypatch.setattr(flash_attention, "resolve_interpret",
+                        lambda interpret=None: False)
+    path = os.path.join(os.path.dirname(reference_mellum2.__file__),
+                        "configs", "mellum2_ep4_serve_bf16.json")
+    with open(path) as f:
+        cfg = dict(json.load(f), num_hidden_layers=4)
+    tiny_cfg = dict(cfg, hidden_size=48, num_attention_heads=4,
+                    num_key_value_heads=2, head_dim=16,
+                    moe_intermediate_size=24, num_experts=8,
+                    num_experts_held=4, num_experts_per_tok=2,
+                    vocab_size=96, sliding_window=8)
+    tiny = SeqFormerModel(
+        seqformer.describe_token_model(reference_mellum2.make_params(
+            tiny_cfg, 0, jnp.bfloat16), tiny_cfg),
+        slots=2, length=16, compute_dtype=jnp.bfloat16,
+        cache_dtype=jnp.bfloat16)
+    params = seqformer.describe_token_model(jax.eval_shape(
+        lambda: reference_mellum2.make_params(cfg, 0, jnp.bfloat16)), cfg)
+    cache = jax.eval_shape(lambda: seqformer.init_cache(
+        params, cfg["slots"] + 1, dtype=jnp.bfloat16, length=cfg["length"],
+        per_row=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    fn = tiny._step if what == "step" else tiny._prefill
+    compiled = fn.lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((n if what == "step" else 1,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32, sharding=one_chip),
+    ).compile()
+    assert sorted(cache) == ["k", "pos", "v"]
+    assert [leaf.shape for leaf in cache["k"]] == [(73, 1024, 512)] * 3 + [
+        (73, 2560, 512)]
+    pool_bytes = sum(int(np.prod(leaf.shape)) * 2
+                     for leaf in cache["k"] + cache["v"])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    text = compiled.as_text()
+    assert f"jit_serve_{what}" in text
+    whole = [line for line in text.splitlines()
+             if re.search(r"= \w+\[73,", line)
+             and re.search(r" (copy|slice|copy-start|slice-start|"
+                           r"transpose)\(", line)]
+    assert not whole, whole[:3]
+    assert "mini-gather-slice" not in text
+    assert text.count("ragged-dot") >= 3 * 4  # gate, up, down a layer
+    if what == "step":
+        # one full layer's gathered K and V rows, the head's float32
+        # logits, and little else (measured 0.10 / 0.20 GB at 32 / 64)
+        full_rows = 2 * n * 2560 * 512 * 2
+        logits = n * cfg["vocab_size"] * 4
+        assert mem.temp_size_in_bytes < 1.5 * (full_rows + logits)
+    else:
+        assert "flash_fwd" in text and "tpu_custom_call" in text
+
+
 # the train cell (chipbench/configs/seqformer_wm100m_train_bf16.json: batch
 # 64 x 512, 8 heads of 128, bfloat16 compute, block 'auto'), the long
 # sequence of chip_smoke.py's kernel leg, and the widest float32 head the

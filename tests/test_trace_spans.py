@@ -539,6 +539,73 @@ def _linear_prefill():
         jnp.ones((3, 1), jnp.int32))
 
 
+TINY_WINDOW = dict(
+    hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, num_hidden_layers=4,
+    layer_types=["sliding_attention"] * 3 + ["full_attention"],
+    sliding_window=4, moe_intermediate_size=16, num_experts=8,
+    num_experts_held=4, num_experts_per_tok=2, norm_topk_prob=True,
+    rms_norm_eps=1e-6, vocab_size=64, rope_parameters={
+        "full_attention": dict(
+            rope_type="yarn", rope_theta=10000, factor=4, beta_fast=32,
+            beta_slow=1, original_max_position_embeddings=64,
+            attention_factor=1.2),
+        "sliding_attention": dict(rope_type="default", rope_theta=1000)})
+
+
+def _tiny_window_model():
+    from chipbench import reference_mellum2
+
+    return SeqFormerModel(
+        seqformer.describe_token_model(
+            reference_mellum2.make_params(TINY_WINDOW, 0, jnp.float32),
+            TINY_WINDOW),
+        slots=2, length=16)
+
+
+def _window_step():
+    model = _tiny_window_model()
+    return model._step.lower(
+        model.params, model._cache, jnp.zeros(2, jnp.int32),
+        jnp.ones((2, 1), jnp.int32))
+
+
+def _window_prefill():
+    model = _tiny_window_model()
+    return model._prefill.lower(
+        model.params, model._cache, jnp.zeros(1, jnp.int32),
+        jnp.ones((6, 1), jnp.int32))
+
+
+def test_a_routed_model_of_window_and_full_layers_counts_both_sets():
+    """One step of a routed model of mixed kinds returns the held-share
+    layers' counts and the hybrid step's beside its reply; nothing is
+    recurrent, so a reset zeroes nothing and no state bytes move."""
+    from blendjax.serve.server import HYBRID_EVENTS, MOE_EVENTS
+
+    model = _tiny_window_model()
+    assert model._step_events == MOE_EVENTS + HYBRID_EVENTS[:3]
+    model.reset_rows(np.asarray([1]))
+    model.prefill_rows(np.asarray([1]), np.ones((5, 1), np.int32))
+    for _ in range(3):  # positions 5, 6, 7; the pad row beside them
+        np.asarray(model.step_rows(np.asarray([1, model.pad_slot]),
+                                   np.ones((2, 1), np.int32)))
+    events = model.drain_events()
+    held = events.pop("serve_moe_assignments_held")
+    hit = events.pop("serve_moe_experts_hit")
+    assert 0 < hit <= held <= 3 * 4 * 2
+    assert events == {
+        "serve_moe_assignments": 3 * 4 * 2,
+        "serve_ctx_positions": 6 + 7 + 8, "serve_rows_stepped": 3,
+        "serve_window_positions": 3 * 4, "serve_state_bytes": 0}
+
+
+@pytest.mark.parametrize("lower", [_window_step, _window_prefill])
+def test_each_attention_kind_has_its_scope_under_attn(lower):
+    text = lower().as_text(debug_info=True)
+    assert "/attn/window/" in text and "/attn/full/" in text
+
+
 def test_linear_attention_model_counters_move_in_a_served_episode():
     """A model without window layers counts no window positions, and the
     other counts stand; ``serve_state_bytes`` is twice the state and tails
@@ -609,6 +676,12 @@ def test_routed_model_counters_are_in_the_vocabulary(name):
     (_linear_prefill, "serve_prefill",
      ("forward", "gdn", "conv", "gate", "chunk", "attn", "full", "scatter",
       "mlp", "ln", "head")),
+    (_window_step, "serve_step",
+     ("decode", "attn", "window", "full", "scatter", "gather", "moe",
+      "route", "experts", "ln", "head")),
+    (_window_prefill, "serve_prefill",
+     ("forward", "attn", "window", "full", "scatter", "moe", "route",
+      "experts", "ln", "head")),
     (_train_step, "train_step",
      ("loss", "optimizer", "attn", "mlp", "ln")),
     (_serve_step, "serve_step",
