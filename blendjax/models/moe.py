@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from blendjax.models.layers import dense_apply, gelu, scaled_normal
+from blendjax.ops.expert_ffn import expert_ffn
 
 
 def expert_capacity(n_tokens, n_experts, k, capacity_factor):
@@ -311,10 +312,11 @@ def moe_apply_held(p, x, dtype, valid=None):
     the shared expert: ``x`` (n, d) is routed over all experts
     (:func:`route`), the assignments whose expert lies in
     ``[first, first + held)`` are sorted by expert and multiplied group
-    by group (``jax.lax.ragged_dot``: no capacity, no padding arena, no
-    drop), and every token's held contributions are summed under their
-    weights.  What the absent experts would add is left out; with
-    ``first = 0`` and every expert stacked it is the whole layer.
+    by group (:func:`blendjax.ops.expert_ffn.expert_ffn`, one kernel for
+    the three products: no capacity, no padding arena, no drop), and
+    every token's held contributions are summed under their weights.
+    What the absent experts would add is left out; with ``first = 0``
+    and every expert stacked it is the whole layer.
 
     Returns ``(y (n, d), counts)``; ``counts`` is int32 ``[assignments
     made, assignments held here, distinct held experts with a token]``
@@ -350,10 +352,8 @@ def moe_apply_held(p, x, dtype, valid=None):
             xs = xs.reshape(n, d // 128, 128)[order // k].reshape(n * k, d)
         else:
             xs = xs[order // k]                              # (n * k, d)
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, p["gate"].astype(dtype),
-                                           sizes)) \
-            * jax.lax.ragged_dot(xs, p["up"].astype(dtype), sizes)
-        out = jax.lax.ragged_dot(h, p["down"].astype(dtype), sizes)
+        out = expert_ffn(xs, sizes, p["gate"].astype(dtype),
+                         p["up"].astype(dtype), p["down"].astype(dtype))
         # back to (token, choice) order; rows past the held groups are
         # whatever the product left there, so they are masked, not scaled
         out = out[jnp.argsort(order)].reshape(n, k, d)

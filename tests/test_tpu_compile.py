@@ -100,20 +100,29 @@ TOKEN_WIDTHS = dict(
 
 
 @pytest.mark.parametrize("what,n", [("step", 64), ("prefill", 256)])
-def test_latent_pool_is_served_in_place_on_the_chip(one_chip, what, n):
+def test_latent_pool_is_served_in_place_on_the_chip(one_chip, what, n,
+                                                    monkeypatch):
     """A latent-attention, routed-expert token model at its published
     widths: the donated latent pool is aliased to the output with no
     pool-shaped `copy` (a row 576 wide would be laid out positions-minor
     and copied twice a layer: `mla.row_width` pads it to whole lanes),
-    the rows are read by pieces, the grouped products are the TPU
-    compiler's own kernel, and the decode step's temporaries stay far
-    under one layer of per-head K and V (absorbed attention never forms
-    them)."""
+    the rows are read by pieces, the held experts' three products are
+    one `expert_ffn` kernel a layer, and the decode step's temporaries
+    stay far under one layer of per-head K and V (absorbed attention
+    never forms them)."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
 
     from blendjax.models import seqformer
     from blendjax.serve.server import SeqFormerModel
+
+    flash_attention = importlib.import_module("blendjax.ops.flash_attention")
+    # this process's backend is the CPU, where the kernels interpret: the
+    # chip compiles them (the guide's "steer such code in the test")
+    monkeypatch.setattr(flash_attention, "resolve_interpret",
+                        lambda interpret=None: False)
 
     tiny_widths = dict(
         TOKEN_WIDTHS, hidden_size=32, num_attention_heads=2, kv_lora_rank=16,
@@ -153,7 +162,9 @@ def test_latent_pool_is_served_in_place_on_the_chip(one_chip, what, n):
     assert not [line for line in text.splitlines()
                 if re.search(r" copy\(", line) and "[129,2048,640]" in line]
     assert "mini-gather-slice" not in text
-    assert text.count("ragged-dot") >= 3  # gate, up, down: grouped kernels
+    # one expert layer of the two: its gate, up and down in one kernel
+    assert len(re.findall(r"%expert_ffn[.\d]* = ", text)) == 1
+    assert "ragged-dot" not in text
     if what == "step":
         # the gathered latent rows of one layer and little else; one
         # layer's per-head K and V of those rows would be 5.4 GB
@@ -378,10 +389,10 @@ def test_window_and_full_pool_is_served_in_place_on_the_chip(
     pool (a ring of 1024 positions per window layer, 2560 per full layer,
     K/V flat behind the position) is aliased to the output; no leaf of it
     is copied, re-laid or sliced whole; the rows are read by pieces; the
-    grouped products are the compiler's own kernel; the step's
-    temporaries stay near one layer's gathered full K/V rows and the
-    head's logits; the prefill's attention, windowed on the window layers,
-    is the flash kernel."""
+    held experts' three products are one `expert_ffn` kernel a layer; the
+    step's temporaries stay near one layer's gathered full K/V rows and
+    the head's logits; the prefill's attention, windowed on the window
+    layers, is the flash kernel."""
     import importlib
     import json
 
@@ -441,7 +452,9 @@ def test_window_and_full_pool_is_served_in_place_on_the_chip(
                            r"transpose)\(", line)]
     assert not whole, whole[:3]
     assert "mini-gather-slice" not in text
-    assert text.count("ragged-dot") >= 3 * 4  # gate, up, down a layer
+    # the held experts' three products, one kernel in each of four layers
+    assert len(re.findall(r"%expert_ffn[.\d]* = ", text)) == 4
+    assert "ragged-dot" not in text
     if what == "step":
         # one full layer's gathered K and V rows, the head's float32
         # logits, and little else (measured 0.10 / 0.20 GB at 32 / 64)
@@ -509,3 +522,33 @@ def test_flash_kernels_compile_at_the_policys_tiles(
         assert operands.count(block) == n_blocks, (kernel, operands)
         assert operands.count(rows) == (0 if kernel == "flash_fwd" else 2)
     assert rows in calls["flash_fwd"].split(" custom-call(")[0]  # lse out
+
+
+# the held experts of the two routed cells, at the rows (top 8) of every
+# bucket they serve (8, 16, 32, 40, 48, 64) and of every prefill length
+# they warm: the tiles come from the shapes, so each row count is its own
+# compile (a 1536-id prefill's row gather was once refused for scoped
+# VMEM where 1024 and 2048 compiled)
+@pytest.mark.parametrize("d,f,held,prefills", [
+    (2304, 896, 16, (1024, 1536, 2048)),   # mellum2_ep4_serve_bf16
+    (4096, 2048, 32, (256, 512, 1024)),    # sarvam105b_ep4_serve_bf16
+], ids=["mellum2", "sarvam"])
+def test_expert_ffn_compiles_at_every_bucket_and_prefill(
+        one_chip, d, f, held, prefills):
+    """The held experts' three products compile for the chip, within the
+    VMEM limit the tiles ask for, as one `expert_ffn` custom call, at
+    every row count the cells give them."""
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.ops.expert_ffn import expert_ffn
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(lambda *a: expert_ffn(*a, interpret=False))
+    for n in (8, 16, 32, 40, 48, 64) + prefills:
+        text = fn.lower(arg(8 * n, d), arg(held, dtype=jnp.int32),
+                        arg(held, d, f), arg(held, d, f),
+                        arg(held, f, d)).compile().as_text()
+        assert len(re.findall(r"%expert_ffn[.\d]* = ", text)) == 1, n
