@@ -112,6 +112,31 @@ def _gate_out(p, y, z, dtype):
     return _mm(y * jax.nn.silu(z), p["out_proj"], dtype).astype(dtype)
 
 
+def conv_sequence(w, b, fresh, tail, dtype):
+    """The depthwise causal convolution of ``taps`` and its SiLU over a
+    sequence: weights ``w`` (taps, C), bias ``b`` (C,), inputs ``fresh``
+    (B, T, C) in ``dtype`` after the ``tail`` (B, taps - 1, C) of the
+    inputs before them -> ``(silu(out) (B, T, C) float32, the tail after
+    T)``.  The sums are float32."""
+    t = fresh.shape[1]
+    padded = jnp.concatenate([tail.astype(dtype), fresh], axis=1)
+    w = w.astype(jnp.float32)
+    u = b.astype(jnp.float32) + sum(
+        padded[:, k:k + t].astype(jnp.float32) * w[k]
+        for k in range(w.shape[0]))
+    return jax.nn.silu(u), padded[:, t:].astype(tail.dtype)
+
+
+def conv_step(w, b, fresh, tail, dtype):
+    """:func:`conv_sequence` at one position a row: ``fresh`` (B, C) ->
+    ``(silu(out) (B, C) float32, the new tail)``."""
+    taps = jnp.concatenate([tail.astype(dtype), fresh[:, None]], axis=1)
+    u = jax.nn.silu(
+        b.astype(jnp.float32)
+        + jnp.sum(taps.astype(jnp.float32) * w.astype(jnp.float32), axis=1))
+    return u, taps[:, 1:].astype(tail.dtype)
+
+
 def _combine(lo, hi):
     """``h -> a h + b`` composed: first ``lo``, then ``hi``."""
     return hi[0] * lo[0], hi[0] * lo[1] + hi[1]
@@ -123,17 +148,12 @@ def mix_sequence(p, x, h, tail, dtype):
     ``(out (B, T, d), y (B, T, d_inner) float32, h after T, tail after
     T)``."""
     b, t, _ = x.shape
-    d_conv, d_inner = p["conv_w"].shape
+    d_inner = p["conv_w"].shape[1]
     uz = _mm(x, p["in_proj"], dtype)
     u_in, z = uz[..., :d_inner].astype(dtype), uz[..., d_inner:]
     with jax.named_scope("conv"):
-        padded = jnp.concatenate([tail.astype(dtype), u_in], axis=1)
-        w = p["conv_w"].astype(jnp.float32)
-        u = p["conv_b"].astype(jnp.float32) + sum(
-            padded[:, k:k + t].astype(jnp.float32) * w[k]
-            for k in range(d_conv))
-        u = jax.nn.silu(u)
-        new_tail = padded[:, t:].astype(tail.dtype)
+        u, new_tail = conv_sequence(p["conv_w"], p["conv_b"], u_in, tail,
+                                    dtype)
     with jax.named_scope("scan"):
         dt, b_sel, c_sel = _selection(p, u, dtype)
         a = -jnp.exp(p["a_log"].astype(jnp.float32))  # (n, d_inner)
@@ -169,12 +189,7 @@ def mix_step(p, x, h, tail, dtype):
     uz = _mm(x, p["in_proj"], dtype)
     u_in, z = uz[..., :d_inner].astype(dtype), uz[..., d_inner:]
     with jax.named_scope("conv"):
-        taps = jnp.concatenate([tail.astype(dtype), u_in[:, None]], axis=1)
-        u = jax.nn.silu(
-            p["conv_b"].astype(jnp.float32)
-            + jnp.sum(taps.astype(jnp.float32)
-                      * p["conv_w"].astype(jnp.float32), axis=1))
-        new_tail = taps[:, 1:].astype(tail.dtype)
+        u, new_tail = conv_step(p["conv_w"], p["conv_b"], u_in, tail, dtype)
     with jax.named_scope("update"):
         dt, b_sel, c_sel = _selection(p, u, dtype)
         a = -jnp.exp(p["a_log"].astype(jnp.float32))

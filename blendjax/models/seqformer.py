@@ -54,20 +54,26 @@ block that made any (a *cross* layer: eight layers then read one
 buffer).  Such a model has no positional encoding.  A `gdn` entry is
 gated delta-rule linear attention (:mod:`blendjax.models.deltanet`: a
 float32 matrix state a head and three convolution tails per sequence);
+an `ssd` entry a Mamba-2 mixer (:mod:`blendjax.models.mamba2`: a float32
+matrix state a head with a scalar decay, one convolution tail);
 `wq`/`wk`/`wv`/`wo` without a `diff` entry, inside such a model, are plain
 softmax attention, with a `q_norm` / `k_norm` RMSNorm where the block
 holds them (over each head where its scale is a head wide, else over the
 whole projection) and, where the block holds an `attn` entry (its static
-:class:`AttnSpec`), a window (a ring of that many positions) and a
-rotary embedding of its own; without one, over a full-length K/V and
-unrotated.  The kinds are then the configuration's own ``layer_types``
-(:func:`init_linear_hybrid_model`; :func:`describe_token_model`): a
-model of window and full attention layers only, with no recurrent
-block, is such a model too.
+:class:`AttnSpec`), a window (a ring of that many positions), a
+rotary embedding of its own and a softmax scale; without one, over a
+full-length K/V, unrotated, at ``1 / sqrt(Dh)``.  The kinds are then the
+configuration's own ``layer_types`` (:func:`init_linear_hybrid_model`,
+:func:`init_ssd_hybrid_model`; :func:`describe_token_model`): a model of
+window and full attention layers only, with no recurrent block, is such a
+model too.
 
 **The norm's place is read off the block too**: `ln1` / `ln2` norm a
 sublayer's input (``x + f(ln(x))``), `post_ln1` / `post_ln2` its output
-(``x + ln(f(x))``, the Olmo 2 convention).
+(``x + ln(f(x))``, the Olmo 2 convention).  A model with muP multipliers
+holds a static :class:`MupSpec` as ``mup``, at the top (the embedding's
+rows times ``embedding``, the logits over ``logits``) and in each block
+(each sublayer's output times ``residual`` before its residual add).
 """
 
 from __future__ import annotations
@@ -79,7 +85,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from blendjax.models import deltanet, diffattn, mamba, mla
+from blendjax.models import deltanet, diffattn, mamba, mamba2, mla
 from blendjax.models.layers import (
     apply_rope,
     apply_rope_rows,
@@ -151,6 +157,17 @@ def _ln_apply(p, x):
     return (out * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class MupSpec:
+    """A model's muP multipliers (an entry ``"mup"`` at the top of the
+    params and in each block; absent, every one is 1)."""
+
+    embedding: float = 1.0
+    residual: float = 1.0
+    logits: float = 1.0
+
+
 def _pre(blk, name, x):
     """A sublayer's input: normed where the block norms before it."""
     return _ln_apply(blk[name], x) if name in blk else x
@@ -158,9 +175,12 @@ def _pre(blk, name, x):
 
 def _post(blk, name, y):
     """A sublayer's output: normed where the block norms after it
-    (``post_ln1`` / ``post_ln2`` in place of ``ln1`` / ``ln2``)."""
+    (``post_ln1`` / ``post_ln2`` in place of ``ln1`` / ``ln2``), then
+    times the block's residual multiplier where it has one."""
     name = "post_" + name
-    return _ln_apply(blk[name], y) if name in blk else y
+    if name in blk:
+        y = _ln_apply(blk[name], y)
+    return y * blk["mup"].residual if "mup" in blk else y
 
 
 def _moe_init(key, n_experts, d, d_ff):
@@ -201,6 +221,7 @@ def _latent(params):
 _MIXERS = {
     "ssm": (mamba, ("ssm_h", "ssm_tail")),
     "gdn": (deltanet, ("gdn_s", "gdn_tail_q", "gdn_tail_k", "gdn_tail_v")),
+    "ssd": (mamba2, ("ssd_s", "ssd_tail")),
 }
 _RECURRENT = tuple(name for _, names in _MIXERS.values() for name in names)
 
@@ -234,16 +255,18 @@ def _hybrid(params):
 class AttnSpec:
     """What the shapes of a plain attention block of a model of mixed
     kinds do not say (its entry ``attn``): how many positions a query
-    sees, its own included (``window``; None: all of them), and its
+    sees, its own included (``window``; None: all of them), its
     rotary embedding (``theta``; None: none): the default rotation, or
     with ``yarn`` (``factor, beta_fast, beta_slow, original_max``) the
     YaRN frequencies, the tables times ``attention_factor``
-    (:func:`~blendjax.models.layers.rope_table`)."""
+    (:func:`~blendjax.models.layers.rope_table`), and the softmax's
+    ``scale`` (None: ``1 / sqrt(Dh)``)."""
 
     window: int | None = None
     theta: float | None = None
     yarn: tuple | None = None
     attention_factor: float | None = None
+    scale: float | None = None
 
 
 def _window_of(blk):
@@ -251,6 +274,12 @@ def _window_of(blk):
     if "diff" in blk:
         return blk["diff"]["spec"].window
     return blk["attn"].window if "attn" in blk else None
+
+
+def _scale_of(blk, dh):
+    """The softmax scale of a plain attention block of ``Dh``-wide heads."""
+    spec = blk.get("attn")
+    return dh ** -0.5 if spec is None or spec.scale is None else spec.scale
 
 
 def _windows_are_described(hybrid, window):
@@ -263,16 +292,25 @@ def _embed(params, obs, dtype):
     """Observations through the dense projection, or int ids through the
     table (an id outside it is clipped)."""
     emb = params["embed"]
-    if "table" in emb:
-        return jnp.take(emb["table"], obs, axis=0, mode="clip").astype(dtype)
-    return _dense_mq(emb, obs.astype(dtype), dtype)
+    if "table" not in emb:
+        return _dense_mq(emb, obs.astype(dtype), dtype)
+    rows = jnp.take(emb["table"], obs, axis=0, mode="clip")
+    if "mup" in params:
+        rows = rows.astype(jnp.float32) * params["mup"].embedding
+    return rows.astype(dtype)
 
 
 @jax.named_scope("head")
 def _head(params, x, dtype):
     """float32 predictions: the observation head, or logits over the
     vocabulary slice (a bias-free head; products in ``dtype``,
-    accumulated in float32, the slice's weights never upcast)."""
+    accumulated in float32, the slice's weights never upcast), over the
+    model's logit divisor where it has one."""
+    out = _predict(params, x, dtype)
+    return out / params["mup"].logits if "mup" in params else out
+
+
+def _predict(params, x, dtype):
     if "head" not in params:  # tied: the embedding table, transposed
         return jnp.einsum("...d,vd->...v", x.astype(dtype),
                           params["embed"]["table"].astype(dtype),
@@ -329,11 +367,12 @@ def _plain_out(blk, a, dtype):
                       blk["wo"].astype(dtype))
 
 
-def _attend_rows(q, kc, vc, pos, dtype):
+def _attend_rows(q, kc, vc, pos, dtype, scale):
     """One query a row, ``q`` (B, H, Dh), over that row's cached keys and
     values, flat ``(B, C, Hkv * Dh)`` rows of a ring written at ``p % C``
     and masked by the absolute position a slot holds (as
-    :func:`_attn_one`), at position ``pos`` (B,) -> (B, H, Dh) float32.
+    :func:`_attn_one`), at position ``pos`` (B,) and softmax ``scale`` ->
+    (B, H, Dh) float32.
     Each K/V head is read where it lies, as the whole-lane columns of the
     rows (``diffattn.attend_one`` says why)."""
     b, c, _ = kc.shape
@@ -347,7 +386,7 @@ def _attend_rows(q, kc, vc, pos, dtype):
         cols = slice(i * dh, (i + 1) * dh)
         s = jnp.einsum("bme,bce->bmc", qs[:, i], kc[..., cols].astype(dtype),
                        preferred_element_type=jnp.float32)
-        w = jax.nn.softmax(jnp.where(keep[:, None], s * dh ** -0.5, -1e30),
+        w = jax.nn.softmax(jnp.where(keep[:, None], s * scale, -1e30),
                            axis=-1)
         outs.append(jnp.einsum(
             "bmc,bce->bme", w.astype(dtype), vc[..., cols].astype(dtype),
@@ -431,17 +470,20 @@ def token_model_specs(config, first=0):
     )
 
 
-#: a published ``layer_types`` entry -> the kind it is here
+#: a published ``layer_types`` entry -> the kind it is here (a
+#: ``granitemoehybrid`` model's ``mamba`` is Mamba-2, its ``attention``
+#: full attention)
 _LAYER_TYPES = {"linear_attention": "gdn", "full_attention": "full",
-                "sliding_attention": "window"}
+                "sliding_attention": "window", "mamba": "ssd",
+                "attention": "full"}
 
 
 def hybrid_layer_kinds(config):
     """The kind of every layer of a model of mixed layer kinds.  Where
     the configuration publishes ``layer_types`` they are its first
     ``num_hidden_layers`` entries (``"gdn"`` for ``linear_attention``,
-    ``"full"`` for ``full_attention``, ``"window"`` for
-    ``sliding_attention``).  Otherwise the rule of a
+    ``"full"`` for ``full_attention`` and ``attention``, ``"window"`` for
+    ``sliding_attention``, ``"ssd"`` for ``mamba``).  Otherwise the rule of a
     decoder-hybrid-decoder model
     (arXiv:2507.06607), from ``num_hidden_layers`` and ``mb_per_layer``:
     in the first half every ``mb_per_layer``-th layer is ``"ssm"`` and
@@ -534,53 +576,83 @@ def init_hybrid_model(key, config, dtype=jnp.float32):
 
 def _attn_spec(config, layer_type, kind):
     """A plain attention layer's :class:`AttnSpec` from the published
-    keys, or None where it has neither a window nor a rotation:
-    ``sliding_window`` for a window layer, and ``rope_parameters`` (one
-    entry for every kind, or an entry by layer type) of ``rope_type``
-    ``default`` or ``yarn``; a ``rope_theta`` of null is no rotation."""
+    keys, or None where it has no window, no rotation and the default
+    scale: ``sliding_window`` for a window layer, ``attention_multiplier``
+    for the scale, and ``rope_parameters`` (one entry for every kind, or
+    an entry by layer type) of ``rope_type`` ``default`` or ``yarn``; a
+    ``rope_theta`` of null, or a ``position_embedding_type`` of ``nope``,
+    is no rotation."""
     window = config["sliding_window"] if kind == "window" else None
+    scale = config.get("attention_multiplier")
+    scale = None if scale is None else float(scale)
     rope = config.get("rope_parameters") or {}
     rope = rope.get(layer_type, rope)
-    if rope.get("rope_theta") is None:
-        return None if window is None else AttnSpec(window)
-    yarn = scale = None
+    if (rope.get("rope_theta") is None
+            or config.get("position_embedding_type") == "nope"):
+        if window is None and scale is None:
+            return None
+        return AttnSpec(window, scale=scale)
+    yarn = factor = None
     rope_type = rope.get("rope_type", "default")
     if rope_type == "yarn":
         yarn = (float(rope["factor"]), rope["beta_fast"], rope["beta_slow"],
                 rope["original_max_position_embeddings"])
-        scale = rope.get("attention_factor")
-        scale = float(yarn_mscale(yarn[0]) if scale is None else scale)
+        factor = rope.get("attention_factor")
+        factor = float(yarn_mscale(yarn[0]) if factor is None else factor)
     elif rope_type != "default":
         raise ValueError(f"rope_type {rope_type!r} is not served")
-    return AttnSpec(window, float(rope["rope_theta"]), yarn, scale)
+    return AttnSpec(window, float(rope["rope_theta"]), yarn, factor, scale)
+
+
+def _mup_spec(config):
+    """A model's :class:`MupSpec` from the published keys
+    ``embedding_multiplier``, ``residual_multiplier`` and
+    ``logits_scaling``, or None where it publishes none of them."""
+    keys = ("embedding_multiplier", "residual_multiplier", "logits_scaling")
+    if not any(key in config for key in keys):
+        return None
+    return MupSpec(*(float(config.get(key, 1.0)) for key in keys))
 
 
 def _describe_layer_types(arrays, config, first=0):
     """The static entries of a model whose configuration lists its
-    ``layer_types`` (linear, window and full attention): each norm's
-    epsilon, each linear block's :class:`deltanet.GdnSpec`, each plain
-    attention block's :class:`AttnSpec` where its kind has a window or a
-    rotation, and each routed layer's ``RouteSpec``: softmax over all
-    ``num_experts``, the ``num_experts_per_tok`` renormalised where
-    ``norm_topk_prob`` says so, the held experts from ``first``."""
+    ``layer_types`` (linear attention, Mamba-2, window and full
+    attention): each norm's epsilon, each linear block's
+    :class:`deltanet.GdnSpec`, each Mamba-2 block's
+    :class:`mamba2.SsdSpec`, each plain attention block's
+    :class:`AttnSpec` where its kind has a window, a rotation or a scale
+    of its own, the :class:`MupSpec` where the model has one, and each
+    routed layer's ``RouteSpec``: softmax over all ``num_experts``, the
+    ``num_experts_per_tok`` renormalised where ``norm_topk_prob`` says
+    so, the held experts from ``first``."""
     eps = float(config["rms_norm_eps"])
     kinds = hybrid_layer_kinds(config)
     if len(kinds) != len(arrays["blocks"]):
         raise ValueError(f"{len(arrays['blocks'])} blocks for {len(kinds)} "
                          "layers")
     arrays["ln_f"]["spec"] = NormSpec(eps)
+    mup = _mup_spec(config)
+    if mup:
+        arrays["mup"] = mup
     for layer, (kind, layer_type, blk) in enumerate(zip(
             kinds, config["layer_types"], arrays["blocks"])):
-        if ("gdn" in blk) != (kind == "gdn"):
+        held = _mixer(blk)
+        if (held[0] if held else None) != (kind if kind in _MIXERS
+                                           else None):
             raise ValueError(f"layer {layer} is {kind!r} by the "
                              f"configuration and holds {sorted(blk)}")
         for name in ("ln1", "ln2", "post_ln1", "post_ln2", "q_norm",
                      "k_norm"):
             if name in blk:
                 blk[name]["spec"] = NormSpec(eps)
+        if mup:
+            blk["mup"] = mup
         if kind == "gdn":
             blk["gdn"]["spec"] = deltanet.GdnSpec(
                 bool(config["linear_allow_neg_eigval"]), eps)
+        elif kind == "ssd":
+            blk["ssd"]["spec"] = mamba2.SsdSpec(
+                int(config["mamba_chunk_size"]), eps)
         elif spec := _attn_spec(config, layer_type, kind):
             blk["attn"] = spec
         if "moe" in blk:
@@ -588,6 +660,50 @@ def _describe_layer_types(arrays, config, first=0):
                 top_k=config["num_experts_per_tok"], first=first,
                 score="softmax", renorm=bool(config["norm_topk_prob"]))
     return arrays
+
+
+def _init_recurrent_hybrid(key, config, mixer, norms, d_ff, table_fan_in,
+                           qk_norm, dtype):
+    """The blocks, embedding and head of a token model whose recurrent
+    layers (``hybrid_layer_kinds``' kind ``mixer[0]``, built by
+    ``mixer[1](key)``) stand beside plain grouped-query attention: per
+    block the RMSNorms named ``norms`` and a gated SiLU feed-forward of
+    ``d_ff``; the attention head-major, with q/k norms over the whole
+    projection if ``qk_norm``; the table normal at ``table_fan_in ** -0.5``;
+    an untied head unless the configuration ties it."""
+    c = config
+    d, heads = c["hidden_size"], c["num_attention_heads"]
+    kinds = hybrid_layer_kinds(c)
+    ke, kh, *kb = jax.random.split(key, 2 + len(kinds))
+
+    def norm(width):
+        return {"scale": jnp.ones((width,), dtype)}
+
+    blocks = []
+    for kind, k in zip(kinds, kb):
+        km, kf = jax.random.split(k)
+        blk = {name: norm(d) for name in norms}
+        blk["mlp"] = gated_mlp_init(kf, d, d_ff, dtype)
+        if kind == mixer[0]:
+            blk[kind] = mixer[1](km)
+        else:
+            h_kv, dh = c["num_key_value_heads"], d // heads
+            kq, kk, kv, ko = jax.random.split(km, 4)
+            blk.update(
+                wq=scaled_normal(kq, (d, heads, dh), d, dtype),
+                wk=scaled_normal(kk, (d, h_kv, dh), d, dtype),
+                wv=scaled_normal(kv, (d, h_kv, dh), d, dtype),
+                wo=scaled_normal(ko, (heads, dh, d), heads * dh, dtype))
+            if qk_norm:
+                blk.update(q_norm=norm(heads * dh), k_norm=norm(h_kv * dh))
+        blocks.append(blk)
+    model = {"embed": {"table": scaled_normal(
+                 ke, (c["vocab_size"], d), table_fan_in, dtype)},
+             "blocks": blocks, "ln_f": norm(d)}
+    if not c.get("tie_word_embeddings"):
+        model["head"] = {"w": scaled_normal(kh, (d, c["vocab_size"]), d,
+                                            dtype)}
+    return _describe_layer_types(model, c)
 
 
 def init_linear_hybrid_model(key, config, dtype=jnp.float32):
@@ -601,42 +717,43 @@ def init_linear_hybrid_model(key, config, dtype=jnp.float32):
     head, gated SiLU feed-forwards, a q/k norm over the whole projection
     in the full layers, no positional encoding, no biases."""
     c = config
-    d, heads = c["hidden_size"], c["num_attention_heads"]
     if c["linear_num_key_heads"] != c["linear_num_value_heads"]:
         raise ValueError("linear key and value heads differ in number")
-    kinds = hybrid_layer_kinds(c)
-    ke, kh, *kb = jax.random.split(key, 2 + len(kinds))
 
-    def norm(width):
-        return {"scale": jnp.ones((width,), dtype)}
+    def mixer(key):
+        return deltanet.init(
+            key, c["hidden_size"], c["linear_num_key_heads"],
+            c["linear_key_head_dim"], c["linear_value_head_dim"],
+            c["linear_conv_kernel_dim"], dtype=dtype)
+    return _init_recurrent_hybrid(
+        key, c, ("gdn", mixer), ("post_ln1", "post_ln2"),
+        c["intermediate_size"], 1.0, True, dtype)
 
-    blocks = []
-    for kind, k in zip(kinds, kb):
-        km, kf = jax.random.split(k)
-        blk = {"post_ln1": norm(d), "post_ln2": norm(d),
-               "mlp": gated_mlp_init(kf, d, c["intermediate_size"], dtype)}
-        if kind == "gdn":
-            blk["gdn"] = deltanet.init(
-                km, d, c["linear_num_key_heads"], c["linear_key_head_dim"],
-                c["linear_value_head_dim"], c["linear_conv_kernel_dim"],
-                dtype=dtype)
-        else:
-            h_kv, dh = c["num_key_value_heads"], d // heads
-            kq, kk, kv, ko = jax.random.split(km, 4)
-            blk.update(
-                wq=scaled_normal(kq, (d, heads, dh), d, dtype),
-                wk=scaled_normal(kk, (d, h_kv, dh), d, dtype),
-                wv=scaled_normal(kv, (d, h_kv, dh), d, dtype),
-                wo=scaled_normal(ko, (heads, dh, d), heads * dh, dtype),
-                q_norm=norm(heads * dh), k_norm=norm(h_kv * dh))
-        blocks.append(blk)
-    model = {"embed": {"table": scaled_normal(ke, (c["vocab_size"], d), 1.0,
-                                              dtype)},
-             "blocks": blocks, "ln_f": norm(d)}
-    if not c.get("tie_word_embeddings"):
-        model["head"] = {"w": scaled_normal(kh, (d, c["vocab_size"]), d,
-                                            dtype)}
-    return _describe_layer_types(model, c)
+
+def init_ssd_hybrid_model(key, config, dtype=jnp.float32):
+    """A token model of Mamba-2 layers beside full attention (a
+    ``granitemoehybrid`` configuration without experts), from the
+    published keys ``hidden_size, shared_intermediate_size,
+    num_hidden_layers, num_attention_heads, num_key_value_heads,
+    layer_types, mamba_n_heads, mamba_d_head, mamba_d_state, mamba_d_conv,
+    mamba_expand, mamba_n_groups, mamba_chunk_size, rms_norm_eps,
+    vocab_size, tie_word_embeddings`` and the muP multipliers: RMSNorm
+    before every sublayer and the head, gated SiLU feed-forwards, no
+    positional encoding, no biases but the convolutions'."""
+    c = config
+    d = c["hidden_size"]
+    if c["mamba_n_groups"] != 1 or c.get("num_local_experts"):
+        raise ValueError("one group of B and C and no experts are served")
+    if c["mamba_n_heads"] * c["mamba_d_head"] != c["mamba_expand"] * d:
+        raise ValueError("mamba_n_heads x mamba_d_head != mamba_expand x "
+                         "hidden_size")
+
+    def mixer(key):
+        return mamba2.init(key, d, c["mamba_n_heads"], c["mamba_d_head"],
+                           c["mamba_d_state"], c["mamba_d_conv"], dtype)
+    return _init_recurrent_hybrid(
+        key, c, ("ssd", mixer), ("ln1", "ln2"),
+        c["shared_intermediate_size"], d, False, dtype)
 
 
 def describe_token_model(arrays, config, first=0):
@@ -893,7 +1010,8 @@ def _forward(params, obs, attn_fn=None, compute_dtype=jnp.bfloat16,
                              "v": v.reshape(*v.shape[:2], -1)})
                 window = _window_of(blk)
                 with jax.named_scope("window" if window else "full"):
-                    a = diff_attn_fn(q, k, v, q.shape[-1] ** -0.5, window)
+                    a = diff_attn_fn(q, k, v, _scale_of(blk, q.shape[-1]),
+                                     window)
                 x = x + _post(blk, "ln1", _plain_out(blk, a, compute_dtype))
         else:
             with jax.named_scope("attn"):
@@ -1534,7 +1652,8 @@ class _HybridStep:
                 # the window's
                 with jax.named_scope("window" if _window_of(blk) else "full"):
                     return _plain_out(blk, _attend_rows(
-                        q, *self.kv_rows, self.pos, dtype), dtype)
+                        q, *self.kv_rows, self.pos, dtype,
+                        _scale_of(blk, q.shape[-1])), dtype)
             with jax.named_scope(_diff_kind(blk)):
                 return diffattn.attend_one(blk, q, *self.kv_rows, self.pos,
                                            dtype)

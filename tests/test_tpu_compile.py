@@ -465,6 +465,102 @@ def test_window_and_full_pool_is_served_in_place_on_the_chip(
         assert "flash_fwd" in text and "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("what,n", [("step", 32), ("step", 64),
+                                    ("prefill", 1024)])
+def test_mamba2_pool_is_served_in_place_on_the_chip(one_chip, what, n,
+                                                    monkeypatch):
+    """Mamba-2 layers beside NoPE grouped-query attention at the published
+    widths (chipbench/configs/granite4hmicro_serve_bf16.json) over one
+    period: five Mamba-2 layers, one attention layer, four Mamba-2 layers.
+    The donated pool (a float32 state of 64 heads x 64 x 128 a row and
+    layer in two pieces of 32 heads, a flat bfloat16 tail of 3 x 4352, the
+    attention layer's K/V) is aliased to the output; no leaf of it is
+    copied, re-laid or sliced whole; the step steps each layer's state
+    where it lies, by the delta-free ``gdn_update`` kernel (``ssd_update``,
+    one a layer), and holds no stepped rows' state of its own; the
+    prefill's attention is the flash kernel."""
+    import importlib
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from blendjax.models import seqformer
+    from blendjax.serve.server import SeqFormerModel
+    from chipbench import reference_granite4h
+
+    flash_attention = importlib.import_module("blendjax.ops.flash_attention")
+    monkeypatch.setattr(flash_attention, "resolve_interpret",
+                        lambda interpret=None: False)
+    path = os.path.join(os.path.dirname(reference_granite4h.__file__),
+                        "configs", "granite4hmicro_serve_bf16.json")
+    with open(path) as f:
+        cfg = dict(json.load(f), num_hidden_layers=10)
+    assert seqformer.hybrid_layer_kinds(cfg) == ["ssd"] * 5 + ["full"] + [
+        "ssd"] * 4
+    tiny_cfg = dict(cfg, hidden_size=64, num_attention_heads=4,
+                    num_key_value_heads=2, mamba_n_heads=8, mamba_d_head=16,
+                    mamba_d_state=16, shared_intermediate_size=64,
+                    vocab_size=96)
+    tiny = SeqFormerModel(
+        seqformer.describe_token_model(reference_granite4h.make_params(
+            tiny_cfg, 0, jnp.bfloat16), tiny_cfg),
+        slots=2, length=16, compute_dtype=jnp.bfloat16,
+        cache_dtype=jnp.bfloat16)
+    params = seqformer.describe_token_model(jax.eval_shape(
+        lambda: reference_granite4h.make_params(cfg, 0, jnp.bfloat16)), cfg)
+    cache = jax.eval_shape(lambda: seqformer.init_cache(
+        params, cfg["slots"] + 1, dtype=jnp.bfloat16, length=cfg["length"],
+        per_row=True))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    fn = tiny._step if what == "step" else tiny._prefill
+    compiled = fn.lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((n if what == "step" else 1,), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32, sharding=one_chip),
+    ).compile()
+    leaves = [leaf for leaf in jax.tree.leaves(cache) if leaf.ndim > 1]
+    shapes = {(leaf.shape, leaf.dtype.name) for leaf in leaves}
+    assert shapes == {
+        ((73, 2, 32, 64, 128), "float32"), ((73, 13056), "bfloat16"),
+        ((73, 1536, 512), "bfloat16")}
+    assert seqformer.state_row_bytes(cache) == 9 * (2_097_152 + 26_112)
+    pool_bytes = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                     for leaf in leaves)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    text = compiled.as_text()
+    assert f"jit_serve_{what}" in text
+    whole = [line for line in text.splitlines()
+             if re.search(r"= \w+\[(73|146),", line)
+             and re.search(r" (copy|slice|copy-start|slice-start|"
+                           r"transpose)\(", line)]
+    assert not whole, whole[:3]
+    assert "mini-gather-slice" not in text
+    if what == "step":
+        assert len(re.findall(r"%ssd_update[.\d]* = ", text)) == 9
+        assert not re.search(r"%gdn_update[.\d]* = ", text)
+        assert "tpu_custom_call" in text
+        assert not re.search(rf"= f32\[{n},(2,32|64),64,128\]", text)
+        # the attention layer's gathered K/V rows, the head's float32
+        # logits, and of the state the kernel's operands and reads alone:
+        # under a sixteenth of the stepped rows' state (measured 0.16 /
+        # 0.29 GB at 32 / 64 over all 40 layers)
+        kv_rows = 2 * n * 1536 * 512 * 2
+        logits = n * cfg["vocab_size"] * 4
+        state_rows = n * 64 * 64 * 128 * 4
+        assert mem.temp_size_in_bytes < kv_rows + logits + state_rows / 16
+    else:
+        assert "flash_fwd" in text and "tpu_custom_call" in text
+        # the chunks' decays and scores of one layer, not of all nine
+        assert mem.temp_size_in_bytes < 0.6e9
+
+
 # the train cell (chipbench/configs/seqformer_wm100m_train_bf16.json: batch
 # 64 x 512, 8 heads of 128, bfloat16 compute, block 'auto'), the long
 # sequence of chip_smoke.py's kernel leg, and the widest float32 head the

@@ -408,12 +408,27 @@ def _decode_frames(x):
     return decode_frames_pallas(x, interpret=True)
 
 
+def _ssd_update(*args):
+    from blendjax.ops.gdn_update import gdn_update
+
+    return gdn_update(*args, delta=False)
+
+
+def _ssd_update_args():
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    return [jnp.zeros((3, 2, 4, 8)), jnp.asarray([0, 2]),
+            jax.random.normal(ks[0], (2, 8)), jax.random.normal(ks[1], (2, 8)),
+            jax.random.normal(ks[2], (2, 2, 4)), jnp.ones((2, 2)),
+            jax.random.uniform(ks[3], (2, 2))]
+
+
 @pytest.mark.parametrize("name,fn,args", [
     ("flash_fwd", _flash_loss, _qkv),
     ("flash_bwd_dq", jax.grad(_flash_loss, argnums=(0, 1, 2)), _qkv),
     ("flash_bwd_dkv", jax.grad(_flash_loss, argnums=(0, 1, 2)), _qkv),
     ("decode_frames", _decode_frames,
      lambda: [jnp.zeros((2, 8, 8, 3), jnp.uint8)]),
+    ("ssd_update", _ssd_update, _ssd_update_args),
 ])
 def test_pallas_calls_carry_their_names(name, fn, args):
     assert name in _pallas_names(jax.make_jaxpr(fn)(*args()))
@@ -577,6 +592,37 @@ def _window_prefill():
         jnp.ones((6, 1), jnp.int32))
 
 
+TINY_SSD = dict(
+    hidden_size=32, shared_intermediate_size=64, num_hidden_layers=2,
+    num_attention_heads=2, num_key_value_heads=2,
+    layer_types=["mamba", "attention"], mamba_n_heads=4, mamba_d_head=16,
+    mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_n_groups=1,
+    mamba_chunk_size=4, rms_norm_eps=1e-5, vocab_size=64,
+    tie_word_embeddings=True, embedding_multiplier=12,
+    residual_multiplier=0.22, logits_scaling=8, attention_multiplier=0.0625,
+    position_embedding_type="nope")
+
+
+def _tiny_ssd_model():
+    return SeqFormerModel(
+        seqformer.init_ssd_hybrid_model(jax.random.PRNGKey(0), TINY_SSD),
+        slots=2, length=16)
+
+
+def _ssd_step():
+    model = _tiny_ssd_model()
+    return model._step.lower(
+        model.params, model._cache, jnp.zeros(2, jnp.int32),
+        jnp.ones((2, 1), jnp.int32))
+
+
+def _ssd_prefill():
+    model = _tiny_ssd_model()
+    return model._prefill.lower(
+        model.params, model._cache, jnp.zeros(1, jnp.int32),
+        jnp.ones((6, 1), jnp.int32))
+
+
 def test_a_routed_model_of_window_and_full_layers_counts_both_sets():
     """One step of a routed model of mixed kinds returns the held-share
     layers' counts and the hybrid step's beside its reply; nothing is
@@ -630,6 +676,31 @@ def test_linear_attention_model_counters_move_in_a_served_episode():
     assert model.drain_events() == {}
 
 
+def test_mamba2_model_counters_move_in_a_served_episode():
+    """A Mamba-2 row's state (64 x 16 x 16 float32 a layer here) and its
+    tail are what ``serve_state_bytes`` counts twice a real row stepped;
+    a reset zeroes both leaves of the row and counts once."""
+    model = _tiny_ssd_model()
+    row = 4 * 16 * 16 * 4 + 3 * (64 + 32) * 4  # float32 tail here
+    assert seqformer.state_row_bytes(model._cache) == row
+    model.reset_rows(np.asarray([1]))
+    model.prefill_rows(np.asarray([1]), np.ones((5, 1), np.int32))
+    for _ in range(3):  # positions 5, 6, 7; the pad row beside them
+        np.asarray(model.step_rows(np.asarray([1, model.pad_slot]),
+                                   np.ones((2, 1), np.int32)))
+    assert model.drain_events() == {
+        "serve_ctx_positions": 6 + 7 + 8, "serve_rows_stepped": 3,
+        "serve_window_positions": 0, "serve_state_resets": 1,
+        "serve_state_bytes": 3 * 2 * row}
+    kept = {name: np.array(model._cache[name][0][1])
+            for name in ("ssd_s", "ssd_tail")}
+    assert all(np.any(leaf != 0) for leaf in kept.values())
+    model.reset_rows(np.asarray([1]))
+    for name in kept:
+        assert not np.any(np.asarray(model._cache[name][0][1]))
+    assert model.drain_events() == {"serve_state_resets": 1}
+
+
 def test_hybrid_model_counters_move_in_a_served_episode():
     from blendjax.serve.server import HYBRID_EVENTS
 
@@ -676,6 +747,12 @@ def test_routed_model_counters_are_in_the_vocabulary(name):
     (_linear_prefill, "serve_prefill",
      ("forward", "gdn", "conv", "gate", "chunk", "attn", "full", "scatter",
       "mlp", "ln", "head")),
+    (_ssd_step, "serve_step",
+     ("decode", "ssd", "conv", "update", "norm", "attn", "full", "scatter",
+      "gather", "mlp", "ln", "head")),
+    (_ssd_prefill, "serve_prefill",
+     ("forward", "ssd", "conv", "chunk", "norm", "attn", "full", "scatter",
+      "mlp", "ln", "head")),
     (_window_step, "serve_step",
      ("decode", "attn", "window", "full", "scatter", "gather", "moe",
       "route", "experts", "ln", "head")),
@@ -695,6 +772,19 @@ def test_lowering_holds_the_step_and_scope_names(lower, module, scopes):
     for scope in scopes:
         # a scope is one component of an operation's name stack
         assert f"/{scope}/" in text or f"({scope})" in text, scope
+
+
+@pytest.mark.parametrize("lower,scopes", [
+    (_ssd_step, ("ssd/conv", "ssd/update", "ssd/norm")),
+    (_ssd_prefill, ("ssd/conv", "ssd/chunk", "ssd/norm")),
+])
+def test_the_mamba2_scopes_lie_under_ssd(lower, scopes):
+    """What a trace of a Mamba-2 layer shows: its convolution, its state's
+    update (decode) or chunks (prefill) and its gated norm, each under the
+    layer's ``ssd`` scope."""
+    text = lower().as_text(debug_info=True)
+    for scope in scopes:
+        assert f"/{scope}/" in text or f"/{scope}\"" in text, scope
 
 
 # -- names change no arithmetic ---------------------------------------------
